@@ -64,9 +64,6 @@ def run_service_bench(
         ExploreRequest,
     )
 
-    if not B.jax_available():
-        raise RuntimeError("service bench needs jax")
-
     topos = TOPOLOGY_LIBRARY
     recipes = enumerate_recipes()
     if n_recipes is not None:

@@ -34,7 +34,9 @@ def main():
     ap.add_argument("--cache", default=None, metavar="DIR",
                     help="persistent characterization cache directory")
     ap.add_argument("--jobs", type=int, default=None,
-                    help="characterization workers (default: min(4, cpus))")
+                    help="characterization workers of the python backend "
+                         "(default: min(4, cpus)); the device backend "
+                         "always runs in this process")
     ap.add_argument("--model-sweep",
                     choices=["corners", "sensitivity", "mc", "correlated"],
                     default=None,

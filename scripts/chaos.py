@@ -304,6 +304,9 @@ def sweep_shard_kill_resume(ctx: Ctx):
         "--scale", "tiny", "--recipes", ";Rw;Rf;Ba,Rw", "--topos", "5",
     ]
     env = _arm_env(ctx.tmp("once_sk"), "sweep.shard:exit::1:1")
+    # This parent has touched JAX and holds the accelerator: the child
+    # runs on the CPU.
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                       "src"), env.get("PYTHONPATH", "")]

@@ -21,7 +21,7 @@ Rules (each pins a bug class this repo has actually fixed):
     without a ``# repro: host-boundary`` annotation on the call line or
     the line above.  Device-adjacent = the function's source mentions
     jax/jnp/lax, the lazy-grid internals (``_raw``, ``_LAZY_FIELDS``,
-    ``_cell_scalar``), ``enable_x64``, or ``device_get`` — i.e. places
+    ``_cell_scalar``), ``jax_env.x64``, or ``device_get`` — i.e. places
     where an innocuous-looking ``np.asarray`` can be an accidental
     device->host transfer of a whole sweep tensor.  Annotating makes the
     intentional boundary crossings (lazy-grid ``cell()`` gathers, winner
@@ -71,7 +71,7 @@ DEVICE_TOKENS = (
     "._raw(",
     "_LAZY_FIELDS",
     "_cell_scalar",
-    "enable_x64",
+    "jax_env.x64",
     "device_get",
 )
 

@@ -11,7 +11,7 @@ benches otherwise only catch at runtime:
 ``jaxpr-dtype-drift`` (error)
     A ``convert_element_type`` to float32/float16/bfloat16 inside an
     x64 kernel.  The engine's accuracy story is float64 end-to-end
-    (``enable_x64``); a stray f32 literal or ``np.float32`` table column
+    (``jax_env.x64``); a stray f32 literal or ``np.float32`` table column
     silently halves precision for the whole downstream dataflow.
 
 ``jaxpr-host-callback`` (error)
@@ -117,7 +117,8 @@ def lint_kernel(
     spec: KernelSpec, const_bytes: int = 65536
 ) -> list[Finding]:
     import jax
-    from jax.experimental import enable_x64
+
+    from repro.runtime import jax_env
 
     findings: list[Finding] = []
 
@@ -174,7 +175,7 @@ def lint_kernel(
         fn = functools.partial(fn, **dict(example.statics))
 
     before = TRACE_COUNTS[spec.name]
-    ctx = enable_x64() if spec.x64 else _null_ctx()
+    ctx = jax_env.x64() if spec.x64 else _null_ctx()
     try:
         with ctx:
             closed = jax.make_jaxpr(fn)(*example.args)

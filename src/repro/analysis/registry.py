@@ -102,7 +102,7 @@ class KernelSpec:
     """A registered kernel: its counter key, owning module, and the lazy
     builder the jaxpr lint layer traces it through.
 
-    ``x64``: trace under ``jax.experimental.enable_x64`` (the float64
+    ``x64``: trace under ``runtime.jax_env.x64()`` (the float64
     kernels' production context); integer-only kernels register with
     ``x64=False`` and are exempt from the dtype-drift rule (they carry
     no floats to drift).

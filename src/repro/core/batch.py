@@ -39,7 +39,7 @@ ChaAIG -> Evaluate -> FilterEnergy sweep is one jitted `jax.numpy` pass:
 Parity contract: every cycle/flag quantity is exact integer arithmetic,
 and the energy expressions are the *same functions* the scalar path uses
 (`sram.paper_power_mw` / `sram.physical_energy_nj`), evaluated in
-float64 via `jax.experimental.enable_x64`, so ``backend="jax"`` matches
+float64 inside `runtime.jax_env.x64`, so ``backend="jax"`` matches
 ``backend="python"`` to float round-off.  Grid arrays are stored
 ``(n_topologies, n_recipes)`` and flattened topology-major — the exact
 iteration order of the scalar loops — so argmin tie-breaking also
@@ -65,6 +65,7 @@ from typing import Mapping, NamedTuple, Sequence
 import numpy as np
 
 from repro.analysis import registry as _registry
+from repro.runtime import jax_env
 
 from .aig import AigStats
 from .mapping import BITS_PER_GATE, macros_per_type
@@ -86,36 +87,19 @@ from .sram import (
 # should not pay.
 jax = None
 jnp = None
-enable_x64 = None
 
 LEVEL_PAD = 64  # pad the level axis to multiples of this to bound recompiles
 
 
 def _load_jax() -> None:
-    global jax, jnp, enable_x64
+    global jax, jnp
     if jnp is not None:
         return
-    try:
-        import jax as _jax
-        import jax.numpy as _jnp
-        from jax.experimental import enable_x64 as _enable_x64
-    except Exception as e:  # pragma: no cover - container always ships jax
-        raise RuntimeError(
-            "the batched exploration engine requires jax; "
-            "use backend='python' instead"
-        ) from e
-    jax, jnp, enable_x64 = _jax, _jnp, _enable_x64
+    import jax as _jax
+    import jax.numpy as _jnp
 
-
-def jax_available() -> bool:
-    """Whether the jitted engine can run here — lets callers pick the
-    device or host filter up front instead of catching mid-call errors
-    (which would also swallow genuine jax failures)."""
-    try:
-        _load_jax()
-    except RuntimeError:
-        return False
-    return True
+    jax_env.setup()
+    jax, jnp = _jax, _jnp
 
 
 # Per-kernel jit trace counters.  The counter lines inside the kernel
@@ -1017,7 +1001,7 @@ def schedule_batch(
     variant axis here.
     """
     schedule_grid, _ = _grids()
-    with enable_x64():
+    with jax_env.x64():
         cycles, active, fits = schedule_grid(
             work.ops, work.n_levels, topos.ops_per_cycle,
             topos.macros_per_type, topos.is_single, topos.total_bits,
@@ -1114,7 +1098,7 @@ def evaluate_batch(
     table, is_sweep = _as_table(model)
     _check_topo_axis(table, topos)
     feasible = _grid_feasible(topos, feasible)
-    with enable_x64():
+    with jax_env.x64():
         out = evaluate_grid(
             work.ops, work.n_levels, topos.ops_per_cycle,
             topos.macros_per_type, topos.is_single, topos.total_bits,
@@ -1227,7 +1211,7 @@ def schedule_suite(
     computing ``(n_circuits, n_topologies, n_recipes)`` ``cycles`` /
     ``active_macro_cycles`` / ``fits`` arrays for the whole suite."""
     schedule, _ = _suite_grids()
-    with enable_x64():
+    with jax_env.x64():
         cycles, active, fits = schedule(
             suite.ops, suite.n_levels, topos.ops_per_cycle,
             topos.macros_per_type, topos.is_single, topos.total_bits,
@@ -1443,7 +1427,7 @@ def evaluate_suite(
     table, is_sweep = _as_table(model)
     _check_topo_axis(table, topos)
     feasible = _suite_feasible(suite, topos, feasible)
-    with enable_x64():
+    with jax_env.x64():
         out = evaluate(
             suite.ops, suite.n_levels, topos.ops_per_cycle,
             topos.macros_per_type, topos.is_single, topos.total_bits,
@@ -1540,16 +1524,9 @@ def _fused_tail(out, feasible, max_latency, use_latency):
 
 
 def _jit_fused(fn):
-    # Donate the per-variant model operands: they are consumed by the
-    # kernel and never reused, so on accelerator backends XLA may alias
-    # their buffers into the outputs.  CPU cannot use donated buffers
-    # (jax would warn on every call), so the gate is per-backend.
-    donate = () if jax.default_backend() == "cpu" else ("params",)
-    return jax.jit(
-        fn,
-        static_argnames=("discipline", "mode", "use_latency"),
-        donate_argnames=donate,
-    )
+    # No donation: no output has the shape of a model operand, so XLA
+    # cannot alias them (on a TPU jax warns of every unusable donation).
+    return jax.jit(fn, static_argnames=("discipline", "mode", "use_latency"))
 
 
 def _make_fused_grid():
@@ -1714,7 +1691,7 @@ def evaluate_select_batch(
     _check_topo_axis(table, topos)
     feasible = _grid_feasible(topos, feasible)
     use_latency = max_latency_ns is not None
-    with enable_x64():
+    with jax_env.x64():
         params, sharded = _shard_variants(_model_params(table), shard)
         res = fused_grid(
             work.ops, work.n_levels, topos.ops_per_cycle,
@@ -1759,7 +1736,7 @@ def evaluate_select_suite(
     _check_topo_axis(table, topos)
     feasible = _suite_feasible(suite, topos, feasible)
     use_latency = max_latency_ns is not None
-    with enable_x64():
+    with jax_env.x64():
         params, sharded = _shard_variants(_model_params(table), shard)
         res = fused_suite(
             suite.ops, suite.n_levels, topos.ops_per_cycle,
@@ -1828,7 +1805,7 @@ def select_best_batch_device(
         raise ValueError("select_best_batch on an empty grid")
     fits = host_cast(fits, bool)
     use_latency = max_latency is not None and latency is not None
-    with enable_x64():
+    with jax_env.x64():
         idx, has_finite = _SELECT_BATCH(
             energy,
             fits,
@@ -2142,11 +2119,6 @@ def _ex_fused(maker, suite):
                 "discipline": "list", "mode": "physical",
                 "use_latency": True,
             },
-            # mirror _jit_fused's backend gate: donation only declared
-            # where XLA can use it
-            donate_argnames=()
-            if jax.default_backend() == "cpu"
-            else ("params",),
         )
 
     return build

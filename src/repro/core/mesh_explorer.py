@@ -33,9 +33,7 @@ import json
 import numpy as np
 
 from .batch import (
-    jax_available,
     select_best,
-    select_best_batch,
     select_best_batch_device,
     winner_summary,
 )
@@ -174,10 +172,9 @@ def variation_summary(
     mesh analogue of `explorer.VariationResult`.  One vectorized
     ``(V, N)`` energy matrix, then ONE shared selection pass for every
     variant's winner — the device reduction
-    (`select_best_batch_device`) when jax is available, the host
-    `select_best_batch` otherwise (identical winners either way; the
-    parity is pinned in tests/test_selection.py).  Variant 0 is the
-    nominal constants."""
+    (`select_best_batch_device`, whose parity with the host
+    `select_best_batch` is pinned in tests/test_selection.py).  Variant 0
+    is the nominal constants."""
     comp = np.array(
         [
             [
@@ -205,13 +202,10 @@ def variation_summary(
     )  # (V, N)
     fits = np.array([e.fits for e in evals])
     lat = np.array([e.latency_s for e in evals])
-    # Availability is probed up front (a mid-call except would also
-    # swallow genuine jax failures).  The first device call per (V, N)
-    # shape pays a jit trace — noise next to the dry-run compiles that
+    # The first device call per (V, N) shape pays a jit trace — noise next to the dry-run compiles that
     # produced `evals` — and keeps the filter on device alongside the
     # SRAM explorer's fused path.
-    select = select_best_batch_device if jax_available() else select_best_batch
-    idx = select(
+    idx = select_best_batch_device(
         energy, fits[None, :], latency=lat[None, :],
         max_latency=max_latency_s,
     )

@@ -715,13 +715,10 @@ def _supports(aig: Aig, cap: int = 14) -> list[set[int] | None]:
 def resolve_backend(backend: str | None) -> str:
     """Resolve a characterization backend name to ``python`` or ``device``.
 
-    ``auto`` (or None) picks ``device`` when jax imports, else ``python``
-    — same discipline as the sweep backends in `core.batch`.
+    ``auto`` (or None) picks ``device``: jax is a hard dependency.
     """
     if backend is None or backend == "auto":
-        from repro.kernels.aig_sim import jax_available
-
-        return "device" if jax_available() else "python"
+        return "device"
     if backend not in ("python", "device"):
         raise ValueError(f"unknown characterization backend {backend!r}")
     return backend
@@ -1442,14 +1439,19 @@ class CharacterizationError(RuntimeError):
 
 
 def _resolve_jobs(n_jobs: int | None, backend: str = "python") -> int:
+    """Worker count for the characterization pool.
+
+    The device backend never starts pool workers, whatever ``n_jobs`` or
+    ``REPRO_CHA_JOBS`` say: each worker would import jax and claim the
+    accelerator this process holds.  The python backend takes
+    ``n_jobs``, else ``REPRO_CHA_JOBS``, else ``min(4, cpu_count)``.
+    """
+    if backend == "device":
+        return 1
     if n_jobs is None:
         env = os.environ.get("REPRO_CHA_JOBS")
         if env is not None:
             n_jobs = int(env)
-        elif backend == "device":
-            # The device path is already batched; spawn workers would each
-            # pay a fresh jax import + jit warm-up, so default to serial.
-            n_jobs = 1
         else:
             n_jobs = min(4, os.cpu_count() or 1)
     if n_jobs > 1 and not _spawn_safe():
@@ -1488,13 +1490,15 @@ def characterize_suite(
       * a persistent on-disk cache (``cache``: a `CharacterizationCache`
         or a directory path) keyed by (circuit fingerprint, recipe,
         `TRANSFORM_VERSION`) — warm lookups skip the transforms entirely;
-      * a process pool (``n_jobs`` workers, default
-        ``min(4, cpu_count)``, env override ``REPRO_CHA_JOBS``; ``1``
-        disables) driven by an *as-completed futures scheduler*: a
-        transform application is submitted the moment its parent's
-        fingerprint is known, so independent prefix branches and
-        circuits overlap freely and a deep chain (the sine-dominated
-        tail) no longer waits for the rest of its DAG level.
+      * on the python backend, a process pool (``n_jobs`` workers,
+        default ``min(4, cpu_count)``, env override ``REPRO_CHA_JOBS``;
+        ``1`` disables; the device backend always runs serially in this
+        process, see `_resolve_jobs`) driven by an *as-completed
+        futures scheduler*: a transform application is submitted the
+        moment its parent's fingerprint is known, so independent prefix
+        branches and circuits overlap freely and a deep chain (the
+        sine-dominated tail) no longer waits for the rest of its DAG
+        level.
 
     The pool uses the ``spawn`` start method: characterization is pure
     numpy/python, but the parent may have jax/XLA threads loaded (the

@@ -29,20 +29,22 @@ simulation*, reusing the instruction-stream layout of
 Two device engines share the host wrapper: the pure-jnp ``lax.scan``
 mega-program engine (the CPU-CI workhorse — Pallas interpret mode
 would crawl) and a Pallas kernel with the cim_logic VMEM-scratch
-layout (one grid step per query against the full graph, the scratch is
-the "SRAM array" holding every node's packed table).  ``engine="auto"``
-picks Pallas on TPU, jnp elsewhere; both are bit-exact against the
-python-int reference, which CI and the property tests enforce.
+layout (one grid step per block of queries packed side by side along
+the lanes, evaluated against the full graph; the scratch is the "SRAM
+array" holding every node's packed table).  ``engine="auto"`` picks
+Pallas on TPU for programs whose scratch fits VMEM (`_pallas_fits`),
+jnp elsewhere; both are bit-exact against the python-int reference,
+which CI and the property tests enforce.
 
 Shape discipline: queries bucket into word tiers (k <= 5 / 10 / 14
 support vars -> 1 / 32 / 512 uint32 words); mega-program chunks are
 bounded by a per-tier instruction budget and padded to pow2 shapes so
 the jit cache stays small.  Queries wider than `DEVICE_MAX_VARS` take
 the host bigint path on the jnp engine — at 512 words per table
-CPython's limb loops already run at memory speed.  `_jax_setup`
-enables jax's persistent compilation cache (``REPRO_JAX_CACHE[_DIR]``)
-so only the first process on a machine pays the XLA compiles — the
-cross-process cold-start cost this module exists to kill.  A
+CPython's limb loops already run at memory speed.  The kernels reach
+jax through `runtime.jax_env.setup`, whose persistent compilation
+cache means only the first process on a machine pays the XLA compiles
+— the cross-process cold-start cost this module exists to kill.  A
 `TRACE_COUNTS` counter (same idiom as core/batch.py) lets tests pin
 the trace count.
 """
@@ -57,6 +59,7 @@ import numpy as np
 
 from repro.analysis import registry as _registry
 from repro.core.aig import Aig, _elementary_int, lit_node, lit_phase
+from repro.runtime import jax_env
 
 #: Traced-call counters (incremented inside the traced function bodies, so
 #: they count *compiles*, not calls) — same discipline as core/batch.py.
@@ -75,8 +78,17 @@ def trace_counts() -> dict[str, int]:
 # with k support vars lands in the smallest tier with 32 * words >= 2**k;
 # its table occupies the low 2**k bits and the host masks the rest off.
 _TIERS: tuple[tuple[int, int], ...] = ((5, 1), (10, 32), (14, 512))
-#: Batch chunk per word tier (bounds the Pallas (chunk, n_pad) pin block).
+#: Queries per Pallas call, per word tier (a multiple of the block's
+#: queries-per-block, so the grid is whole).
 _CHUNK = {1: 2048, 32: 128, 512: 16}
+#: Lanes of a TPU vector register: a Pallas block row is
+#: ``max(_LANES, words)`` wide, so ``_LANES // words`` queries share one.
+_LANES = 128
+#: VMEM the Pallas engine's whole-graph scratch may take at its widest
+#: tier, and the scoped-VMEM limit the kernel asks Mosaic for (v5e has
+#: 128 MiB of VMEM; the default scoped limit is 16 MiB).
+_PALLAS_SCRATCH_BYTES = 32 << 20
+_PALLAS_VMEM_LIMIT = 64 << 20
 
 #: jnp mega-program shape knobs per word tier: instructions per wave and
 #: the per-chunk instruction budget.  Wider waves amortize the per-step
@@ -92,51 +104,6 @@ DEVICE_MAX_VARS = 10
 MAX_VARS = _TIERS[-1][0]
 
 
-def jax_available() -> bool:
-    try:
-        import jax  # noqa: F401
-    except Exception:  # pragma: no cover - environment without jax
-        return False
-    return True
-
-
-_JAX_SETUP_DONE = False
-
-
-def _jax_setup() -> None:
-    """One-time jax configuration for the characterization kernels.
-
-    Enables the persistent compilation cache (the mega-program engine
-    compiles a few dozen shape buckets; without the cache every fresh
-    process pays ~10 s of XLA compiles, *the* cold-start cost this
-    module exists to kill).  ``REPRO_JAX_CACHE=0`` disables it;
-    ``REPRO_JAX_CACHE_DIR`` overrides the location.
-    """
-    global _JAX_SETUP_DONE
-    if _JAX_SETUP_DONE:
-        return
-    _JAX_SETUP_DONE = True
-    import os
-
-    if os.environ.get("REPRO_JAX_CACHE", "1") == "0":
-        return
-    cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro_jax_cache"
-    )
-    try:  # pragma: no cover - depends on jax version/backend support
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass
-    except Exception:
-        pass
-
-
 #: Instructions per wave of the level-packed stream (see `compile_aig`).
 WAVE_WIDTH = 128
 
@@ -145,20 +112,21 @@ WAVE_WIDTH = 128
 class AigProgram:
     """One AIG lowered to the shared instruction stream.
 
-    ``instrs[i] = [kind, a_row, b_row, out_row]`` evaluates node
-    ``n_pis + 1 + i``; rows are node indices (node 0 = const0, nodes
-    1..n_pis = PIs).  ``kind`` = pa | (pb << 1) — the fanin complement
-    bits.  Rows/instructions are padded to ``n_pad`` (power of two);
-    padding instructions write the scratch row ``n_pad - 1``.
+    ``lits[2 n], lits[2 n + 1]`` are node ``n``'s two fanin literals
+    (``row << 1 | complement``; rows are node indices, node 0 = const0,
+    nodes 1..n_pis = PIs), for every node up to ``n_pad`` (a power of
+    two).  Const0, the PIs and the padding nodes carry ``(0, 0)``, the
+    AND of const0 with itself — the Pallas engine's flat stream.
 
-    ``waves`` is the same stream *level-packed* for the jnp engine:
-    nodes grouped by AIG level (same-level nodes never depend on each
+    ``waves`` is the stream as ``[kind, a_row, b_row, out_row]``
+    instructions (``kind`` = pa | (pb << 1), the fanin complement bits),
+    *level-packed* for the jnp engine: nodes grouped by AIG level (same-level nodes never depend on each
     other), each level split into `WAVE_WIDTH`-wide waves, so one scan
     step evaluates up to 128 independent nodes and the scan length is
     ~depth, not ~n_nodes.  Wave count pads to a power of two.
     """
 
-    instrs: np.ndarray  # (n_pad, 4) int32 — flat, for the Pallas engine
+    lits: np.ndarray  # (2 * n_pad,) int32 — flat, for the Pallas engine
     waves: np.ndarray  # (n_waves_pad, wave_w, 4) int32 — jnp sig engine
     lv: np.ndarray  # (n_nodes,) int64 — AIG levels (mega wave packing)
     n_nodes: int
@@ -187,6 +155,9 @@ def compile_aig(aig: Aig) -> AigProgram:
         instrs[:n_ands, 1] = a >> 1
         instrs[:n_ands, 2] = b >> 1
         instrs[:n_ands, 3] = np.arange(lo, n_nodes)
+    lits = np.zeros((n_pad, 2), dtype=np.int32)
+    lits[lo:n_nodes, 0] = f0[lo:]
+    lits[lo:n_nodes, 1] = f1[lo:]
 
     # Pack into waves by capacity-constrained ASAP list scheduling: a node
     # goes into the first non-full wave after both fanins' waves.  The wave
@@ -224,7 +195,7 @@ def compile_aig(aig: Aig) -> AigProgram:
     if n_ands > 0:
         waves[wave_id, col] = instrs[:n_ands]
     return AigProgram(
-        instrs=instrs,
+        lits=lits.reshape(-1),
         waves=waves,
         lv=lv,
         n_nodes=n_nodes,
@@ -278,7 +249,7 @@ def _make_jnp_mega():
     """A fresh jit wrapper around the mega-program evaluator (fresh =
     empty trace cache, as the analyzer's counter check requires);
     production goes through `_jnp_mega_fn`'s process-wide cache."""
-    _jax_setup()
+    jax_env.setup()
     import jax
     import jax.numpy as jnp
 
@@ -324,7 +295,7 @@ def _jnp_mega_fn():
 def _make_jnp_sig():
     """Fresh jit wrapper for the signature evaluator (see
     `_make_jnp_mega`)."""
-    _jax_setup()
+    jax_env.setup()
     import jax
     import jax.numpy as jnp
 
@@ -353,105 +324,147 @@ def _jnp_sig_fn():
 
 
 # ---------------------------------------------------------------------------
-# Pallas engine — cim_logic's VMEM-scratch layout, one grid step per query
+# Pallas engine — cim_logic's VMEM-scratch layout, queries packed in lanes
 # ---------------------------------------------------------------------------
+#
+# One grid step evaluates a block of queries against the whole graph.  A
+# scratch row holds one node's table for every query of the block side by
+# side (``words`` lanes each, ``_LANES // words`` queries per 128-lane row
+# on the narrow tiers), so each instruction is one full-width row op.  The
+# instruction stream, the per-block pinned nodes and the root literals are
+# read as scalars, so they come in through SMEM (scalar prefetch); the
+# pinned lanes' masks and elementary tables are VMEM rows.  Pinned nodes
+# are sorted, so one pointer carried through the node loop finds them.
+
+
+def _pallas_geometry(w: int) -> tuple[int, int]:
+    """(row width in lanes, queries per block) for word tier ``w``."""
+    width = max(_LANES, w)
+    return width, width // w
+
+
+def _pallas_fits(n_pad: int) -> bool:
+    """Whether a program's whole-graph scratch fits `_PALLAS_SCRATCH_BYTES`
+    at the widest tier — the rule by which ``engine="auto"`` picks the
+    Pallas engine on a TPU.  (Its SMEM stream, 8 bytes per node, is then
+    far inside the 1 MiB of SMEM.)"""
+    width, _ = _pallas_geometry(_TIERS[-1][1])
+    return n_pad * width * 4 <= _PALLAS_SCRATCH_BYTES
+
+
+def _n_pin_slots(w: int) -> int:
+    """Pinned-node slots per block: every query's support, a sentinel,
+    rounded to the 8-row sublane tile."""
+    k_max = next(km for km, tw in _TIERS if tw == w)
+    _, qb = _pallas_geometry(w)
+    return -(-(qb * k_max + 1) // 8) * 8
+
+
+def _make_pallas_eval(interpret: bool):
+    """A fresh jit wrapper around the Pallas evaluator; production goes
+    through `_pallas_fn`, which derives ``interpret`` from the backend."""
+    jax_env.setup()
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(meta_ref, lits_ref, pins_ref, roots_ref, mask_ref, vals_ref,
+               out_ref, scratch_ref, *, w: int, n_roots: int, n_slots: int):
+        blk = pl.program_id(0)
+        width = scratch_ref.shape[1]
+        qb = width // w
+        zero = jnp.zeros((1, width), jnp.int32)
+        scratch_ref[0:1, :] = zero  # const0
+        pbase = blk * n_slots
+
+        def step(i, p):
+            a = lits_ref[2 * i]
+            b = lits_ref[2 * i + 1]
+            va = scratch_ref[pl.ds(a >> 1, 1), :] ^ -(a & 1)
+            vb = scratch_ref[pl.ds(b >> 1, 1), :] ^ -(b & 1)
+            res = va & vb
+            scratch_ref[pl.ds(i, 1), :] = res
+            hit = pins_ref[pbase + p] == i
+
+            @pl.when(hit)
+            def _():
+                m = mask_ref[pl.ds(p, 1), :]
+                v = vals_ref[pl.ds(p, 1), :]
+                scratch_ref[pl.ds(i, 1), :] = jnp.where(m != 0, v, res)
+
+            return p + hit.astype(jnp.int32)
+
+        jax.lax.fori_loop(0, meta_ref[0], step, jnp.int32(0))
+
+        lane_q = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) // w
+        rbase = blk * qb * n_roots
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
+        for j in range(n_roots):
+
+            def gather(q, acc, j=j):
+                lit = roots_ref[rbase + q * n_roots + j]
+                v = scratch_ref[pl.ds(lit >> 1, 1), :] ^ -(lit & 1)
+                return jnp.where(lane_q == q, v, acc)
+
+            out_ref[j : j + 1, :] = jax.lax.fori_loop(0, qb, gather, zero)
+
+    @functools.partial(jax.jit, static_argnames=("w", "n_roots"))
+    def eval_batch(meta, lits, pins, roots, mask, vals, w: int, n_roots: int):
+        """meta (1,) i32 = [n_nodes]; lits (2 n_pad,) i32; pins (B S,) i32
+        sorted pinned nodes per block; roots (B qb R,) i32 root
+        literals; mask / vals (B S, width) i32 pinned lanes and their
+        tables.  Returns (B R8, width) i32: row j of block b holds root
+        j of every query in the block."""
+        TRACE_COUNTS["aig_eval_pallas"] += 1
+        n_pad = lits.shape[0] // 2
+        width = mask.shape[1]
+        n_slots = _n_pin_slots(w)
+        n_blocks = pins.shape[0] // n_slots
+        r8 = -(-n_roots // 8) * 8
+        row = lambda b, *_: (b, 0)  # noqa: E731
+        return pl.pallas_call(
+            functools.partial(kernel, w=w, n_roots=n_roots, n_slots=n_slots),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(n_blocks,),
+                in_specs=[
+                    pl.BlockSpec((n_slots, width), row),
+                    pl.BlockSpec((n_slots, width), row),
+                ],
+                out_specs=pl.BlockSpec((r8, width), row),
+                scratch_shapes=[pltpu.VMEM((n_pad, width), jnp.int32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((n_blocks * r8, width), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=_PALLAS_VMEM_LIMIT,
+            ),
+            interpret=interpret,
+        )(meta, lits, pins, roots, mask, vals)
+
+    return eval_batch
+
 
 _PALLAS_EVAL = None
 
 
 def _pallas_fn():
     global _PALLAS_EVAL
-    if _PALLAS_EVAL is not None:
-        return _PALLAS_EVAL
-    _jax_setup()
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def kernel(instr_ref, pin_ref, elem_ref, rootp_ref, out_ref, scratch_ref,
-               *, n_instr: int, n_roots: int):
-        n_rows, n_words = scratch_ref.shape
-
-        def init_row(i, _):
-            pv = pin_ref[0, i]
-            erow = pl.load(
-                elem_ref, (pl.dslice(jnp.maximum(pv, 0), 1), slice(None))
-            )
-            row = jnp.where(pv >= 0, erow, jnp.zeros_like(erow))
-            pl.store(scratch_ref, (pl.dslice(i, 1), slice(None)), row)
-            return 0
-
-        jax.lax.fori_loop(0, n_rows, init_row, 0)
-
-        def step(i, _):
-            kind = instr_ref[i, 0]
-            a = instr_ref[i, 1]
-            b = instr_ref[i, 2]
-            o = instr_ref[i, 3]
-            va = pl.load(scratch_ref, (pl.dslice(a, 1), slice(None)))
-            vb = pl.load(scratch_ref, (pl.dslice(b, 1), slice(None)))
-            va = jnp.where((kind & 1) == 1, ~va, va)
-            vb = jnp.where(((kind >> 1) & 1) == 1, ~vb, vb)
-            res = va & vb
-            old = pl.load(scratch_ref, (pl.dslice(o, 1), slice(None)))
-            res = jnp.where(pin_ref[0, o] >= 0, old, res)
-            pl.store(scratch_ref, (pl.dslice(o, 1), slice(None)), res)
-            return 0
-
-        jax.lax.fori_loop(0, n_instr, step, 0)
-
-        def gather(j, _):
-            r = rootp_ref[0, j]
-            ph = rootp_ref[0, n_roots + j]
-            v = pl.load(scratch_ref, (pl.dslice(r, 1), slice(None)))
-            v = jnp.where(ph == 1, ~v, v)
-            pl.store(out_ref, (slice(None), pl.dslice(j * n_words, n_words)), v)
-            return 0
-
-        jax.lax.fori_loop(0, n_roots, gather, 0)
-
-    @functools.partial(
-        jax.jit, static_argnames=("n_roots", "interpret")
-    )
-    def eval_batch(instrs, pin, elem, rootp, n_roots: int, interpret: bool):
-        TRACE_COUNTS["aig_eval_pallas"] += 1
-        n_b, n_rows = pin.shape
-        n_words = elem.shape[1]
-        out = pl.pallas_call(
-            functools.partial(
-                kernel, n_instr=instrs.shape[0], n_roots=n_roots
-            ),
-            grid=(n_b,),
-            in_specs=[
-                pl.BlockSpec(instrs.shape, lambda b: (0, 0)),
-                pl.BlockSpec((1, n_rows), lambda b: (b, 0)),
-                pl.BlockSpec(elem.shape, lambda b: (0, 0)),
-                pl.BlockSpec((1, 2 * n_roots), lambda b: (b, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n_roots * n_words), lambda b: (b, 0)),
-            out_shape=jax.ShapeDtypeStruct(
-                (n_b, n_roots * n_words), jnp.int32
-            ),
-            scratch_shapes=[_vmem((n_rows, n_words), jnp.int32)],
-            interpret=interpret,
-        )(instrs, pin, elem, rootp)
-        return out
-
-    _PALLAS_EVAL = eval_batch
+    if _PALLAS_EVAL is None:
+        _PALLAS_EVAL = _make_pallas_eval(jax_env.pallas_interpret())
     return _PALLAS_EVAL
 
 
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _resolve_engine(engine: str) -> str:
+def _resolve_engine(engine: str, prog: "AigProgram | None" = None) -> str:
+    """``auto``: Pallas on a TPU when the program fits (`_pallas_fits`),
+    the jnp engine otherwise."""
     if engine == "auto":
         import jax
 
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        on_tpu = jax.default_backend() == "tpu"
+        fits = prog is None or _pallas_fits(prog.n_pad)
+        return "pallas" if on_tpu and fits else "jnp"
     if engine not in ("jnp", "pallas"):
         raise ValueError(f"unknown aig_sim engine {engine!r}")
     return engine
@@ -681,12 +694,13 @@ def eval_tts(
     membership is derived here with the same descending scan.
 
     The Pallas engine evaluates every query against the whole graph
-    (one grid step per query, VMEM scratch = the packed node array).
+    (one grid step per block of lane-packed queries, VMEM scratch = the
+    packed node array).
     """
     if not items:
         return []
-    engine = _resolve_engine(engine)
     prog = program if program is not None else compile_aig(aig)
+    engine = _resolve_engine(engine, prog)
     results: list[tuple[int, ...] | None] = [None] * len(items)
     if engine == "pallas":
         _eval_pallas(aig, prog, items, results)
@@ -723,52 +737,75 @@ def _eval_pallas(
         _, w = _tier_for(len(support))
         groups.setdefault((w, len(roots)), []).append(idx)
 
+    fn = _pallas_fn()
+    meta = jnp.full((1,), prog.n_nodes, dtype=jnp.int32)
+    lits = jnp.asarray(prog.lits)
     for (w, n_roots), idxs in groups.items():
-        k_max = next(km for km, tw in _TIERS if tw == w)
-        elem = _elem_words(k_max)
+        width, qb = _pallas_geometry(w)
         chunk = _CHUNK[w]
+        n_blocks = chunk // qb
         for lo in range(0, len(idxs), chunk):
             batch = idxs[lo : lo + chunk]
-            n_b = len(batch)
-            pin = np.full((chunk, prog.n_pad), -1, dtype=np.int32)
-            # Scatter all supports at once: (item row, support node) -> var.
-            sup_nodes = np.concatenate(
-                [np.asarray(items[i][1], dtype=np.int64) for i in batch]  # repro: host-boundary
+            pins, roots, mask, vals = _pallas_operands(
+                prog, items, batch, w, n_roots
             )
-            sup_lens = np.array([len(items[i][1]) for i in batch])  # repro: host-boundary
-            item_rows = np.repeat(np.arange(n_b), sup_lens)
-            var_idx = np.concatenate([np.arange(l) for l in sup_lens])
-            pin[item_rows, sup_nodes] = var_idx
-            root_lits_a = np.array([items[i][0] for i in batch], dtype=np.int64)  # repro: host-boundary
-            roots_a = np.zeros((chunk, n_roots), dtype=np.int32)
-            roots_a[:n_b] = root_lits_a >> 1
-            phase_a = np.zeros((chunk, n_roots), dtype=np.int32)
-            phase_a[:n_b] = root_lits_a & 1
-            rootp = np.concatenate([roots_a, phase_a], axis=1)
-            fn = _pallas_fn()
             out = fn(
-                jnp.asarray(prog.instrs),
-                jnp.asarray(pin),
-                jnp.asarray(elem.view(np.int32)),
-                jnp.asarray(rootp),
-                n_roots=n_roots,
-                interpret=_pallas_interpret(),
+                meta, lits, jnp.asarray(pins), jnp.asarray(roots),
+                jnp.asarray(mask), jnp.asarray(vals), w=w, n_roots=n_roots,
             )
             out = np.asarray(out).view(np.uint32)  # repro: host-boundary
-            out = out.reshape(chunk, n_roots, w)
+            # (block, root, query-in-block, word) -> (query, root, word)
+            out = out.reshape(n_blocks, -1, qb, w)[:, :n_roots]
+            out = out.transpose(0, 2, 1, 3).reshape(chunk, n_roots, w)
             for bi, idx in enumerate(batch):
                 root_lits, support = items[idx]
-                mask = (1 << (1 << len(support))) - 1
+                tmask = (1 << (1 << len(support))) - 1
                 results[idx] = tuple(
-                    words_to_int(out[bi, ri]) & mask
+                    words_to_int(out[bi, ri]) & tmask
                     for ri in range(len(root_lits))
                 )
 
 
-def _pallas_interpret() -> bool:
-    import jax
+def _pallas_operands(
+    prog: AigProgram,
+    items: Sequence[tuple[Sequence[int], Sequence[int]]],
+    batch: list[int],
+    w: int,
+    n_roots: int,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Host-side operands of one Pallas call over ``batch`` (padded to
+    `_CHUNK`; padding queries pin nothing and read const0).
 
-    return jax.default_backend() != "tpu"
+    Per block: the distinct pinned nodes in ascending order (then the
+    sentinel ``n_pad``, which no node index reaches), and for each the
+    lanes of the queries that pin it with those queries' elementary
+    tables.  Roots are the flat ``(query, root)`` literals."""
+    k_max = next(km for km, tw in _TIERS if tw == w)
+    elem = _elem_words(k_max).view(np.int32)
+    width, qb = _pallas_geometry(w)
+    n_slots = _n_pin_slots(w)
+    n_blocks = _CHUNK[w] // qb
+    n_pad = prog.n_pad
+    sups = [np.asarray(items[i][1], dtype=np.int64) for i in batch]  # repro: host-boundary
+    sup_lens = np.array([len(s) for s in sups], dtype=np.int64)  # repro: host-boundary
+    q_of = np.repeat(np.arange(len(batch)), sup_lens)
+    node = np.concatenate(sups) if sups else np.zeros(0, np.int64)
+    var = np.arange(len(node)) - np.repeat(np.cumsum(sup_lens) - sup_lens, sup_lens)
+    blk = q_of // qb
+    uniq, inv = np.unique(blk * n_pad + node, return_inverse=True)
+    ublk = uniq // n_pad
+    slot = np.arange(len(uniq)) - np.searchsorted(ublk, ublk)
+    pins = np.full(n_blocks * n_slots, n_pad, dtype=np.int32)
+    pins[ublk * n_slots + slot] = uniq % n_pad
+    rows = (ublk * n_slots + slot)[inv][:, None]
+    lanes = (q_of % qb)[:, None] * w + np.arange(w)
+    mask = np.zeros((n_blocks * n_slots, width), dtype=np.int32)
+    vals = np.zeros((n_blocks * n_slots, width), dtype=np.int32)
+    mask[rows, lanes] = -1
+    vals[rows, lanes] = elem[var]
+    roots = np.zeros((n_blocks * qb, n_roots), dtype=np.int32)
+    roots[: len(batch)] = [items[i][0] for i in batch]
+    return pins, roots.reshape(-1), mask, vals
 
 
 def eval_tt(

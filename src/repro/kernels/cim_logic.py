@@ -24,10 +24,14 @@ come from ops.compile_netlist, which performs the paper's operand placement
 within the two columns").
 
 Kernel layout:
-  * instrs  (n_gates, 4) int32 in VMEM   — [kind, a_row, b_row, out_row]
+  * instrs  (4 (n_gates + n_pos),) int32 in SMEM (scalar prefetch) —
+    flat [kind, a_row, b_row, out_row] words, read as scalars
   * pi      (n_rows_padded, block_words) — PI planes pre-placed in rows
   * out     (n_po_padded, block_words)   — gathered PO planes
   * scratch (n_rows_padded, block_words) VMEM — the "SRAM array"
+
+The kernel compiles with Mosaic on a TPU and runs in interpret mode on
+every other backend (`runtime.jax_env.pallas_interpret`).
 """
 
 from __future__ import annotations
@@ -37,8 +41,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import registry as _registry
+from repro.runtime import jax_env
 
 # repro: kernel-module
 TRACE_COUNTS = _registry.TRACE_COUNTS
@@ -59,27 +65,25 @@ def _cim_kernel(instr_ref, pi_ref, out_ref, scratch_ref, *, n_gates: int, n_pos:
     scratch_ref[...] = pi_ref[...]
 
     def step(i, _):
-        kind = instr_ref[i, 0]
-        a_row = instr_ref[i, 1]
-        b_row = instr_ref[i, 2]
-        o_row = instr_ref[i, 3]
-        a = pl.load(scratch_ref, (pl.dslice(a_row, 1), slice(None)))
-        b = pl.load(scratch_ref, (pl.dslice(b_row, 1), slice(None)))
-        is_nor = (kind == 1).astype(jnp.int32)
-        and_ab = jnp.bitwise_and(a, b)
-        or_ab = jnp.bitwise_or(a, b)
-        res = jnp.bitwise_not(jnp.where(is_nor == 1, or_ab, and_ab))
-        pl.store(scratch_ref, (pl.dslice(o_row, 1), slice(None)), res)
+        kind = instr_ref[4 * i]
+        a_row = instr_ref[4 * i + 1]
+        b_row = instr_ref[4 * i + 2]
+        o_row = instr_ref[4 * i + 3]
+        a = scratch_ref[pl.ds(a_row, 1), :]
+        b = scratch_ref[pl.ds(b_row, 1), :]
+        res = jnp.bitwise_not(jnp.where(kind == 1, a | b, a & b))
+        scratch_ref[pl.ds(o_row, 1), :] = res
         return 0
 
     jax.lax.fori_loop(0, n_gates, step, 0)
 
     # Gather POs: instruction slots [n_gates, n_gates + n_pos) carry the PO
     # row index in column 3 (kind = 3 sentinel).
+    out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
     def gather(j, _):
-        row = instr_ref[n_gates + j, 3]
-        v = pl.load(scratch_ref, (pl.dslice(row, 1), slice(None)))
-        pl.store(out_ref, (pl.dslice(j, 1), slice(None)), v)
+        row = instr_ref[4 * (n_gates + j) + 3]
+        out_ref[pl.ds(j, 1), :] = scratch_ref[pl.ds(row, 1), :]
         return 0
 
     jax.lax.fori_loop(0, n_pos, gather, 0)
@@ -89,10 +93,45 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_rows", "n_gates", "n_pos", "block_words", "interpret"),
-)
+@functools.lru_cache(maxsize=None)
+def _cim_call(interpret: bool):
+    """The jitted kernel call, built once per interpret flag (the compile
+    tests build the Mosaic one on a host without a TPU)."""
+
+    @functools.partial(
+        jax.jit, static_argnames=("n_rows", "n_gates", "n_pos", "block_words")
+    )
+    def call(instrs, pi_planes, n_rows: int, n_gates: int, n_pos: int,
+             block_words: int):
+        TRACE_COUNTS["cim_pallas"] += 1
+        n_rows_p, n_words = pi_planes.shape
+        assert n_rows_p == _round_up(n_rows, SUBLANE)
+        assert n_words % block_words == 0, (n_words, block_words)
+        n_pos_p = _round_up(n_pos, SUBLANE)
+        return pl.pallas_call(
+            functools.partial(_cim_kernel, n_gates=n_gates, n_pos=n_pos),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n_words // block_words,),
+                in_specs=[
+                    pl.BlockSpec((n_rows_p, block_words), lambda j, _: (0, j)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (n_pos_p, block_words), lambda j, _: (0, j)
+                ),
+                # VMEM scratch: the "SRAM array".
+                scratch_shapes=[pltpu.VMEM((n_rows_p, block_words), jnp.int32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((n_pos_p, n_words), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
+            ),
+            interpret=interpret,
+        )(jnp.reshape(instrs, (-1,)), pi_planes)
+
+    return call
+
+
 def cim_pallas_call(
     instrs: jax.Array,  # (n_gates + n_pos, 4) int32 (PO gather slots appended)
     pi_planes: jax.Array,  # (n_rows_padded, n_words) int32, PIs pre-placed
@@ -100,34 +139,9 @@ def cim_pallas_call(
     n_gates: int,
     n_pos: int,
     block_words: int = 512,
-    interpret: bool = True,
 ):
-    TRACE_COUNTS["cim_pallas"] += 1
-    n_rows_p, n_words = pi_planes.shape
-    assert n_rows_p == _round_up(n_rows, SUBLANE)
-    assert n_words % block_words == 0, (n_words, block_words)
-    n_pos_p = _round_up(n_pos, SUBLANE)
-    grid = (n_words // block_words,)
-
-    out = pl.pallas_call(
-        functools.partial(_cim_kernel, n_gates=n_gates, n_pos=n_pos),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((instrs.shape[0], 4), lambda j: (0, 0)),
-            pl.BlockSpec((n_rows_p, block_words), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((n_pos_p, block_words), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n_pos_p, n_words), jnp.int32),
-        scratch_shapes=[
-            # VMEM scratch: the "SRAM array".
-            _vmem((n_rows_p, block_words), jnp.int32)
-        ],
-        interpret=interpret,
-    )(instrs, pi_planes)
-    return out
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    """Run the CiM engine over ``pi_planes`` in ``block_words``-lane tiles."""
+    return _cim_call(jax_env.pallas_interpret())(
+        instrs, pi_planes, n_rows=n_rows, n_gates=n_gates, n_pos=n_pos,
+        block_words=block_words,
+    )

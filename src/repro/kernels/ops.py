@@ -8,7 +8,8 @@ be placed flexibly ... optimizing the use of available SRAM resources".
 
 ``cim_evaluate`` is the jit'd user-facing entry point; it packs test
 vectors, pads shapes to TPU tiling (8 sublanes x 128 lanes), invokes the
-Pallas kernel (interpret=True on CPU), and unpacks outputs.
+Pallas kernel (Mosaic on a TPU, interpret mode elsewhere), and unpacks
+outputs.
 """
 
 from __future__ import annotations
@@ -129,7 +130,6 @@ def cim_evaluate(
     vectors: np.ndarray,  # (n_pis, n_vectors) bits  OR packed int32 words
     packed: bool = False,
     block_words: int = 512,
-    interpret: bool = True,
 ) -> np.ndarray:
     """Evaluate a netlist on test vectors via the Pallas CiM engine.
 
@@ -163,7 +163,6 @@ def cim_evaluate(
         n_gates=cc.n_gates,
         n_pos=cc.n_pos,
         block_words=bw,
-        interpret=interpret,
     )
     out = np.asarray(out)[: cc.n_pos, :n_words]
     if packed:

@@ -35,7 +35,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
     from repro.configs import SKIP_CELLS, get_config
     from repro.launch.hloparse import analyze
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.launch.roofline import CollectiveStats, model_flops, roofline_terms
     from repro.launch.specs import CellSpec
     from repro.models.config import SHAPES
@@ -55,7 +55,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     t0 = time.time()
     if mesh_shape is not None:
         axes = ("pod", "data", "model") if len(mesh_shape) == 3 else ("data", "model")
-        mesh = jax.make_mesh(tuple(mesh_shape), axes)
+        mesh = make_mesh(mesh_shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.devices.size

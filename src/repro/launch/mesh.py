@@ -12,10 +12,18 @@ import jax
 from repro.models.config import ParallelConfig
 
 
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: the models place activations with
+    `with_sharding_constraint`, which jax accepts on Auto axes only
+    (`jax.make_mesh` defaults to Explicit ones)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def parallel_config_for(mesh) -> ParallelConfig:
@@ -27,4 +35,4 @@ def make_host_mesh(model: int = 1):
     """Single-process debug mesh over the visible devices."""
     n = len(jax.devices())
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -60,7 +60,9 @@ def _main_llm(args: argparse.Namespace) -> None:
         print(f"  req {r.uid}: {r.out_tokens[:8]}...")
 
 
-def _main_explore(args: argparse.Namespace) -> None:
+def _main_explore(args: argparse.Namespace) -> int:
+    """Serve ``--requests`` queries; 1 when any failed or came back
+    degraded, else 0."""
     import numpy as np
 
     from repro.core.circuits import benchmark_suite
@@ -107,6 +109,9 @@ def _main_explore(args: argparse.Namespace) -> None:
                 print(f"{r.request.tag:>6}  ERROR {r.error.code}: "
                       f"{r.error.message}")
                 continue
+            if r.degraded:
+                print(f"{r.request.tag:>6}  DEGRADED (served by a fallback "
+                      f"backend)")
             lat.append(r.service_ms)
             w = r.winner
             mark = "warm" if r.grid_cache_hit else "cold"
@@ -130,11 +135,12 @@ def _main_explore(args: argparse.Namespace) -> None:
               f"hit/miss, grid {st.get('grid_hits', 0)}/"
               f"{st.get('grid_misses', 0)} hit/miss, "
               f"{st['distinct_buckets']} trace bucket(s)")
+        return 0 if all(r.ok and not r.degraded for r in resps) else 1
     finally:
         svc.close()
 
 
-def main(argv: "list[str] | None" = None) -> None:
+def main(argv: "list[str] | None" = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     # Back-compat: bare `python -m repro.launch.serve --batch 4` still
     # routes to the LLM launcher.
@@ -175,10 +181,10 @@ def main(argv: "list[str] | None" = None) -> None:
 
     args = ap.parse_args(argv)
     if args.cmd == "explore":
-        _main_explore(args)
-    else:
-        _main_llm(args)
+        return _main_explore(args)
+    _main_llm(args)
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

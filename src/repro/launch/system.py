@@ -29,6 +29,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.analysis import registry as _registry
+from repro.runtime import jax_env
 
 # The shared trace counter (see repro.analysis.registry); this module's
 # sweep kernel bumps ``TRACE_COUNTS["roofline_sweep"]``.
@@ -192,7 +193,7 @@ def sweep_roofline(cost: dict,
     hbm = np.atleast_1d(np.asarray(hbm_bw, np.float64))  # repro: host-boundary
     link = np.atleast_1d(np.asarray(link_bw, np.float64))  # repro: host-boundary
     hbm, link = np.broadcast_arrays(hbm, link)
-    with batch.enable_x64():
+    with jax_env.x64():
         out = _sweep_kernel()(
             np.float64(cost["flops"]), np.float64(cost["hbm_bytes"]),
             np.float64(cost["link_bytes"]), np.float64(peak_flops),

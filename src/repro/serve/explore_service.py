@@ -105,7 +105,7 @@ from repro.core.transforms import (
     characterize_suite,
     resolve_backend,
 )
-from repro.runtime import faults
+from repro.runtime import faults, jax_env
 
 
 # ---------------------------------------------------------------------------
@@ -300,8 +300,6 @@ class ExplorationService:
         default_deadline_s: float | None = None,
         start: bool = True,
     ):
-        if not B.jax_available():  # pragma: no cover - container ships jax
-            raise RuntimeError("ExplorationService requires jax")
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         if grid_cache_size < 1:
@@ -813,7 +811,7 @@ class ExplorationService:
             mask = np.broadcast_to(
                 within[:, None], (len(self._topos), n_r)
             ).reshape(1, n)
-            with B.enable_x64():  # keep the f64 metrics undemoted
+            with jax_env.x64():  # keep the f64 metrics undemoted
                 energy = B.jnp.where(mask, energy, B.jnp.inf)
         try:
             # Always through the latency tier (an absent bound is +inf,
@@ -882,7 +880,7 @@ class ExplorationService:
             ]
         )
         # Device gathers: (V,) vectors are the only transfers here.
-        with B.enable_x64():  # keep the f64 metrics undemoted
+        with jax_env.x64():  # keep the f64 metrics undemoted
             winner_energy = np.asarray(  # repro: host-boundary
                 B.jnp.take_along_axis(
                     entry.energy, B.jnp.asarray(idx)[:, None], axis=-1

@@ -304,13 +304,13 @@ def test_checked_in_baseline_is_empty():
 def test_select_best_batch_device_keeps_operands_on_device(monkeypatch):
     from repro.core import batch as B
 
-    if not B.jax_available():  # pragma: no cover - container ships jax
-        pytest.skip("jax required")
+    from repro.runtime import jax_env
+
     B._load_jax()
     rng = np.random.default_rng(7)
     host_energy = rng.random((4, 96))
     host_fits = np.ones((1, 96), dtype=bool)
-    with B.enable_x64():
+    with jax_env.x64():
         energy = B.jnp.asarray(host_energy)
         fits = B.jnp.asarray(host_fits)
 

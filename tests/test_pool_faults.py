@@ -138,3 +138,24 @@ def test_front_half_failure_quarantines_serially(suite_circuits, clean):
             characterize_suite(
                 suite_circuits, RECIPES, n_jobs=1, backend="python"
             )
+
+
+@pytest.mark.parametrize("n_jobs, env_jobs", [(4, None), (None, "4")])
+def test_device_backend_never_starts_a_pool(
+    suite_circuits, clean, monkeypatch, n_jobs, env_jobs
+):
+    """Pool workers would each import jax and claim the accelerator the
+    parent holds, so the device backend runs serially in-process
+    whatever ``n_jobs`` / ``REPRO_CHA_JOBS`` ask for."""
+    import concurrent.futures
+
+    def no_pool(*args, **kwargs):
+        raise AssertionError("device backend started a process pool")
+
+    monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor", no_pool)
+    if env_jobs is not None:
+        monkeypatch.setenv("REPRO_CHA_JOBS", env_jobs)
+    out = characterize_suite(
+        suite_circuits, RECIPES, n_jobs=n_jobs, backend="device"
+    )
+    assert out == clean

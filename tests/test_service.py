@@ -377,3 +377,14 @@ def test_threaded_submit_cancel_stress(adder, maxc):
     s.close()
     with pytest.raises(RuntimeError):
         s.submit(ExploreRequest(adder))
+
+
+def test_serve_explore_cli_exit_code():
+    """The explore CLI exits non-zero when a request fails (here: a memory
+    budget no topology fits) and 0 when every request is served."""
+    from repro.launch.serve import main
+
+    base = ["explore", "--circuits", "adder", "--recipes", "2",
+            "--requests", "2"]
+    assert main(base) == 0
+    assert main(base + ["--max-memory-kb", "0.001"]) == 1

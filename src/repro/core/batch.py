@@ -65,7 +65,7 @@ from typing import Mapping, NamedTuple, Sequence
 import numpy as np
 
 from repro.analysis import registry as _registry
-from repro.runtime import jax_env
+from repro.runtime import jax_env, trace
 
 from .aig import AigStats
 from .mapping import BITS_PER_GATE, macros_per_type
@@ -1572,6 +1572,15 @@ def _fused_kernels():
     return _FUSED_GRID, _FUSED_SUITE
 
 
+def _host_nbytes(operands) -> int:
+    """Bytes of the host (numpy) leaves of ``operands``: what a device
+    call on them copies to the device."""
+    return sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(operands)
+        if isinstance(x, (np.ndarray, np.generic))
+    )
+
+
 def _shard_variants(
     params: ModelParams, shard: "bool | None"
 ) -> tuple[ModelParams, bool]:
@@ -1737,15 +1746,23 @@ def evaluate_select_suite(
     feasible = _suite_feasible(suite, topos, feasible)
     use_latency = max_latency_ns is not None
     with jax_env.x64():
-        params, sharded = _shard_variants(_model_params(table), shard)
-        res = fused_suite(
+        tables = (
             suite.ops, suite.n_levels, topos.ops_per_cycle,
             topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, topos.cols, params, feasible,
-            np.float64(max_latency_ns if use_latency else 0.0),
-            discipline, mode, use_latency,
+            topos.rows, topos.cols,
         )
-        sel = _fetch_selection(res, sharded)
+        params = _model_params(table)
+        max_latency = np.float64(max_latency_ns if use_latency else 0.0)
+        h2d = _host_nbytes((tables, params, feasible, max_latency))
+        with trace.span("batch.dispatch", h2d_bytes=h2d):
+            params, sharded = _shard_variants(params, shard)
+            res = fused_suite(
+                *tables, params, feasible, max_latency,
+                discipline, mode, use_latency,
+            )
+        with trace.span("batch.fetch") as span:
+            sel = _fetch_selection(res, sharded)
+            span.set_metadata(d2h_bytes=sel.payload_bytes)
         sched, mets = _fused_outputs(res, lazy)
         grid = _build_suite_grid(
             suite, topos, table, model, is_sweep, mode, discipline,

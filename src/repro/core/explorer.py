@@ -69,6 +69,7 @@ from .sram import (
     evaluate,
     inductor_size_nh,
 )
+from ..runtime import trace
 from .transforms import (
     CharacterizationCache,
     enumerate_recipes,
@@ -531,112 +532,122 @@ def explore_suite(
         if backend != "jax":
             raise ValueError("model_sweep requires backend='jax'")
         model = model_sweep.model(0)  # nominal, for best materialization
-    t0 = time.time()
-    if model is None:
-        model = EnergyModel()
+    n_variants = 1 if model_sweep is None else len(model_sweep)
+    with trace.span("explore_suite", circuits=len(circuits), variants=n_variants):
+        t0 = time.time()
+        if model is None:
+            model = EnergyModel()
 
-    if cha is None:
-        cha = characterize_suite(
-            circuits, recipes, cache=cache, n_jobs=n_jobs, backend=cha_backend
-        )
-    cha = {name: _restrict_cha(cha[name], recipes) for name in circuits}
-
-    if backend == "python":
-        out = {
-            name: explore(
-                rtl, sram_list, recipes, model, mode,
-                max_latency_ns=max_latency_ns, backend="python",
-                discipline=discipline, cha=cha[name],
+        if cha is None:
+            cha = characterize_suite(
+                circuits, recipes, cache=cache, n_jobs=n_jobs, backend=cha_backend
             )
-            for name, rtl in circuits.items()
-        }
-        wall = (time.time() - t0) / max(1, len(out))
-        for res in out.values():
-            res.wall_s = wall
-        return out
+        names = list(circuits)
+        sram_list = list(sram_list)
+        with trace.span("explore.feasible"):
+            cha = {name: _restrict_cha(cha[name], recipes) for name in circuits}
+            if backend == "jax":
+                opt = {}
+                feas_mask = np.zeros((len(names), len(sram_list)), dtype=bool)
+                for i, name in enumerate(names):
+                    opt_gate, opt_level, feasible = _opt_and_feasible(
+                        cha[name], sram_list
+                    )
+                    opt[name] = (opt_gate, opt_level)
+                    feas_mask[i] = [t in feasible for t in sram_list]
 
-    names = list(circuits)
-    opt, feas_mask = {}, np.zeros((len(names), len(sram_list)), dtype=bool)
-    sram_list = list(sram_list)
-    for i, name in enumerate(names):
-        opt_gate, opt_level, feasible = _opt_and_feasible(cha[name], sram_list)
-        opt[name] = (opt_gate, opt_level)
-        feas_mask[i] = [t in feasible for t in sram_list]
+        if backend == "python":
+            out = {
+                name: explore(
+                    rtl, sram_list, recipes, model, mode,
+                    max_latency_ns=max_latency_ns, backend="python",
+                    discipline=discipline, cha=cha[name],
+                )
+                for name, rtl in circuits.items()
+            }
+            wall = (time.time() - t0) / max(1, len(out))
+            for res in out.values():
+                res.wall_s = wall
+            return out
 
-    suite = SuiteTable.from_cha(cha)
-    topo_table = TopologyTable.from_topologies(sram_list)
-    swept = model_sweep if model_sweep is not None else model
-    sel: SelectionResult | None = None
-    if fused:
-        # Device-resident back half: evaluate + FilterEnergy fused into
-        # one jitted (optionally variant-sharded) pass — only (C, V)
-        # winner indices + per-winner metrics are transferred, and the
-        # grids below are lazy device views.
-        sg, sel = evaluate_select_suite(
-            suite, topo_table, swept, mode=mode, discipline=discipline,
-            feasible=feas_mask, max_latency_ns=max_latency_ns, lazy=True,
-            shard=shard,
-        )
-    else:
-        sg = evaluate_suite(
-            suite, topo_table, swept,
-            mode=mode, discipline=discipline, feasible=feas_mask,
-        )
+        with trace.span("explore.suite_table", circuits=len(names),
+                        recipes=len(cha[names[0]]) if names else 0):
+            suite = SuiteTable.from_cha(cha)
+            topo_table = TopologyTable.from_topologies(sram_list)
+        swept = model_sweep if model_sweep is not None else model
+        sel: SelectionResult | None = None
+        with trace.span("explore.fused"):
+            if fused:
+                # Device-resident back half: evaluate + FilterEnergy fused
+                # into one jitted (optionally variant-sharded) pass — only
+                # (C, V) winner indices + per-winner metrics are
+                # transferred, and the grids below are lazy device views.
+                sg, sel = evaluate_select_suite(
+                    suite, topo_table, swept, mode=mode, discipline=discipline,
+                    feasible=feas_mask, max_latency_ns=max_latency_ns, lazy=True,
+                    shard=shard,
+                )
+            else:
+                sg = evaluate_suite(
+                    suite, topo_table, swept,
+                    mode=mode, discipline=discipline, feasible=feas_mask,
+                )
 
-    out = {}
-    wall = (time.time() - t0) / max(1, len(names))
-    if sel is not None:
-        suite_winners = sel.winner_idx  # (C, V) — computed on device
-    elif model_sweep is not None:
-        # Host selection stage for the whole hypercube: every (circuit,
-        # variant) winner from ONE batched masked-argmin pass.
-        suite_winners = sg.best_indices(max_latency_ns)  # (C, V)
-    for i, name in enumerate(names):
-        variation = None
-        if model_sweep is not None:
-            vgrid = sg.variation(name)
-            variation = _variation_result(
-                vgrid, max_latency_ns, idx=suite_winners[i],
-                winner_energy=(
-                    None if sel is None else sel.winner_energy_nj[i]
-                ),
-                nominal_latency=(
-                    None if sel is None else sel.nominal_latency_ns[i]
-                ),
-                nominal_fits=(
-                    None if sel is None else bool(sel.nominal_fits[i])
-                ),
-            )
-            grid = vgrid.grid(0)  # nominal variant, the headline result
-            # the batched pass already holds variant 0's winner under
-            # the same tiers — no per-circuit re-selection needed
-            best_flat = int(suite_winners[i, 0])
-        elif sel is not None:
-            grid = sg.grid(name)
-            best_flat = int(sel.winner_idx[i, 0])  # V=1 hypercube
-        else:
-            grid = sg.grid(name)
-            best_flat = grid.best_index(max_latency_ns)
-        ti, ri = grid.unravel(best_flat)
-        recipe, topo = grid.recipes[ri], sram_list[ti]
-        best = _materialize(
-            recipe, topo, cha[name][recipe], model, mode, discipline
-        )
-        out[name] = ExplorationResult(
-            circuit=circuits[name].name,
-            best=best,
-            inductor_nh=inductor_size_nh(topo, model),
-            opt_gate_recipe=opt[name][0],
-            opt_level_recipe=opt[name][1],
-            evaluations=[],
-            n_recipes=len(cha[name]),
-            wall_s=wall,
-            backend=backend,
-            grid=grid,
-            cha=cha[name],
-            variation=variation,
-        )
-    return out
+        with trace.span("explore.assemble"):
+            out = {}
+            wall = (time.time() - t0) / max(1, len(names))
+            if sel is not None:
+                suite_winners = sel.winner_idx  # (C, V) — computed on device
+            elif model_sweep is not None:
+                # Host selection stage for the whole hypercube: every (circuit,
+                # variant) winner from ONE batched masked-argmin pass.
+                suite_winners = sg.best_indices(max_latency_ns)  # (C, V)
+            for i, name in enumerate(names):
+                variation = None
+                if model_sweep is not None:
+                    vgrid = sg.variation(name)
+                    variation = _variation_result(
+                        vgrid, max_latency_ns, idx=suite_winners[i],
+                        winner_energy=(
+                            None if sel is None else sel.winner_energy_nj[i]
+                        ),
+                        nominal_latency=(
+                            None if sel is None else sel.nominal_latency_ns[i]
+                        ),
+                        nominal_fits=(
+                            None if sel is None else bool(sel.nominal_fits[i])
+                        ),
+                    )
+                    grid = vgrid.grid(0)  # nominal variant, the headline result
+                    # the batched pass already holds variant 0's winner under
+                    # the same tiers — no per-circuit re-selection needed
+                    best_flat = int(suite_winners[i, 0])
+                elif sel is not None:
+                    grid = sg.grid(name)
+                    best_flat = int(sel.winner_idx[i, 0])  # V=1 hypercube
+                else:
+                    grid = sg.grid(name)
+                    best_flat = grid.best_index(max_latency_ns)
+                ti, ri = grid.unravel(best_flat)
+                recipe, topo = grid.recipes[ri], sram_list[ti]
+                best = _materialize(
+                    recipe, topo, cha[name][recipe], model, mode, discipline
+                )
+                out[name] = ExplorationResult(
+                    circuit=circuits[name].name,
+                    best=best,
+                    inductor_nh=inductor_size_nh(topo, model),
+                    opt_gate_recipe=opt[name][0],
+                    opt_level_recipe=opt[name][1],
+                    evaluations=[],
+                    n_recipes=len(cha[name]),
+                    wall_s=wall,
+                    backend=backend,
+                    grid=grid,
+                    cha=cha[name],
+                    variation=variation,
+                )
+            return out
 
 
 def explore_request(

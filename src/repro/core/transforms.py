@@ -37,7 +37,7 @@ from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
-from repro.runtime import faults
+from repro.runtime import faults, trace
 
 from .aig import (
     CONST0,
@@ -788,59 +788,65 @@ def _rewrite_device(aig: Aig, k: int = 4, max_cuts: int = 8) -> Aig:
     """`rewrite` with batched device truth tables + vectorized MFFC."""
     from repro.kernels import aig_sim
 
-    cuts = _enumerate_cuts(aig, k=k, max_cuts=max_cuts)
-    fanout = aig.fanout_counts()
-    reach = _reachable(aig)
+    with trace.span("cha.candidates") as span:
+        cuts = _enumerate_cuts(aig, k=k, max_cuts=max_cuts)
+        fanout = aig.fanout_counts()
+        reach = _reachable(aig)
 
-    # Phase A — precompute: every (node, cut) query in python iteration
-    # order; all decisions below depend only on the original AIG.
-    items: list[tuple[int, list[int]]] = []
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if not reach[n]:
-            continue
-        for cut in cuts[n]:
-            if len(cut) < 2 or n in cut:
+        # Phase A — precompute: every (node, cut) query in python iteration
+        # order; all decisions below depend only on the original AIG.
+        items: list[tuple[int, list[int]]] = []
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if not reach[n]:
                 continue
-            items.append((n, sorted(cut)))
+            for cut in cuts[n]:
+                if len(cut) < 2 or n in cut:
+                    continue
+                items.append((n, sorted(cut)))
+        span.set_metadata(queries=len(items))
 
     best_for: dict[int, tuple[tuple, list[int]]] = {}
     if items:
         prog = aig_sim.compile_aig(aig)
-        members = _cone_matrix(aig, [n for n, _ in items], [s for _, s in items])
+        roots = [n for n, _ in items]
+        with trace.span("cha.cones", queries=len(items)):
+            members = _cone_matrix(aig, roots, [s for _, s in items])
+            old_costs = _mffc_sizes_batch(aig, roots, members, fanout)
         tts = aig_sim.eval_tts(
             aig,
             [((lit(n),), sup) for n, sup in items],
             program=prog,
             members=members,
         )
-        old_costs = _mffc_sizes_batch(aig, [n for n, _ in items], members, fanout)
-        best_gain: dict[int, int] = {}
-        for (n, sup), (tt,), old_cost in zip(items, tts, old_costs):
-            cost, plan = synth_plan(tt, len(sup))
-            gain = int(old_cost) - cost
-            if gain > best_gain.get(n, 0):
-                best_gain[n] = gain
-                best_for[n] = (plan, sup)
+        with trace.span("cha.synth", queries=len(items)):
+            best_gain: dict[int, int] = {}
+            for (n, sup), (tt,), old_cost in zip(items, tts, old_costs):
+                cost, plan = synth_plan(tt, len(sup))
+                gain = int(old_cost) - cost
+                if gain > best_gain.get(n, 0):
+                    best_gain[n] = gain
+                    best_for[n] = (plan, sup)
 
     # Phase B — sequential rebuild, replaying the python path's choices.
-    new = Aig(aig.n_pis, name=aig.name)
-    mapping: dict[int, int] = {0: CONST0}
-    for i in range(1, 1 + aig.n_pis):
-        mapping[i] = lit(i)
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if not reach[n]:
-            continue
-        fa, fb = aig.fanins(n)
-        mapping[n] = new.g_and(
-            mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1)
-        )
-        hit = best_for.get(n)
-        if hit is not None:
-            plan, support = hit
-            mapping[n] = build_plan(new, plan, [mapping[m] for m in support])
-    for p in aig.pos:
-        new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
-    out = new.clone()
+    with trace.span("cha.rebuild"):
+        new = Aig(aig.n_pis, name=aig.name)
+        mapping: dict[int, int] = {0: CONST0}
+        for i in range(1, 1 + aig.n_pis):
+            mapping[i] = lit(i)
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if not reach[n]:
+                continue
+            fa, fb = aig.fanins(n)
+            mapping[n] = new.g_and(
+                mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1)
+            )
+            hit = best_for.get(n)
+            if hit is not None:
+                plan, support = hit
+                mapping[n] = build_plan(new, plan, [mapping[m] for m in support])
+        for p in aig.pos:
+            new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
+        out = new.clone()
     return out if out.n_ands <= aig.n_ands else aig
 
 
@@ -854,67 +860,69 @@ def _refactor_device(aig: Aig, max_leaves: int = 10) -> Aig:
     """
     from repro.kernels import aig_sim
 
-    fanout = aig.fanout_counts()
-    reach = _reachable(aig)
-    lv = aig.levels()
+    with trace.span("cha.candidates") as span:
+        fanout = aig.fanout_counts()
+        reach = _reachable(aig)
+        lv = aig.levels()
 
-    cand_items: list[tuple[int, list[int]]] = []
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if not reach[n]:
-            continue
-        if fanout[n] < 2 and lv[n] % 3 != 0:
-            continue
-        leaves = _reconv_cut(aig, n, max_leaves)
-        if len(leaves) < 3 or n in leaves:
-            continue
-        if len(leaves) > 12:
-            continue
-        cand_items.append((n, leaves))
+        cand_items: list[tuple[int, list[int]]] = []
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if not reach[n]:
+                continue
+            if fanout[n] < 2 and lv[n] % 3 != 0:
+                continue
+            leaves = _reconv_cut(aig, n, max_leaves)
+            if len(leaves) < 3 or n in leaves:
+                continue
+            if len(leaves) > 12:
+                continue
+            cand_items.append((n, leaves))
+        span.set_metadata(queries=len(cand_items))
 
     plans: dict[int, tuple[list[tuple[int, int]], list[int], int]] = {}
     if cand_items:
         prog = aig_sim.compile_aig(aig)
-        members = _cone_matrix(
-            aig, [n for n, _ in cand_items], [l for _, l in cand_items]
-        )
+        roots = [n for n, _ in cand_items]
+        with trace.span("cha.cones", queries=len(cand_items)):
+            members = _cone_matrix(aig, roots, [l for _, l in cand_items])
+            old_costs = _mffc_sizes_batch(aig, roots, members, fanout)
         tts = aig_sim.eval_tts(
             aig,
             [((lit(n),), lvs) for n, lvs in cand_items],
             program=prog,
             members=members,
         )
-        old_costs = _mffc_sizes_batch(
-            aig, [n for n, _ in cand_items], members, fanout
-        )
-        for (n, leaves), (tt,), old_cost in zip(cand_items, tts, old_costs):
-            kk = len(leaves)
-            cubes = _isop(tt, _tt_mask(kk), kk)
-            est = sum(bin(p | q).count("1") for p, q in cubes) + max(0, len(cubes) - 1)
-            if est >= int(old_cost) + 2:
-                continue
-            plans[n] = (cubes, leaves, int(old_cost))
+        with trace.span("cha.synth", queries=len(cand_items)):
+            for (n, leaves), (tt,), old_cost in zip(cand_items, tts, old_costs):
+                kk = len(leaves)
+                cubes = _isop(tt, _tt_mask(kk), kk)
+                est = sum(bin(p | q).count("1") for p, q in cubes) + max(0, len(cubes) - 1)
+                if est >= int(old_cost) + 2:
+                    continue
+                plans[n] = (cubes, leaves, int(old_cost))
 
-    new = Aig(aig.n_pis, name=aig.name)
-    mapping: dict[int, int] = {0: CONST0}
-    for i in range(1, 1 + aig.n_pis):
-        mapping[i] = lit(i)
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if not reach[n]:
-            continue
-        fa, fb = aig.fanins(n)
-        mapping[n] = new.g_and(mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1))
-        hit = plans.get(n)
-        if hit is None:
-            continue
-        cubes, leaves, old_cost = hit
-        before = new.n_ands
-        cand = _factor_cubes(new, cubes, [mapping[m] for m in leaves])
-        added = new.n_ands - before
-        if added <= old_cost:
-            mapping[n] = cand
-    for p in aig.pos:
-        new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
-    out = new.clone()
+    with trace.span("cha.rebuild"):
+        new = Aig(aig.n_pis, name=aig.name)
+        mapping: dict[int, int] = {0: CONST0}
+        for i in range(1, 1 + aig.n_pis):
+            mapping[i] = lit(i)
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if not reach[n]:
+                continue
+            fa, fb = aig.fanins(n)
+            mapping[n] = new.g_and(mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1))
+            hit = plans.get(n)
+            if hit is None:
+                continue
+            cubes, leaves, old_cost = hit
+            before = new.n_ands
+            cand = _factor_cubes(new, cubes, [mapping[m] for m in leaves])
+            added = new.n_ands - before
+            if added <= old_cost:
+                mapping[n] = cand
+        for p in aig.pos:
+            new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
+        out = new.clone()
     return out if out.n_ands <= aig.n_ands else aig
 
 
@@ -936,69 +944,73 @@ def _resub_device(aig: Aig, n_words: int = 32, seed: int = 7) -> Aig:
     prog = aig_sim.compile_aig(aig)
     sig = aig_sim.node_signatures(aig, patterns, program=prog)
 
-    buckets: dict[bytes, list[int]] = {}
-    for n in range(1, aig.n_nodes):
-        buckets.setdefault(sig[n].tobytes(), []).append(n)
+    with trace.span("cha.candidates") as span:
+        buckets: dict[bytes, list[int]] = {}
+        for n in range(1, aig.n_nodes):
+            buckets.setdefault(sig[n].tobytes(), []).append(n)
 
-    supports = _supports(aig, cap=14)
-    full = np.uint64(0xFFFFFFFFFFFFFFFF)
-    cand_lists: dict[int, list[tuple[int, bool, list[int]]]] = {}
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if supports[n] is None:
-            continue
-        cands = buckets.get(sig[n].tobytes(), [])
-        comp = (sig[n] ^ full).tobytes()
-        cands = [m for m in cands if m < n] + [m for m in buckets.get(comp, []) if m < n]
-        flist: list[tuple[int, bool, list[int]]] = []
-        for m in cands:
-            if supports[m] is None:
+        supports = _supports(aig, cap=14)
+        full = np.uint64(0xFFFFFFFFFFFFFFFF)
+        cand_lists: dict[int, list[tuple[int, bool, list[int]]]] = {}
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if supports[n] is None:
                 continue
-            neg = sig[m].tobytes() != sig[n].tobytes()
-            sup = sorted(supports[n] | supports[m])
-            if len(sup) > 14:
-                continue
-            flist.append((m, neg, sup))
-        if flist:
-            cand_lists[n] = flist
+            cands = buckets.get(sig[n].tobytes(), [])
+            comp = (sig[n] ^ full).tobytes()
+            cands = [m for m in cands if m < n] + [m for m in buckets.get(comp, []) if m < n]
+            flist: list[tuple[int, bool, list[int]]] = []
+            for m in cands:
+                if supports[m] is None:
+                    continue
+                neg = sig[m].tobytes() != sig[n].tobytes()
+                sup = sorted(supports[n] | supports[m])
+                if len(sup) > 14:
+                    continue
+                flist.append((m, neg, sup))
+            if flist:
+                cand_lists[n] = flist
+        span.set_metadata(queries=sum(map(len, cand_lists.values())))
 
     replace: dict[int, int] = {}
     pos_i = {n: 0 for n in cand_lists}
     active = sorted(cand_lists)
-    while active:
-        batch = [(n,) + cand_lists[n][pos_i[n]] for n in active]
-        tts = aig_sim.eval_tts(
-            aig,
-            [((lit(n), lit(m)), sup) for n, m, _, sup in batch],
-            program=prog,
-        )
-        nxt: list[int] = []
-        for (n, m, neg, sup), (tt_n, tt_m) in zip(batch, tts):
-            if tt_n == tt_m and not neg:
-                replace[n] = lit(m)
-            elif neg and tt_n == (tt_m ^ _tt_mask(len(sup))):
-                replace[n] = lit_not(lit(m))
-            else:
-                pos_i[n] += 1
-                if pos_i[n] < len(cand_lists[n]):
-                    nxt.append(n)
-        active = nxt
+    with trace.span("cha.synth"):
+        while active:
+            batch = [(n,) + cand_lists[n][pos_i[n]] for n in active]
+            tts = aig_sim.eval_tts(
+                aig,
+                [((lit(n), lit(m)), sup) for n, m, _, sup in batch],
+                program=prog,
+            )
+            nxt: list[int] = []
+            for (n, m, neg, sup), (tt_n, tt_m) in zip(batch, tts):
+                if tt_n == tt_m and not neg:
+                    replace[n] = lit(m)
+                elif neg and tt_n == (tt_m ^ _tt_mask(len(sup))):
+                    replace[n] = lit_not(lit(m))
+                else:
+                    pos_i[n] += 1
+                    if pos_i[n] < len(cand_lists[n]):
+                        nxt.append(n)
+            active = nxt
 
     if not replace:
         return aig
-    new = Aig(aig.n_pis, name=aig.name)
-    mapping: dict[int, int] = {0: CONST0}
-    for i in range(1, 1 + aig.n_pis):
-        mapping[i] = lit(i)
-    for n in range(aig.n_pis + 1, aig.n_nodes):
-        if n in replace:
-            r = replace[n]
-            mapping[n] = mapping[lit_node(r)] ^ lit_phase(r)
-        else:
-            fa, fb = aig.fanins(n)
-            mapping[n] = new.g_and(mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1))
-    for p in aig.pos:
-        new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
-    out = new.clone()
+    with trace.span("cha.rebuild"):
+        new = Aig(aig.n_pis, name=aig.name)
+        mapping: dict[int, int] = {0: CONST0}
+        for i in range(1, 1 + aig.n_pis):
+            mapping[i] = lit(i)
+        for n in range(aig.n_pis + 1, aig.n_nodes):
+            if n in replace:
+                r = replace[n]
+                mapping[n] = mapping[lit_node(r)] ^ lit_phase(r)
+            else:
+                fa, fb = aig.fanins(n)
+                mapping[n] = new.g_and(mapping[fa >> 1] ^ (fa & 1), mapping[fb >> 1] ^ (fb & 1))
+        for p in aig.pos:
+            new.add_po(mapping[lit_node(p)] ^ lit_phase(p))
+        out = new.clone()
     return out if out.n_ands <= aig.n_ands else aig
 
 
@@ -1118,9 +1130,11 @@ class RecipeRunner:
         hit = self._applied.get(key)
         if hit is not None:
             return hit
-        out = self._fns[transform](self._store[src_fp])
+        src = self._store[src_fp]
+        with trace.span("cha.apply", transform=transform, n_ands=src.n_ands):
+            out = self._fns[transform](src)
+            out_fp = out.fingerprint()
         self.n_applied += 1
-        out_fp = out.fingerprint()
         self._applied[key] = out_fp
         self._store.setdefault(out_fp, out)
         if self.on_apply is not None:
@@ -1132,7 +1146,9 @@ class RecipeRunner:
         stats: AigStats | None = None,
     ) -> str:
         """Install an externally computed application (process-pool path)."""
-        out_fp = out.fingerprint()
+        with trace.span("cha.apply", transform=transform,
+                        n_ands=self._store[src_fp].n_ands):
+            out_fp = out.fingerprint()
         self.n_applied += 1
         self._applied[(src_fp, transform)] = out_fp
         self._store.setdefault(out_fp, out)
@@ -1174,7 +1190,9 @@ class RecipeRunner:
         fp = self.run_fp(recipe)
         hit = self._stats.get(fp)
         if hit is None:
-            hit = self._stats[fp] = self._store[fp].characterize()
+            aig = self._store[fp]
+            with trace.span("cha.stats", n_ands=aig.n_ands):
+                hit = self._stats[fp] = aig.characterize()
         return hit
 
 
@@ -1523,70 +1541,75 @@ def characterize_suite(
     recipes = [
         tuple(r) for r in (recipes if recipes is not None else enumerate_recipes())
     ]
-    wanted = list(dict.fromkeys([()] + recipes))
-    cache = _as_cache(cache)
-    backend = resolve_backend(backend)
-    failed: dict[str, CharacterizationError] = {}
+    with trace.span("cha.suite", circuits=len(circuits), recipes=len(recipes)):
+        wanted = list(dict.fromkeys([()] + recipes))
+        cache = _as_cache(cache)
+        backend = resolve_backend(backend)
+        failed: dict[str, CharacterizationError] = {}
 
-    out: dict[str, dict[tuple[str, ...], AigStats]] = {}
-    runners: dict[str, RecipeRunner] = {}
-    fps: dict[str, str] = {}
-    for name, rtl in circuits.items():
-        try:
-            faults.inject("cha.backend", detail=f"{backend}:{name}")
-            fps[name] = rtl.fingerprint()
-            cached = cache.load(fps[name]) if cache is not None else {}
-            if cached and all(r in cached for r in wanted):
-                if cache is not None:
-                    cache.hits += 1
-                out[name] = {r: cached[r] for r in wanted}
-                continue
-            if cache is not None:
-                cache.misses += 1
-            runner = RecipeRunner(rtl, backend=backend)
-            if cache is not None:
-                # Partial warm start: replay persisted applications into the
-                # structural memo, then persist every fresh one incrementally.
-                for (src_fp, t), (out_fp, st) in cache.load_applications(
-                    fps[name]
-                ).items():
-                    out_aig = cache.load_aig(out_fp)
-                    if out_aig is not None:
-                        runner.preload_application(src_fp, t, out_aig, st)
-                runner.on_apply = partial(
-                    _persist_application, cache, fps[name], runner
-                )
-            runners[name] = runner
-        except Exception as e:  # noqa: BLE001 — quarantine, don't abort
-            err = CharacterizationError(name, f"{type(e).__name__}: {e}")
-            if failures is None:
-                raise err from e
-            failed[name] = err
-
-    if runners:
-        _run_suite_dag(runners, wanted, n_jobs, backend, policy=policy,
-                       failed=failed if failures is not None else None)
-        for name, runner in runners.items():
-            if name in failed:
-                continue
+        out: dict[str, dict[tuple[str, ...], AigStats]] = {}
+        runners: dict[str, RecipeRunner] = {}
+        fps: dict[str, str] = {}
+        for name, rtl in circuits.items():
             try:
-                cha = {r: runner.stats(r) for r in wanted}
-            except Exception as e:  # noqa: BLE001
+                faults.inject("cha.backend", detail=f"{backend}:{name}")
+                with trace.span("cha.warm_start") as span:
+                    fps[name] = rtl.fingerprint()
+                    cached = cache.load(fps[name]) if cache is not None else {}
+                    if cached and all(r in cached for r in wanted):
+                        if cache is not None:
+                            cache.hits += 1
+                        out[name] = {r: cached[r] for r in wanted}
+                        continue
+                    if cache is not None:
+                        cache.misses += 1
+                    runner = RecipeRunner(rtl, backend=backend)
+                    if cache is not None:
+                        # Partial warm start: replay persisted applications into
+                        # the structural memo, then persist every fresh one
+                        # incrementally.
+                        for (src_fp, t), (out_fp, st) in cache.load_applications(
+                            fps[name]
+                        ).items():
+                            out_aig = cache.load_aig(out_fp)
+                            if out_aig is not None:
+                                runner.preload_application(src_fp, t, out_aig, st)
+                        runner.on_apply = partial(
+                            _persist_application, cache, fps[name], runner
+                        )
+                    span.set_metadata(preloaded=runner.n_preloaded)
+                runners[name] = runner
+            except Exception as e:  # noqa: BLE001 — quarantine, don't abort
                 err = CharacterizationError(name, f"{type(e).__name__}: {e}")
                 if failures is None:
                     raise err from e
                 failed[name] = err
-                continue
-            out[name] = cha
-            if cache is not None:
-                cache.store(fps[name], cha)
 
-    if failed:
-        if failures is None:
-            raise next(iter(failed.values()))
-        failures.update(failed)
-    # Preserve the caller's circuit order; quarantined circuits are absent.
-    return {name: out[name] for name in circuits if name in out}
+        if runners:
+            _run_suite_dag(runners, wanted, n_jobs, backend, policy=policy,
+                           failed=failed if failures is not None else None)
+            for name, runner in runners.items():
+                if name in failed:
+                    continue
+                try:
+                    cha = {r: runner.stats(r) for r in wanted}
+                except Exception as e:  # noqa: BLE001
+                    err = CharacterizationError(name, f"{type(e).__name__}: {e}")
+                    if failures is None:
+                        raise err from e
+                    failed[name] = err
+                    continue
+                out[name] = cha
+                if cache is not None:
+                    with trace.span("cha.persist"):
+                        cache.store(fps[name], cha)
+
+        if failed:
+            if failures is None:
+                raise next(iter(failed.values()))
+            failures.update(failed)
+        # Preserve the caller's circuit order; quarantined circuits are absent.
+        return {name: out[name] for name in circuits if name in out}
 
 
 def _persist_application(
@@ -1607,9 +1630,11 @@ def _persist_application(
     if stats is None:
         stats = runner._stats.get(out_fp)
         if stats is None:
-            stats = out.characterize()
+            with trace.span("cha.stats", n_ands=out.n_ands):
+                stats = out.characterize()
         runner._stats.setdefault(out_fp, stats)
-    cache.store_application(circuit_fp, src_fp, transform, out, stats)
+    with trace.span("cha.persist"):
+        cache.store_application(circuit_fp, src_fp, transform, out, stats)
 
 
 def _run_suite_dag(

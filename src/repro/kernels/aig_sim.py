@@ -59,7 +59,7 @@ import numpy as np
 
 from repro.analysis import registry as _registry
 from repro.core.aig import Aig, _elementary_int, lit_node, lit_phase
-from repro.runtime import jax_env
+from repro.runtime import jax_env, trace
 
 #: Traced-call counters (incremented inside the traced function bodies, so
 #: they count *compiles*, not calls) — same discipline as core/batch.py.
@@ -140,68 +140,69 @@ def _next_pow2(x: int, floor: int = 3) -> int:
 
 def compile_aig(aig: Aig) -> AigProgram:
     """Lower an AIG to the level-ordered instruction stream (host, once)."""
-    n_nodes = aig.n_nodes
-    n_pad = _next_pow2(n_nodes + 1)
-    f0 = np.asarray(aig._f0, dtype=np.int64)
-    f1 = np.asarray(aig._f1, dtype=np.int64)
-    instrs = np.zeros((n_pad, 4), dtype=np.int32)
-    # No-op padding: AND of const0 with itself, parked in the scratch row.
-    instrs[:, 3] = n_pad - 1
-    lo = aig.n_pis + 1
-    n_ands = n_nodes - lo
-    if n_ands > 0:
-        a, b = f0[lo:], f1[lo:]
-        instrs[:n_ands, 0] = (a & 1) | ((b & 1) << 1)
-        instrs[:n_ands, 1] = a >> 1
-        instrs[:n_ands, 2] = b >> 1
-        instrs[:n_ands, 3] = np.arange(lo, n_nodes)
-    lits = np.zeros((n_pad, 2), dtype=np.int32)
-    lits[lo:n_nodes, 0] = f0[lo:]
-    lits[lo:n_nodes, 1] = f1[lo:]
+    with trace.span("aig_sim.compile", n_nodes=aig.n_nodes):
+        n_nodes = aig.n_nodes
+        n_pad = _next_pow2(n_nodes + 1)
+        f0 = np.asarray(aig._f0, dtype=np.int64)
+        f1 = np.asarray(aig._f1, dtype=np.int64)
+        instrs = np.zeros((n_pad, 4), dtype=np.int32)
+        # No-op padding: AND of const0 with itself, parked in the scratch row.
+        instrs[:, 3] = n_pad - 1
+        lo = aig.n_pis + 1
+        n_ands = n_nodes - lo
+        if n_ands > 0:
+            a, b = f0[lo:], f1[lo:]
+            instrs[:n_ands, 0] = (a & 1) | ((b & 1) << 1)
+            instrs[:n_ands, 1] = a >> 1
+            instrs[:n_ands, 2] = b >> 1
+            instrs[:n_ands, 3] = np.arange(lo, n_nodes)
+        lits = np.zeros((n_pad, 2), dtype=np.int32)
+        lits[lo:n_nodes, 0] = f0[lo:]
+        lits[lo:n_nodes, 1] = f1[lo:]
 
-    # Pack into waves by capacity-constrained ASAP list scheduling: a node
-    # goes into the first non-full wave after both fanins' waves.  The wave
-    # width adapts to the graph's average level width (deep carry-chain
-    # circuits get narrow waves), so the stream stays *dense* — total slots
-    # ~ n_ands, steps ~ depth — and the scan's memory traffic is bounded by
-    # useful work, not padding.  Padding slots replay the no-op (scratch-row
-    # write of const0 — duplicates within a wave all store the same value).
-    lv = np.asarray(aig.levels(), dtype=np.int64)
-    if n_ands > 0:
-        depth = max(1, int(lv.max()))
-        wave_w = _next_pow2(min(WAVE_WIDTH, max(8, -(-n_ands // depth))))
-        wave_of = np.full(n_nodes, -1, dtype=np.int64)
-        fill: list[int] = []
-        wave_id = np.zeros(n_ands, dtype=np.int64)
-        col = np.zeros(n_ands, dtype=np.int64)
-        for i in range(n_ands):
-            node = lo + i
-            w = max(wave_of[f0[node] >> 1], wave_of[f1[node] >> 1]) + 1
-            while w < len(fill) and fill[w] >= wave_w:
-                w += 1
-            while w >= len(fill):
-                fill.append(0)
-            wave_of[node] = w
-            wave_id[i] = w
-            col[i] = fill[w]
-            fill[w] += 1
-        n_waves = len(fill)
-    else:
-        wave_w = 8
-        n_waves = 0
-    n_waves_pad = _next_pow2(n_waves + 1, floor=1)
-    waves = np.zeros((n_waves_pad, wave_w, 4), dtype=np.int32)
-    waves[:, :, 3] = n_pad - 1
-    if n_ands > 0:
-        waves[wave_id, col] = instrs[:n_ands]
-    return AigProgram(
-        lits=lits.reshape(-1),
-        waves=waves,
-        lv=lv,
-        n_nodes=n_nodes,
-        n_pis=aig.n_pis,
-        n_pad=n_pad,
-    )
+        # Pack into waves by capacity-constrained ASAP list scheduling: a node
+        # goes into the first non-full wave after both fanins' waves.  The wave
+        # width adapts to the graph's average level width (deep carry-chain
+        # circuits get narrow waves), so the stream stays *dense* — total slots
+        # ~ n_ands, steps ~ depth — and the scan's memory traffic is bounded by
+        # useful work, not padding.  Padding slots replay the no-op (scratch-row
+        # write of const0 — duplicates within a wave all store the same value).
+        lv = np.asarray(aig.levels(), dtype=np.int64)
+        if n_ands > 0:
+            depth = max(1, int(lv.max()))
+            wave_w = _next_pow2(min(WAVE_WIDTH, max(8, -(-n_ands // depth))))
+            wave_of = np.full(n_nodes, -1, dtype=np.int64)
+            fill: list[int] = []
+            wave_id = np.zeros(n_ands, dtype=np.int64)
+            col = np.zeros(n_ands, dtype=np.int64)
+            for i in range(n_ands):
+                node = lo + i
+                w = max(wave_of[f0[node] >> 1], wave_of[f1[node] >> 1]) + 1
+                while w < len(fill) and fill[w] >= wave_w:
+                    w += 1
+                while w >= len(fill):
+                    fill.append(0)
+                wave_of[node] = w
+                wave_id[i] = w
+                col[i] = fill[w]
+                fill[w] += 1
+            n_waves = len(fill)
+        else:
+            wave_w = 8
+            n_waves = 0
+        n_waves_pad = _next_pow2(n_waves + 1, floor=1)
+        waves = np.zeros((n_waves_pad, wave_w, 4), dtype=np.int32)
+        waves[:, :, 3] = n_pad - 1
+        if n_ands > 0:
+            waves[wave_id, col] = instrs[:n_ands]
+        return AigProgram(
+            lits=lits.reshape(-1),
+            waves=waves,
+            lv=lv,
+            n_nodes=n_nodes,
+            n_pis=aig.n_pis,
+            n_pad=n_pad,
+        )
 
 
 @functools.lru_cache(maxsize=None)
@@ -563,113 +564,119 @@ def _eval_mega_tier(
     import itertools
 
     for chunk in chunks:
-        if len(chunk) == len(idxs):
-            cm, counts = mem, sizes
-        else:
-            sel = np.asarray(chunk, dtype=np.int64)  # repro: host-boundary
-            cm, counts = mem[sel], sizes[sel]
-        it = [items[idxs[p]] for p in chunk]
-        k_b = np.array([len(s) for _, s in it], dtype=np.int64)  # repro: host-boundary
-        r_b = np.array([len(r) for r, _ in it], dtype=np.int64)  # repro: host-boundary
-        row_base = 1 + np.concatenate(([0], np.cumsum(k_b + counts)[:-1]))
-        n_rows = int(1 + (k_b + counts).sum())
-        n_rows_pad = _next_pow2(n_rows + 1, floor=10)
-        # Support rows: pinned to elementary tables via the pin map.
-        tot_k = int(k_b.sum())
-        sup_nodes = np.fromiter(
-            itertools.chain.from_iterable(s for _, s in it),
-            dtype=np.int64,
-            count=tot_k,
-        )
-        item_of_sup = np.repeat(np.arange(len(it)), k_b)
-        koff = np.concatenate(([0], np.cumsum(k_b)[:-1]))
-        var_idx = np.arange(tot_k) - np.repeat(koff, k_b)
-        sup_rows = row_base[item_of_sup] + var_idx
-        pin_rows = np.full(n_rows_pad, -1, dtype=np.int32)
-        pin_rows[sup_rows] = var_idx
-        # node -> row per query; unmapped nodes fall through to row 0
-        # (const0) — the python path would raise on such a read, and no
-        # caller produces one (cones are closed over their supports).
-        rowmap = np.zeros((len(it), aig.n_nodes), dtype=np.int32)
-        rowmap[item_of_sup, sup_nodes] = sup_rows
-        b_idx, node_idx = np.nonzero(cm)
-        n_waves = 0
-        if len(b_idx):
-            starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-            local = np.arange(len(b_idx)) - np.repeat(starts, counts)
-            cone_rows = row_base[b_idx] + k_b[b_idx] + local
-            rowmap[b_idx, node_idx] = cone_rows
-            f0n = f0[node_idx]
-            f1n = f1[node_idx]
-            kind = (f0n & 1) | ((f1n & 1) << 1)
-            a_row = rowmap[b_idx, f0n >> 1]
-            b_row = rowmap[b_idx, f1n >> 1]
-            instr = np.stack([kind, a_row, b_row, cone_rows], axis=1).astype(
-                np.int32
+        with trace.span("aig_sim.pack") as span:
+            if len(chunk) == len(idxs):
+                cm, counts = mem, sizes
+            else:
+                sel = np.asarray(chunk, dtype=np.int64)  # repro: host-boundary
+                cm, counts = mem[sel], sizes[sel]
+            it = [items[idxs[p]] for p in chunk]
+            k_b = np.array([len(s) for _, s in it], dtype=np.int64)  # repro: host-boundary
+            r_b = np.array([len(r) for r, _ in it], dtype=np.int64)  # repro: host-boundary
+            row_base = 1 + np.concatenate(([0], np.cumsum(k_b + counts)[:-1]))
+            n_rows = int(1 + (k_b + counts).sum())
+            n_rows_pad = _next_pow2(n_rows + 1, floor=10)
+            # Support rows: pinned to elementary tables via the pin map.
+            tot_k = int(k_b.sum())
+            sup_nodes = np.fromiter(
+                itertools.chain.from_iterable(s for _, s in it),
+                dtype=np.int64,
+                count=tot_k,
             )
-            # Wave-pack by global level, chopping each level into
-            # wave_m-wide groups (same-level instrs never depend).
-            lvn = prog.lv[node_idx]
-            order = np.argsort(lvn, kind="stable")
-            slv = lvn[order]
-            lstarts = np.searchsorted(slv, slv, side="left")
-            pos_in_lv = np.arange(len(order)) - lstarts
-            # (level, sub-group) keys are non-decreasing in `order`, so
-            # consecutive-difference cumsum numbers the waves directly.
-            key = slv * (len(order) + 1) + pos_in_lv // wave_m
-            wid = np.concatenate(([0], np.cumsum(np.diff(key) > 0)))
-            n_waves = int(wid[-1]) + 1
-        n_waves_pad = _next_pow2(n_waves + 1, floor=2)
-        waves = np.zeros((n_waves_pad, wave_m, 4), dtype=np.int32)
-        waves[:, :, 3] = n_rows_pad - 1  # no-op padding: scratch row <- 0
-        if len(b_idx):
-            waves[wid, pos_in_lv % wave_m] = instr[order]
-        # Root queries: one output row per root literal.
-        q_item = np.repeat(np.arange(len(it)), r_b)
-        root_lits = np.fromiter(
-            itertools.chain.from_iterable(r for r, _ in it),
-            dtype=np.int64,
-            count=int(r_b.sum()),
-        )
-        root_rows = rowmap[q_item, root_lits >> 1]
-        n_q = len(root_lits)
-        n_q_pad = _next_pow2(n_q, floor=6)
-        rootp = np.zeros(n_q_pad, dtype=np.int32)
-        rootp[:n_q] = (root_rows.astype(np.int64) << 1) | (root_lits & 1)
-        out = np.asarray(  # repro: host-boundary
-            fn(
-                jnp.asarray(waves),
-                jnp.asarray(pin_rows),
-                dev_elem,
-                jnp.asarray(rootp),
-            )
-        )
-        qoff = np.concatenate(([0], np.cumsum(r_b)))
-        if w == 1:
-            flat = out[:n_q, 0].tolist()
-            for bi, p in enumerate(chunk):
-                idx = idxs[p]
-                roots, support = items[idx]
-                mask = (1 << (1 << len(support))) - 1
-                base = int(qoff[bi])
-                results[idx] = tuple(
-                    flat[base + ri] & mask for ri in range(len(roots))
+            item_of_sup = np.repeat(np.arange(len(it)), k_b)
+            koff = np.concatenate(([0], np.cumsum(k_b)[:-1]))
+            var_idx = np.arange(tot_k) - np.repeat(koff, k_b)
+            sup_rows = row_base[item_of_sup] + var_idx
+            pin_rows = np.full(n_rows_pad, -1, dtype=np.int32)
+            pin_rows[sup_rows] = var_idx
+            # node -> row per query; unmapped nodes fall through to row 0
+            # (const0) — the python path would raise on such a read, and no
+            # caller produces one (cones are closed over their supports).
+            rowmap = np.zeros((len(it), aig.n_nodes), dtype=np.int32)
+            rowmap[item_of_sup, sup_nodes] = sup_rows
+            b_idx, node_idx = np.nonzero(cm)
+            n_waves = 0
+            if len(b_idx):
+                starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+                local = np.arange(len(b_idx)) - np.repeat(starts, counts)
+                cone_rows = row_base[b_idx] + k_b[b_idx] + local
+                rowmap[b_idx, node_idx] = cone_rows
+                f0n = f0[node_idx]
+                f1n = f1[node_idx]
+                kind = (f0n & 1) | ((f1n & 1) << 1)
+                a_row = rowmap[b_idx, f0n >> 1]
+                b_row = rowmap[b_idx, f1n >> 1]
+                instr = np.stack([kind, a_row, b_row, cone_rows], axis=1).astype(
+                    np.int32
                 )
-        else:
-            buf = np.ascontiguousarray(out[:n_q]).tobytes()
-            nb = w * 4
-            for bi, p in enumerate(chunk):
-                idx = idxs[p]
-                roots, support = items[idx]
-                mask = (1 << (1 << len(support))) - 1
-                base = int(qoff[bi])
-                results[idx] = tuple(
-                    int.from_bytes(
-                        buf[(base + ri) * nb : (base + ri + 1) * nb], "little"
+                # Wave-pack by global level, chopping each level into
+                # wave_m-wide groups (same-level instrs never depend).
+                lvn = prog.lv[node_idx]
+                order = np.argsort(lvn, kind="stable")
+                slv = lvn[order]
+                lstarts = np.searchsorted(slv, slv, side="left")
+                pos_in_lv = np.arange(len(order)) - lstarts
+                # (level, sub-group) keys are non-decreasing in `order`, so
+                # consecutive-difference cumsum numbers the waves directly.
+                key = slv * (len(order) + 1) + pos_in_lv // wave_m
+                wid = np.concatenate(([0], np.cumsum(np.diff(key) > 0)))
+                n_waves = int(wid[-1]) + 1
+            n_waves_pad = _next_pow2(n_waves + 1, floor=2)
+            waves = np.zeros((n_waves_pad, wave_m, 4), dtype=np.int32)
+            waves[:, :, 3] = n_rows_pad - 1  # no-op padding: scratch row <- 0
+            if len(b_idx):
+                waves[wid, pos_in_lv % wave_m] = instr[order]
+            # Root queries: one output row per root literal.
+            q_item = np.repeat(np.arange(len(it)), r_b)
+            root_lits = np.fromiter(
+                itertools.chain.from_iterable(r for r, _ in it),
+                dtype=np.int64,
+                count=int(r_b.sum()),
+            )
+            root_rows = rowmap[q_item, root_lits >> 1]
+            n_q = len(root_lits)
+            n_q_pad = _next_pow2(n_q, floor=6)
+            rootp = np.zeros(n_q_pad, dtype=np.int32)
+            rootp[:n_q] = (root_rows.astype(np.int64) << 1) | (root_lits & 1)
+            span.set_metadata(
+                h2d_bytes=waves.nbytes + pin_rows.nbytes + rootp.nbytes
+            )
+        with trace.span("aig_sim.launch", engine="jnp", w=w, queries=len(chunk)):
+            out = np.asarray(  # repro: host-boundary
+                fn(
+                    jnp.asarray(waves),
+                    jnp.asarray(pin_rows),
+                    dev_elem,
+                    jnp.asarray(rootp),
+                )
+            )
+        with trace.span("aig_sim.unpack"):
+            qoff = np.concatenate(([0], np.cumsum(r_b)))
+            if w == 1:
+                flat = out[:n_q, 0].tolist()
+                for bi, p in enumerate(chunk):
+                    idx = idxs[p]
+                    roots, support = items[idx]
+                    mask = (1 << (1 << len(support))) - 1
+                    base = int(qoff[bi])
+                    results[idx] = tuple(
+                        flat[base + ri] & mask for ri in range(len(roots))
                     )
-                    & mask
-                    for ri in range(len(roots))
-                )
+            else:
+                buf = np.ascontiguousarray(out[:n_q]).tobytes()
+                nb = w * 4
+                for bi, p in enumerate(chunk):
+                    idx = idxs[p]
+                    roots, support = items[idx]
+                    mask = (1 << (1 << len(support))) - 1
+                    base = int(qoff[bi])
+                    results[idx] = tuple(
+                        int.from_bytes(
+                            buf[(base + ri) * nb : (base + ri + 1) * nb], "little"
+                        )
+                        & mask
+                        for ri in range(len(roots))
+                    )
 
 
 def eval_tts(
@@ -738,32 +745,33 @@ def _eval_pallas(
         groups.setdefault((w, len(roots)), []).append(idx)
 
     fn = _pallas_fn()
-    meta = jnp.full((1,), prog.n_nodes, dtype=jnp.int32)
-    lits = jnp.asarray(prog.lits)
+    with trace.span("aig_sim.pack", h2d_bytes=prog.lits.nbytes):
+        meta = jnp.full((1,), prog.n_nodes, dtype=jnp.int32)
+        lits = jnp.asarray(prog.lits)
     for (w, n_roots), idxs in groups.items():
         width, qb = _pallas_geometry(w)
         chunk = _CHUNK[w]
         n_blocks = chunk // qb
         for lo in range(0, len(idxs), chunk):
             batch = idxs[lo : lo + chunk]
-            pins, roots, mask, vals = _pallas_operands(
-                prog, items, batch, w, n_roots
-            )
-            out = fn(
-                meta, lits, jnp.asarray(pins), jnp.asarray(roots),
-                jnp.asarray(mask), jnp.asarray(vals), w=w, n_roots=n_roots,
-            )
-            out = np.asarray(out).view(np.uint32)  # repro: host-boundary
-            # (block, root, query-in-block, word) -> (query, root, word)
-            out = out.reshape(n_blocks, -1, qb, w)[:, :n_roots]
-            out = out.transpose(0, 2, 1, 3).reshape(chunk, n_roots, w)
-            for bi, idx in enumerate(batch):
-                root_lits, support = items[idx]
-                tmask = (1 << (1 << len(support))) - 1
-                results[idx] = tuple(
-                    words_to_int(out[bi, ri]) & tmask
-                    for ri in range(len(root_lits))
-                )
+            with trace.span("aig_sim.pack") as span:
+                ops = _pallas_operands(prog, items, batch, w, n_roots)
+                span.set_metadata(h2d_bytes=sum(x.nbytes for x in ops))
+            with trace.span("aig_sim.launch", engine="pallas", w=w,
+                            queries=len(batch)):
+                out = fn(meta, lits, *map(jnp.asarray, ops), w=w, n_roots=n_roots)
+                out = np.asarray(out).view(np.uint32)  # repro: host-boundary
+            with trace.span("aig_sim.unpack"):
+                # (block, root, query-in-block, word) -> (query, root, word)
+                out = out.reshape(n_blocks, -1, qb, w)[:, :n_roots]
+                out = out.transpose(0, 2, 1, 3).reshape(chunk, n_roots, w)
+                for bi, idx in enumerate(batch):
+                    root_lits, support = items[idx]
+                    tmask = (1 << (1 << len(support))) - 1
+                    results[idx] = tuple(
+                        words_to_int(out[bi, ri]) & tmask
+                        for ri in range(len(root_lits))
+                    )
 
 
 def _pallas_operands(
@@ -835,13 +843,17 @@ def node_signatures(
     prog = program if program is not None else compile_aig(aig)
     import jax.numpy as jnp
 
-    patterns = np.asarray(patterns, dtype=np.uint64)  # repro: host-boundary
-    n_words = patterns.shape[1]
-    vals0 = np.zeros((prog.n_pad, 2 * n_words), dtype=np.uint32)
-    vals0[1 : 1 + prog.n_pis] = patterns.view("<u4")
+    with trace.span("aig_sim.pack") as span:
+        patterns = np.asarray(patterns, dtype=np.uint64)  # repro: host-boundary
+        n_words = patterns.shape[1]
+        vals0 = np.zeros((prog.n_pad, 2 * n_words), dtype=np.uint32)
+        vals0[1 : 1 + prog.n_pis] = patterns.view("<u4")
+        span.set_metadata(h2d_bytes=prog.waves.nbytes + vals0.nbytes)
     sig_fn = _jnp_sig_fn()
-    out = np.asarray(sig_fn(jnp.asarray(prog.waves), jnp.asarray(vals0)))  # repro: host-boundary
-    return np.ascontiguousarray(out[: prog.n_nodes]).view("<u8")
+    with trace.span("aig_sim.launch", engine="jnp", w=2 * n_words, queries=prog.n_nodes):
+        out = np.asarray(sig_fn(jnp.asarray(prog.waves), jnp.asarray(vals0)))  # repro: host-boundary
+    with trace.span("aig_sim.unpack"):
+        return np.ascontiguousarray(out[: prog.n_nodes]).view("<u8")
 
 
 # ---------------------------------------------------------------------------

@@ -716,7 +716,6 @@ class ExplorationService:
             + [self._capacity_feasible(self._cha[fps[0]][1])]
             * (len(padded.circuits) - len(fps))
         )
-        t0 = time.perf_counter()
         sg, _sel = evaluate_select_suite(
             padded,
             self._topos,
@@ -730,9 +729,6 @@ class ExplorationService:
         with self._stats_lock:
             self._stats["batches"] += 1
             self._stats["evaluate_calls"] += 1
-            self._stats["evaluate_ms"] += int(
-                (time.perf_counter() - t0) * 1e3
-            )
         self._buckets[bucket] += 1
         is_sweep = table is not None
         n = len(self._topos) * len(padded.recipes)

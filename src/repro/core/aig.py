@@ -55,7 +55,13 @@ def lit_regular(l: int) -> int:
 
 @dataclasses.dataclass
 class AigStats:
-    """Characterization record — ``ChaAIG`` of Algorithm I."""
+    """Characterization record — ``ChaAIG`` of Algorithm I.
+
+    A record is immutable once built: nothing changes its fields after
+    construction, so `ops_matrix` converts ``ops_per_level`` once and
+    keeps the matrix (outside the dataclass fields, so `to_dict`, ``==``,
+    ``repr`` and pickles are those of the fields alone).
+    """
 
     n_pis: int
     n_pos: int
@@ -90,16 +96,33 @@ class AigStats:
             return 0
         return max(sum(d.values()) for d in self.ops_per_level)
 
+    # The (n_levels, 3) level-op matrix, built on first use.
+    _ops_matrix = None
+
+    def __getstate__(self) -> dict:
+        state = dict(self.__dict__)
+        state.pop("_ops_matrix", None)
+        return state
+
+    @property
+    def has_ops_matrix(self) -> bool:
+        """Whether `ops_matrix` has been built for this record."""
+        return self._ops_matrix is not None
+
     def ops_matrix(self) -> np.ndarray:
-        """Per-level op counts as an ``(n_levels, 3)`` int array in
-        (nand, nor, inv) order — the row format the batched exploration
-        engine (core/batch.py) stacks into its workload tensor."""
-        out = np.zeros((len(self.ops_per_level), 3), dtype=np.int64)
-        for i, level in enumerate(self.ops_per_level):
-            out[i, 0] = level.get("nand", 0)
-            out[i, 1] = level.get("nor", 0)
-            out[i, 2] = level.get("inv", 0)
-        return out
+        """Per-level op counts as a read-only ``(n_levels, 3)`` int64 array
+        in (nand, nor, inv) order — the row format the batched exploration
+        engine (core/batch.py) stacks into its workload tensor.  Built on
+        the first call; later calls return the same array."""
+        if self._ops_matrix is None:
+            out = np.array(
+                [(lv.get("nand", 0), lv.get("nor", 0), lv.get("inv", 0))
+                 for lv in self.ops_per_level],
+                dtype=np.int64,
+            ).reshape(-1, 3)
+            out.flags.writeable = False
+            self._ops_matrix = out
+        return self._ops_matrix
 
 
 class Aig:

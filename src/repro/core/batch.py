@@ -354,11 +354,24 @@ class SuiteTable:
         )
         pad = max(pad_levels_to, 1)
         l_pad = ((max(max_l, 1) + pad - 1) // pad) * pad
-        tables = [
-            WorkloadTable.from_stats(cha[name], pad_levels_to=l_pad)
-            for name in names
-        ]
-        return cls.from_workloads(dict(zip(names, tables)))
+        shape = (len(names), len(recipes))
+        ops = np.zeros(shape + (l_pad, len(OP_TYPES)), dtype=np.int32)
+        op_totals = np.zeros(shape + (len(OP_TYPES),), dtype=np.int64)
+        n_levels = np.zeros(shape, dtype=np.int32)
+        for c, name in enumerate(names):
+            for r, s in enumerate(cha[name].values()):
+                m = s.ops_matrix()
+                ops[c, r, : m.shape[0]] = m
+                op_totals[c, r] = m.sum(axis=0)
+                n_levels[c, r] = s.n_levels
+        return cls(
+            circuits=names,
+            recipes=recipes,
+            ops=ops,
+            n_levels=n_levels,
+            op_totals=op_totals,
+            gates=op_totals.sum(axis=2),
+        )
 
     @classmethod
     def from_workloads(

@@ -571,9 +571,12 @@ def explore_suite(
             return out
 
         with trace.span("explore.suite_table", circuits=len(names),
-                        recipes=len(cha[names[0]]) if names else 0):
+                        recipes=len(cha[names[0]]) if names else 0) as span:
+            records = [s for m in cha.values() for s in m.values()]
+            built = len({id(s) for s in records if not s.has_ops_matrix})
             suite = SuiteTable.from_cha(cha)
             topo_table = TopologyTable.from_topologies(sram_list)
+            span.set_metadata(built=built, reused=len(records) - built)
         swept = model_sweep if model_sweep is not None else model
         sel: SelectionResult | None = None
         with trace.span("explore.fused"):

@@ -7,6 +7,9 @@ The contract under test: ``backend="jax"`` is the same Algorithm I as
 batched into one jitted grid.
 """
 
+import json
+import pickle
+
 import numpy as np
 import pytest
 
@@ -260,6 +263,70 @@ def test_workload_table_padding_and_totals():
     ]
     # padding rows are zero
     assert work.ops[0, 2:].sum() == 0
+
+
+# ---------------------------------------------------------------------------
+# AigStats level-op matrix memo
+# ---------------------------------------------------------------------------
+
+LEVELS = [(3, 1, 0), (0, 0, 1), (400, 130, 65), (0, 7, 2)]
+
+
+class _CountingLevels(list):
+    """An ``ops_per_level`` list that counts its iterations."""
+
+    reads = 0
+
+    def __iter__(self):
+        type(self).reads += 1
+        return super().__iter__()
+
+
+def test_ops_matrix_is_built_once_and_read_only(monkeypatch):
+    rec = stats_from_levels(LEVELS)
+    monkeypatch.setattr(_CountingLevels, "reads", 0)
+    rec.ops_per_level = _CountingLevels(rec.ops_per_level)
+    assert not rec.has_ops_matrix
+    first = rec.ops_matrix()
+    assert _CountingLevels.reads == 1 and rec.has_ops_matrix
+    assert first.dtype == np.int64 and first.tolist() == [list(l) for l in LEVELS]
+    again = rec.ops_matrix()
+    assert again is first
+    assert _CountingLevels.reads == 1
+    assert not again.flags.writeable
+    with pytest.raises(ValueError):
+        again[0, 0] = 1
+    # a level that leaves out an op type reads as zero of that type
+    sparse = stats_from_levels([(2, 0, 0)])
+    sparse.ops_per_level[0] = dict(nand=2)
+    assert sparse.ops_matrix().tolist() == [[2, 0, 0]]
+    assert stats_from_levels([]).ops_matrix().shape == (0, 3)
+
+
+def test_ops_matrix_memo_leaves_record_form_unchanged():
+    rec, twin = stats_from_levels(LEVELS), stats_from_levels(LEVELS)
+    before = json.dumps(rec.to_dict(), sort_keys=True)
+    rec.ops_matrix()
+    assert json.dumps(rec.to_dict(), sort_keys=True) == before
+    assert rec.to_dict() == twin.to_dict()
+    assert rec == twin and repr(rec) == repr(twin)
+    back = AigStats.from_dict(rec.to_dict())
+    assert back == rec and not back.has_ops_matrix
+
+
+def test_ops_matrix_memo_is_not_pickled():
+    rec, twin = stats_from_levels(LEVELS), stats_from_levels(LEVELS)
+    rec.ops_matrix()
+    blob = pickle.dumps(rec)
+    assert blob == pickle.dumps(twin)
+    clone = pickle.loads(blob)
+    assert clone == rec and not clone.has_ops_matrix
+    assert np.array_equal(clone.ops_matrix(), rec.ops_matrix())
+
+
+def test_characterization_builds_no_ops_matrix():
+    stats = C.gen_adder(4).characterize()
+    assert not stats.has_ops_matrix
 
 
 def test_topology_table_matches_library():

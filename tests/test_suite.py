@@ -20,6 +20,7 @@ from repro.core import circuits as C
 from repro.core import transforms as T
 from repro.core.aig import AigStats
 from repro.core.batch import (
+    LEVEL_PAD,
     SuiteTable,
     TopologyTable,
     WorkloadTable,
@@ -248,6 +249,54 @@ def test_suite_table_workload_view(tiny_cha):
         ]
 
 
+def _stats_of_depth(depth: int, seed: int) -> AigStats:
+    levels = np.random.default_rng(seed).integers(0, 50, size=(depth, 3))
+    ops = [dict(nand=int(a), nor=int(b), inv=int(c)) for a, b, c in levels]
+    if depth:
+        ops[0].pop("nor")  # a level without an op type reads as zero of it
+    return AigStats(
+        n_pis=4, n_pos=2, n_ands=0, n_levels=depth, ops_per_level=ops,
+        nand_count=int(levels[:, 0].sum()),
+        nor_count=int(levels[1:, 1].sum()),
+        inv_count=int(levels[:, 2].sum()),
+    )
+
+
+@pytest.mark.parametrize("depths,pad_levels_to", [
+    ({"a": [3, 17, 130], "b": [1, 40, 0], "c": [64, 5, 9]}, LEVEL_PAD),
+    ({"a": [2 * LEVEL_PAD, 7, 1], "b": [LEVEL_PAD, 3, 12]}, LEVEL_PAD),
+    ({"a": [3, 17, 130], "b": [1, 40, 2]}, 100),
+    ({"a": [3, 17, 130], "b": [1, 40, 2]}, 1),
+], ids=["mixed", "on_pad_multiple", "pad_100", "pad_1"])
+def test_suite_table_from_cha_matches_stacked_workloads(depths, pad_levels_to):
+    """`from_cha` fills the padded tensor from each record's matrix; it
+    equals stacking each circuit's `WorkloadTable.from_stats` through
+    `from_workloads`, dtypes and shapes included."""
+    recipes = [(), ("Ba",), ("Rw", "Rf")]
+    cha = {
+        name: {r: _stats_of_depth(d, seed=10 * ci + ri)
+               for ri, (r, d) in enumerate(zip(recipes, ds))}
+        for ci, (name, ds) in enumerate(depths.items())
+    }
+    max_l = max(d for ds in depths.values() for d in ds)
+    l_pad = -(-max(max_l, 1) // pad_levels_to) * pad_levels_to
+    ref = SuiteTable.from_workloads({
+        name: WorkloadTable.from_stats(rows, pad_levels_to=l_pad)
+        for name, rows in cha.items()
+    })
+    got = SuiteTable.from_cha(cha, pad_levels_to=pad_levels_to)
+    assert got.circuits == ref.circuits == tuple(depths)
+    assert got.recipes == ref.recipes == tuple(recipes)
+    assert got.ops.shape == (len(cha), len(recipes), l_pad, 3)
+    for field in ("ops", "n_levels", "op_totals", "gates"):
+        a, b = getattr(got, field), getattr(ref, field)
+        assert a.dtype == b.dtype and a.shape == b.shape, field
+        assert np.array_equal(a, b), field
+    assert got.gates.tolist() == [
+        [s.total_gates for s in rows.values()] for rows in cha.values()
+    ]
+
+
 def test_suite_table_validation(tiny_cha):
     with pytest.raises(ValueError, match="empty"):
         SuiteTable.from_cha({})
@@ -256,6 +305,11 @@ def test_suite_table_validation(tiny_cha):
     lopsided["short"] = {(): tiny_cha[name][()]}
     with pytest.raises(ValueError, match="different recipe set"):
         SuiteTable.from_cha(lopsided)
+    last = list(tiny_cha)[-1]
+    reordered = dict(tiny_cha)
+    reordered[last] = dict(reversed(tiny_cha[last].items()))
+    with pytest.raises(ValueError, match=f"circuit {last!r} covers a different"):
+        SuiteTable.from_cha(reordered)
 
 
 def test_explore_suite_matches_explore(tiny_pair, tiny_cha):

@@ -186,6 +186,9 @@ def test_torn_write_via_journal_fault_recovers(
             suite_circuits, journal_dir=journal, shard_size=2,
             sram_list=TOPOS, recipes=RECIPES, cache=cha_cache, n_jobs=1,
         )
+        # drain the async writer while the rule is armed, so the append
+        # it tears is not left to a publish after the scope closes
+        CheckpointManager(str(journal)).wait()
     out = run_sweep(
         suite_circuits, journal_dir=journal, shard_size=2,
         sram_list=TOPOS, recipes=RECIPES, cache=cha_cache, n_jobs=1,

@@ -21,6 +21,7 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from repro.core import batch, circuits as C  # noqa: E402
+from repro.core.aig import AigStats  # noqa: E402
 from repro.core.explorer import explore_suite  # noqa: E402
 from repro.core.sram import TOPOLOGY_LIBRARY, ModelTable  # noqa: E402
 from repro.core.transforms import CharacterizationCache, characterize_suite  # noqa: E402
@@ -86,6 +87,12 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
     cha = characterize_suite(suite, DEPTH1, n_jobs=1, backend="python")
     table = ModelTable.monte_carlo(n=2, sigma=0.1, seed=3)
     explore_suite(suite, TOPOLOGY_LIBRARY, DEPTH1, cha=cha, model_sweep=table)
+    # the traced calls run on distinct copies of the records (the runner
+    # shares one record between recipes with equal outputs), the first
+    # call building every level-op matrix
+    fresh = {n: {r: AigStats.from_dict(s.to_dict()) for r, s in m.items()}
+             for n, m in cha.items()}
+    n_records = sum(map(len, fresh.values()))
 
     _, fused_suite = batch._fused_kernels()
     calls = []
@@ -96,7 +103,7 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
 
     monkeypatch.setattr(batch, "_FUSED_SUITE", counted)
     events = _record(tmp_path / "trace", lambda: explore_suite(
-        suite, TOPOLOGY_LIBRARY, DEPTH1, cha=cha, model_sweep=table))
+        suite, TOPOLOGY_LIBRARY, DEPTH1, cha=fresh, model_sweep=table))
 
     (top,) = _named(events, "rcim.explore_suite")
     assert _parent_name(top) == "bench.window"
@@ -105,7 +112,8 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
         (ev,) = _named(events, f"rcim.explore.{name}")
         assert ev["parent"] is top
     (table_span,) = _named(events, "rcim.explore.suite_table")
-    assert table_span["args"] == {"circuits": 2, "recipes": len(DEPTH1) + 1}
+    assert table_span["args"] == {"circuits": 2, "recipes": len(DEPTH1) + 1,
+                                  "built": n_records, "reused": 0}
     (fused,) = _named(events, "rcim.explore.fused")
     (dispatch,) = _named(events, "rcim.batch.dispatch")
     (fetch,) = _named(events, "rcim.batch.fetch")
@@ -114,6 +122,13 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
     assert len(calls) == 1
     assert dispatch["args"] == {"h2d_bytes": calls[0]}
     assert fetch["args"]["d2h_bytes"] > 0
+
+    # a second call over the same records reuses every matrix
+    events = _record(tmp_path / "trace2", lambda: explore_suite(
+        suite, TOPOLOGY_LIBRARY, DEPTH1, cha=fresh, model_sweep=table))
+    (table_span,) = _named(events, "rcim.explore.suite_table")
+    assert table_span["args"]["built"] == 0
+    assert table_span["args"]["reused"] == n_records
 
 
 def test_characterize_suite_spans(tmp_path, monkeypatch):

@@ -18,17 +18,12 @@ default-scale suite x all 65 recipes x the 12 library topologies.
    Monte-Carlo queries); each must be ok, not degraded, and equal to the
    offline `explore_request` answer.
 
-``--four-chips`` runs only the variant-sharded sweep: a V=1024
-Monte-Carlo `explore_suite` sharded over 4 devices, against the same
-sweep unsharded on one device.  It reuses the characterization cache
-under `RUN_DIR` when one is there (the one-chip run starts it cold).
-
 Phase times printed here are smoke timings of one cold run, not
 metrics.  Exits non-zero, with no result line, when JAX finds no TPU or
 any check fails; otherwise the last line of stdout is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 
-Usage:  python3 chip_smoke.py [--four-chips]
+Usage:  python3 chip_smoke.py
 """
 
 from __future__ import annotations
@@ -46,7 +41,6 @@ PARITY_CIRCUITS = ("bar", "max")
 ENERGY_RTOL = 1e-9
 N_REQUESTS = 18
 N_VARIANTS = 16
-N_VARIANTS_SHARDED = 1024
 
 
 class SmokeFailure(Exception):
@@ -69,12 +63,11 @@ def phase(name: str, t0: float, counts0: dict) -> dict:
     return counts
 
 
-def characterize(suite, recipes, cold: bool):
+def characterize(suite, recipes):
     from repro.core.transforms import characterize_suite
 
     cache = os.path.join(RUN_DIR, "cha")
-    if cold:
-        shutil.rmtree(cache, ignore_errors=True)
+    shutil.rmtree(cache, ignore_errors=True)
     return characterize_suite(
         suite, recipes, cache=cache, n_jobs=1, backend="device"
     ), cache
@@ -194,7 +187,7 @@ def one_chip(suite, recipes) -> None:
 
     counts = {}
     t0 = time.perf_counter()
-    cha, cache = characterize(suite, recipes, cold=True)
+    cha, cache = characterize(suite, recipes)
     counts = phase("cold characterization (device backend)", t0, counts)
     check_characterization(suite, recipes, cha)
 
@@ -215,44 +208,8 @@ def one_chip(suite, recipes) -> None:
           counts)
 
 
-def four_chips(suite, recipes) -> None:
-    import jax
-
-    from repro.core.explorer import explore_suite
-    from repro.core.sram import TOPOLOGY_LIBRARY, ModelTable
-
-    check(len(jax.devices()) == 4, f"--four-chips needs 4 devices, JAX "
-                                   f"found {len(jax.devices())}")
-    t0 = time.perf_counter()
-    cha, _ = characterize(suite, recipes, cold=False)
-    counts = phase("characterization (device backend, cache kept)", t0, {})
-    table = ModelTable.monte_carlo(n=N_VARIANTS_SHARDED, sigma=0.1, seed=0)
-    out = {}
-    for shard in (True, False):
-        t0 = time.perf_counter()
-        out[shard] = explore_suite(suite, TOPOLOGY_LIBRARY, recipes,
-                                   cha=cha, model_sweep=table, shard=shard)
-        counts = phase(f"V={len(table)} sweep, shard={shard}", t0, counts)
-    for name in suite:
-        raw = out[True][name].variation.grid._raw("energy_nj")
-        n_dev = len(raw.sharding.device_set)
-        check(n_dev == 4, f"{name}: sharded sweep output spans {n_dev} "
-                          f"devices, not 4")
-        a, b = out[True][name].variation, out[False][name].variation
-        check([winner_key(*w) for w in a.winners]
-              == [winner_key(*w) for w in b.winners],
-              f"{name}: sharded winners differ from unsharded")
-        check(bool((a.winner_energy_nj == b.winner_energy_nj).all()),
-              f"{name}: sharded winner energies differ from unsharded")
-    print(f"four chips: {len(suite)} x {len(table)} winners identical "
-          f"sharded over 4 devices and unsharded", flush=True)
-
-
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--four-chips", action="store_true",
-                    help="run only the variant-sharded sweep on 4 chips")
-    args = ap.parse_args(argv)
+    argparse.ArgumentParser(description=__doc__.split("\n")[0]).parse_args(argv)
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
     try:
@@ -280,7 +237,7 @@ def main(argv=None) -> int:
     recipes = enumerate_recipes()
     os.makedirs(RUN_DIR, exist_ok=True)
     try:
-        (four_chips if args.four_chips else one_chip)(suite, recipes)
+        one_chip(suite, recipes)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

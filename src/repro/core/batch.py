@@ -1766,14 +1766,26 @@ def evaluate_select_suite(
         )
         params = _model_params(table)
         max_latency = np.float64(max_latency_ns if use_latency else 0.0)
-        h2d = _host_nbytes((tables, params, feasible, max_latency))
-        with trace.span("batch.dispatch", h2d_bytes=h2d):
-            params, sharded = _shard_variants(params, shard)
+        replicated = _host_nbytes((tables, feasible, max_latency))
+        per_variant = _host_nbytes(params)
+        with trace.span("batch.dispatch", h2d_bytes=replicated + per_variant) as span:
+            with trace.span("batch.shard") as shard_span:
+                params, sharded = _shard_variants(params, shard)
+                devices = (len(params.f_clk_hz.sharding.device_set)
+                           if sharded else 1)
+                shard_span.set_metadata(
+                    devices=devices,
+                    variants_per_device=len(table) // devices,
+                )
+            # the variant-sharded model operands reach the devices once;
+            # every other operand is replicated to each of them
+            span.set_metadata(devices=devices,
+                              placed_bytes=replicated * devices + per_variant)
             res = fused_suite(
                 *tables, params, feasible, max_latency,
                 discipline, mode, use_latency,
             )
-        with trace.span("batch.fetch") as span:
+        with trace.span("batch.fetch", devices=devices) as span:
             sel = _fetch_selection(res, sharded)
             span.set_metadata(d2h_bytes=sel.payload_bytes)
         sched, mets = _fused_outputs(res, lazy)

@@ -63,34 +63,57 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_fused_suite_compiles_for_v5e(one_chip):
-    """The 9 circuits x 16 variants x 12 topologies x 65 recipes fused
-    evaluate+select kernel, with its float64 operands."""
+def _compile_fused_suite(table_sharding, param_sharding, n_variants: int):
+    """Compile the fused evaluate+select suite kernel for 9 circuits x
+    ``n_variants`` x 12 topologies x 65 recipes, with its float64
+    operands: the model operands on ``param_sharding``, the rest on
+    ``table_sharding``."""
     n_c, n_r, n_l = 9, len(enumerate_recipes()) + 1, 4 * B.LEVEL_PAD
     assert n_r == 65
     topos = B.TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     n_t = len(topos)
-    params = B._model_params(ModelTable.monte_carlo(n=16, sigma=0.1, seed=0))
+    params = B._model_params(
+        ModelTable.monte_carlo(n=n_variants, sigma=0.1, seed=0))
     with jax_env.x64():
-        spec = lambda a: _spec(  # noqa: E731
-            one_chip, np.shape(a), jnp.asarray(a).dtype
-        )
+        def spec(a, sharding=table_sharding):
+            return _spec(sharding, np.shape(a), jnp.asarray(a).dtype)
+
         args = (
-            _spec(one_chip, (n_c, n_r, n_l, 3), jnp.int32),
-            _spec(one_chip, (n_c, n_r), jnp.int32),
+            _spec(table_sharding, (n_c, n_r, n_l, 3), jnp.int32),
+            _spec(table_sharding, (n_c, n_r), jnp.int32),
             *map(spec, (
                 topos.ops_per_cycle, topos.macros_per_type, topos.is_single,
                 topos.total_bits, topos.rows, topos.cols,
             )),
-            jax.tree.map(spec, params),
-            _spec(one_chip, (n_c, n_t), jnp.bool_),
-            _spec(one_chip, (), jnp.float64),
+            jax.tree.map(lambda a: spec(a, param_sharding), params),
+            _spec(table_sharding, (n_c, n_t), jnp.bool_),
+            _spec(table_sharding, (), jnp.float64),
         )
         _, fused_suite = B._fused_kernels()
-        compiled = fused_suite.lower(
+        return fused_suite.lower(
             *args, discipline="list", mode="physical", use_latency=True
         ).compile()
+
+
+def test_fused_suite_compiles_for_v5e(one_chip):
+    """The 9 circuits x 16 variants x 12 topologies x 65 recipes fused
+    evaluate+select kernel, with its float64 operands."""
+    compiled = _compile_fused_suite(one_chip, one_chip, 16)
     assert compiled.as_text()
+
+
+def test_sharded_fused_suite_compiles_for_v5e_2x2(topo):
+    """The four-chip cell's kernel: 1,024 variants laid 256 a chip over
+    the 2x2 mesh as `batch._shard_variants` lays them, every other
+    operand replicated.  GSPMD partitions it along the variants, with
+    the all-reduce that hands each chip the nominal (variant-0) winner."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.asarray(topo.devices), ("variants",))
+    compiled = _compile_fused_suite(
+        NamedSharding(mesh, PartitionSpec()),
+        NamedSharding(mesh, PartitionSpec("variants")), 1024)
+    assert "all-reduce" in compiled.as_text()
 
 
 @pytest.mark.parametrize("w", [w for _, w in aig_sim._TIERS])

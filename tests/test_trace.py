@@ -120,8 +120,13 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
     assert dispatch["parent"] is fused and fetch["parent"] is fused
     assert dispatch["end"] <= fetch["start"]
     assert len(calls) == 1
-    assert dispatch["args"] == {"h2d_bytes": calls[0]}
-    assert fetch["args"]["d2h_bytes"] > 0
+    # one device: every operand is placed once (tests/test_shard.py has four)
+    assert dispatch["args"] == {"h2d_bytes": calls[0], "devices": 1,
+                                "placed_bytes": calls[0]}
+    (shard,) = _named(events, "rcim.batch.shard")
+    assert shard["parent"] is dispatch
+    assert shard["args"] == {"devices": 1, "variants_per_device": 2}
+    assert fetch["args"]["d2h_bytes"] > 0 and fetch["args"]["devices"] == 1
 
     # a second call over the same records reuses every matrix
     events = _record(tmp_path / "trace2", lambda: explore_suite(

@@ -752,6 +752,62 @@ _METRIC_KEYS = (
 # Grid fields that may hold device-resident (jax) arrays in lazy mode.
 _LAZY_FIELDS = frozenset(_SCHED_KEYS + _METRIC_KEYS)
 
+# Deferred views made by the grids' view methods, and deferred slices
+# resolved on the device (`_Slice.resolve`): the explorer's assembly span
+# reports both, so a view that is made and never read shows as a view
+# without a slice.
+VIEW_COUNTS = {"views": 0, "slices": 0}
+
+
+def _compose(index: tuple, idx: tuple) -> tuple:
+    """``idx`` applied to ``root[index]``, as one index into ``root``.
+
+    Both hold integers and full slices only: each full slice of
+    ``index`` takes the next entry of ``idx``, the rest of ``idx`` goes
+    on the trailing axes."""
+    rest = iter(idx)
+    out = tuple(next(rest, i) if isinstance(i, slice) else i for i in index)
+    return out + tuple(rest)
+
+
+class _Slice:
+    """``root[index]`` of a device array, not yet dispatched: what a lazy
+    grid's view holds in place of a device slice.  A view of a view
+    composes the index, so the root is always the array the device
+    program returned."""
+
+    __slots__ = ("root", "index")
+
+    def __init__(self, root, index: tuple):
+        self.root, self.index = root, index
+
+    @property
+    def shape(self) -> tuple:
+        kept = (n for n, i in zip(self.root.shape, self.index)
+                if isinstance(i, slice))
+        return tuple(kept) + tuple(self.root.shape[len(self.index):])
+
+    @property
+    def size(self) -> int:
+        return int(np.prod(self.shape))
+
+    def resolve(self):
+        """The slice as a device array: one device op."""
+        VIEW_COUNTS["slices"] += 1
+        return self.root[self.index]
+
+
+def _view(a, idx: tuple):
+    """``a[idx]`` without device work: a numpy view of a numpy array, a
+    deferred `_Slice` of a device array or of a `_Slice`."""
+    if isinstance(a, np.ndarray):
+        return a[idx]
+    if not idx:
+        return a
+    if isinstance(a, _Slice):
+        return _Slice(a.root, _compose(a.index, idx))
+    return _Slice(a, idx)
+
 
 class _LazyArrays:
     """Mixin for the grid dataclasses: metric/schedule fields may hold
@@ -763,32 +819,56 @@ class _LazyArrays:
     already moved the winners across, and the full (C, V, T, R) tensors
     stay where they were computed.
 
-    View methods (``grid``/``variation``/``suite``) slice through
-    ``_raw`` so child grids inherit the un-materialized device arrays —
-    slicing a jax array is a device op, not a transfer.
+    View methods (``grid``/``variation``/``suite``) hand their child
+    grids deferred slices (`_Slice`) of the device arrays, so making a
+    view does no device work either: a child's field is sliced on the
+    device when it is first read, through ``_raw`` or an attribute, and
+    numpy-backed grids keep plain numpy views.
     """
 
     def __getattribute__(self, name):
         val = object.__getattribute__(self, name)
         if name in _LAZY_FIELDS and not isinstance(val, np.ndarray):
             # repro: host-boundary — lazy-grid materialization on first access
-            val = np.asarray(val)
+            val = np.asarray(self._raw(name))
             object.__setattr__(self, name, val)
         return val
 
-    def _raw(self, name: str):
-        """The stored array without materializing it (device or numpy)."""
+    def _stored(self, name: str):
+        """The stored field as it is: numpy, device array or `_Slice`."""
         return object.__getattribute__(self, name)
+
+    def _raw(self, name: str):
+        """The stored array without materializing it (device or numpy);
+        a deferred slice is dispatched here, once, and cached in place."""
+        val = object.__getattribute__(self, name)
+        if isinstance(val, _Slice):
+            val = val.resolve()
+            object.__setattr__(self, name, val)
+        return val
+
+    def _views(self, sched_idx: tuple, metric_idx: tuple) -> dict:
+        """The schedule and metric fields of a view: each stored field
+        indexed by ``sched_idx`` / ``metric_idx`` through `_view`."""
+        out = {k: _view(self._stored(k), sched_idx) for k in _SCHED_KEYS}
+        out.update({k: _view(self._stored(k), metric_idx) for k in _METRIC_KEYS})
+        if any(isinstance(a, _Slice) for a in out.values()):
+            VIEW_COUNTS["views"] += 1
+        return out
 
     def _cell_scalar(self, name: str, idx: tuple) -> float:
         """One element of a (possibly device-resident) field.
 
-        Indexing the raw array first keeps the gather on the device and
-        moves a single scalar across the boundary — the full tensor is
-        NOT materialized (and stays lazy for later accesses).
+        Indexing the raw array (a deferred slice's root, at the composed
+        index) keeps the gather on the device and moves a single scalar
+        across the boundary — the field is NOT materialized (and stays
+        lazy for later accesses).
         """
+        val = self._stored(name)
+        if isinstance(val, _Slice):
+            val, idx = val.root, _compose(val.index, idx)
         # repro: host-boundary — single-scalar device gather
-        return float(np.asarray(self._raw(name)[idx]))
+        return float(np.asarray(val[idx]))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -849,8 +929,8 @@ class ExplorationGrid(_LazyArrays):
 
     @property
     def size(self) -> int:
-        # _raw: a shape query must not materialize a lazy device tensor
-        return self._raw("energy_nj").size
+        # _stored: a shape query must not slice or materialize a lazy field
+        return self._stored("energy_nj").size
 
     def unravel(self, flat_index: int) -> tuple[int, int]:
         """Flat (topology-major) index -> (topology_idx, recipe_idx)."""
@@ -903,7 +983,8 @@ class VariationGrid(_LazyArrays):
     Schedules (``cycles`` / ``active_macro_cycles`` / ``fits``) are
     model-free exact integers, stored once as ``(T, R)``; each metric
     carries a leading variant axis ``(V, T, R)``.  ``grid(v)`` slices
-    variant ``v`` back out as a standard `ExplorationGrid` (numpy views).
+    variant ``v`` back out as a standard `ExplorationGrid` (numpy views,
+    or deferred device slices of a lazy grid — see `_LazyArrays`).
     """
 
     recipes: tuple[tuple[str, ...], ...]
@@ -945,14 +1026,7 @@ class VariationGrid(_LazyArrays):
         return ExplorationGrid(
             recipes=self.recipes,
             topologies=self.topologies,
-            cycles=self._raw("cycles"),
-            active_macro_cycles=self._raw("active_macro_cycles"),
-            fits=self._raw("fits"),
-            latency_ns=self._raw("latency_ns")[v],
-            energy_nj=self._raw("energy_nj")[v],
-            power_mw=self._raw("power_mw")[v],
-            throughput_gops=self._raw("throughput_gops")[v],
-            tops_per_watt=self._raw("tops_per_watt")[v],
+            **self._views((), (v,)),
             area_mm2=self.area_mm2[v],
             feasible=self.feasible,
             mode=self.mode,
@@ -1069,7 +1143,7 @@ def _build_grid(
             discipline=discipline,
             model=model if isinstance(model, EnergyModel) else table.model(0),
             **sched,
-            **{k: v[0] for k, v in mets.items()},
+            **{k: _view(v, (0,)) for k, v in mets.items()},
         )
     return VariationGrid(
         recipes=work.recipes,
@@ -1135,7 +1209,8 @@ class SuiteGrid(_LazyArrays):
     arrays — one `ExplorationGrid` per circuit, stacked.
 
     Produced by `evaluate_suite`; ``grid(circuit)`` slices one circuit
-    back out as a standard `ExplorationGrid` (numpy views, no copies), so
+    back out as a standard `ExplorationGrid` (numpy views, no copies; a
+    lazy grid's views are deferred device slices), so
     everything downstream of the per-circuit sweep (``best_index``,
     `select_best`, `explorer.best_worst`) works unchanged.
     """
@@ -1160,7 +1235,7 @@ class SuiteGrid(_LazyArrays):
     @property
     def size(self) -> int:
         """Total swept implementations (circuits x topologies x recipes)."""
-        return self._raw("energy_nj").size
+        return self._stored("energy_nj").size
 
     def circuit_index(self, circuit: str | int) -> int:
         if isinstance(circuit, int):
@@ -1173,14 +1248,7 @@ class SuiteGrid(_LazyArrays):
         return ExplorationGrid(
             recipes=self.recipes,
             topologies=self.topologies,
-            cycles=self._raw("cycles")[c],
-            active_macro_cycles=self._raw("active_macro_cycles")[c],
-            fits=self._raw("fits")[c],
-            latency_ns=self._raw("latency_ns")[c],
-            energy_nj=self._raw("energy_nj")[c],
-            power_mw=self._raw("power_mw")[c],
-            throughput_gops=self._raw("throughput_gops")[c],
-            tops_per_watt=self._raw("tops_per_watt")[c],
+            **self._views((c,), (c,)),
             area_mm2=self.area_mm2,
             feasible=self.feasible[c],
             mode=self.mode,
@@ -1272,7 +1340,7 @@ class SuiteVariationGrid(_LazyArrays):
     @property
     def size(self) -> int:
         """Total swept implementations (C x V x T x R)."""
-        return self._raw("energy_nj").size
+        return self._stored("energy_nj").size
 
     def circuit_index(self, circuit: str | int) -> int:
         if isinstance(circuit, int):
@@ -1286,14 +1354,7 @@ class SuiteVariationGrid(_LazyArrays):
             recipes=self.recipes,
             topologies=self.topologies,
             models=self.models,
-            cycles=self._raw("cycles")[c],
-            active_macro_cycles=self._raw("active_macro_cycles")[c],
-            fits=self._raw("fits")[c],
-            latency_ns=self._raw("latency_ns")[c],
-            energy_nj=self._raw("energy_nj")[c],
-            power_mw=self._raw("power_mw")[c],
-            throughput_gops=self._raw("throughput_gops")[c],
-            tops_per_watt=self._raw("tops_per_watt")[c],
+            **self._views((c,), (c,)),
             area_mm2=self.area_mm2,
             feasible=self.feasible[c],
             mode=self.mode,
@@ -1308,14 +1369,7 @@ class SuiteVariationGrid(_LazyArrays):
             circuits=self.circuits,
             recipes=self.recipes,
             topologies=self.topologies,
-            cycles=self._raw("cycles"),
-            active_macro_cycles=self._raw("active_macro_cycles"),
-            fits=self._raw("fits"),
-            latency_ns=self._raw("latency_ns")[:, v],
-            energy_nj=self._raw("energy_nj")[:, v],
-            power_mw=self._raw("power_mw")[:, v],
-            throughput_gops=self._raw("throughput_gops")[:, v],
-            tops_per_watt=self._raw("tops_per_watt")[:, v],
+            **self._views((), (slice(None), v)),
             area_mm2=self.area_mm2[v],
             feasible=self.feasible,
             mode=self.mode,
@@ -1395,7 +1449,7 @@ def _build_suite_grid(
             discipline=discipline,
             model=model if isinstance(model, EnergyModel) else table.model(0),
             **sched,
-            **{k: v[:, 0] for k, v in mets.items()},
+            **{k: _view(v, (slice(None), 0)) for k, v in mets.items()},
         )
     return SuiteVariationGrid(
         circuits=suite.circuits,

@@ -47,6 +47,7 @@ import numpy as np
 
 from .aig import Aig, AigStats
 from .batch import (
+    VIEW_COUNTS,
     ExplorationGrid,
     SelectionResult,
     SuiteTable,
@@ -596,7 +597,8 @@ def explore_suite(
                     mode=mode, discipline=discipline, feasible=feas_mask,
                 )
 
-        with trace.span("explore.assemble"):
+        with trace.span("explore.assemble") as span:
+            views, slices = VIEW_COUNTS["views"], VIEW_COUNTS["slices"]
             out = {}
             wall = (time.time() - t0) / max(1, len(names))
             if sel is not None:
@@ -650,7 +652,11 @@ def explore_suite(
                     cha=cha[name],
                     variation=variation,
                 )
-            return out
+            # views made here, and those of their slices dispatched to the
+            # device here: none where nothing reads a lazy view's field
+            span.set_metadata(views=VIEW_COUNTS["views"] - views,
+                              view_slices=VIEW_COUNTS["slices"] - slices)
+        return out
 
 
 def explore_request(

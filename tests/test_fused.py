@@ -30,7 +30,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import circuits as C
+from repro.core import batch, circuits as C
 from repro.core.aig import AigStats
 from repro.core.batch import (
     SuiteTable,
@@ -253,14 +253,58 @@ def test_one_device_sharded_is_bit_identical(workloads):
         )
 
 
-def test_lazy_grid_materializes_identically(workloads):
+LAZY_KEYS = METRIC_KEYS + ("cycles", "active_macro_cycles", "fits")
+
+# view -> (swept over a ModelTable, make it from the suite grid, the
+# arguments of its ``cell``, the deferred views it makes)
+LAZY_VIEWS = {
+    "variation": (True, lambda g: g.variation("b"), (1, 2, 3), 1),
+    "variation.grid": (True, lambda g: g.variation("b").grid(2), (2, 3), 2),
+    "suite": (True, lambda g: g.suite(1), ("b", 2, 3), 1),
+    "suite.grid": (True, lambda g: g.suite(1).grid("b"), (2, 3), 2),
+    "nominal.grid": (False, lambda g: g.grid("b"), (2, 3), 1),
+}
+
+
+def _check_lazy_view(suite, topos, model, make, at, n_views):
+    lazy_root, _ = evaluate_select_suite(suite, topos, model, lazy=True)
+    eager_root, _ = evaluate_select_suite(suite, topos, model, lazy=False)
+    made = dict(batch.VIEW_COUNTS)
+    lazy, eager = make(lazy_root), make(eager_root)
+    # making a view (of a view) dispatches no slice; numpy-backed grids
+    # make plain numpy views, which are not counted
+    assert batch.VIEW_COUNTS == dict(
+        views=made["views"] + n_views, slices=made["slices"])
+    assert lazy._stored("energy_nj").shape == eager.energy_nj.shape
+    # a cell gathers single elements of the root at the composed index
+    assert lazy.cell(*at) == eager.cell(*at)
+    assert batch.VIEW_COUNTS["slices"] == made["slices"]
+    # _raw dispatches the one slice: a device array with its placement
+    raw = lazy._raw("energy_nj")
+    assert not isinstance(raw, np.ndarray) and raw.sharding.device_set
+    assert batch.VIEW_COUNTS["slices"] == made["slices"] + 1
+    assert lazy._raw("energy_nj") is raw  # resolved once, cached
+    for k in LAZY_KEYS:
+        got, want = getattr(lazy, k), getattr(eager, k)
+        assert isinstance(got, np.ndarray), k
+        assert (got.dtype, got.shape) == (want.dtype, want.shape), k
+        assert got.tobytes() == want.tobytes(), k
+
+
+@pytest.mark.parametrize("view", [None, *LAZY_VIEWS])
+def test_lazy_grid_materializes_identically(workloads, view):
     _, suite, topos = workloads
     table = ModelTable.monte_carlo(n=3, sigma=0.2, seed=5)
+    if view is not None:
+        swept, make, at, n_views = LAZY_VIEWS[view]
+        model = table if swept else EnergyModel()
+        _check_lazy_view(suite, topos, model, make, at, n_views)
+        return
     lazy_grid, _ = evaluate_select_suite(suite, topos, table, lazy=True)
     eager_grid, _ = evaluate_select_suite(suite, topos, table, lazy=False)
     # before access the lazy fields are device arrays, not numpy
     assert not isinstance(lazy_grid._raw("energy_nj"), np.ndarray)
-    for k in METRIC_KEYS + ("cycles", "active_macro_cycles", "fits"):
+    for k in LAZY_KEYS:
         np.testing.assert_array_equal(
             getattr(lazy_grid, k), getattr(eager_grid, k)
         )

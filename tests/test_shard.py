@@ -15,6 +15,9 @@ compare what it prints:
   * sampled variants' winners are the scalar path's, energies within
     1e-12 relative;
   * V=6 runs on three devices and stays exact;
+  * lazy views of the sharded grid, composed ones among them, dispatch
+    no slice when made and materialize bit-identical to the unsharded
+    grid's, and a view's dispatched slice spans the four devices;
   * ``rcim.batch.dispatch``/``.shard``/``.fetch`` carry the devices and
     the variants per device, and ``placed_bytes`` counts each operand
     the program is given once per device it reaches.
@@ -38,6 +41,15 @@ SAMPLED = (0, 3, 7)
 #: Rounding of the scalar path's float64 arithmetic against the batched
 #: kernel's: a few ulps, far below any gap between two designs.
 SCALAR_REL_TOL = 1e-12
+LAZY_KEYS = ("cycles", "active_macro_cycles", "fits", "latency_ns", "energy_nj",
+             "power_mw", "throughput_gops", "tops_per_watt")
+#: Lazy views of the suite grid, composed ones among them.
+VIEWS = {
+    "variation": lambda g: g.variation("max"),
+    "variation.grid": lambda g: g.variation("max").grid(3),
+    "suite": lambda g: g.suite(5),
+    "suite.grid": lambda g: g.suite(5).grid("log2"),
+}
 
 
 def _explore_fields(res: dict) -> dict:
@@ -110,6 +122,25 @@ def _measure(tmp: Path) -> dict:
                  for name, rtl in suite.items()}
         for v in SAMPLED}
 
+    # composed lazy views over the variant-sharded grid against the same
+    # views of the unsharded one; making them dispatches no slice
+    sharded, _ = batch.evaluate_select_suite(*tables, table, shard=None)
+    plain, _ = batch.evaluate_select_suite(*tables, table, shard=False)
+    out["views"] = {}
+    for name, make in VIEWS.items():
+        made = dict(batch.VIEW_COUNTS)
+        view, ref = make(sharded), make(plain)
+        made_views = batch.VIEW_COUNTS["views"] - made["views"]
+        made_slices = batch.VIEW_COUNTS["slices"] - made["slices"]
+        devices = len(view._raw("energy_nj").sharding.device_set)
+        out["views"][name] = dict(
+            made_views=made_views, made_slices=made_slices, devices=devices,
+            fields={k: [str(getattr(view, k).dtype), getattr(view, k).shape,
+                        getattr(view, k).tobytes() == getattr(ref, k).tobytes()]
+                    for k in LAZY_KEYS},
+            ref={k: [str(getattr(ref, k).dtype), getattr(ref, k).shape]
+                 for k in LAZY_KEYS})
+
     # the operands the fused program is given, by placement: host arrays
     # (replicated to every device) and arrays already laid over devices
     fused = batch._fused_kernels()[1]
@@ -178,6 +209,18 @@ def test_indivisible_variants_fall_back_to_three_devices_exactly(four):
     assert four["odd_sharded"]["selection"] == four["odd_plain"]["selection"]
     (shard,) = four["odd_spans"]["rcim.batch.shard"]
     assert shard == {"devices": 3, "variants_per_device": V_ODD // 3}
+
+
+@pytest.mark.parametrize("view", VIEWS)
+def test_composed_views_over_sharded_arrays_are_bit_identical(four, view):
+    got = four["views"][view]
+    # one deferred view a level for each of the two grids, no slice
+    assert got["made_views"] == 2 * (view.count(".") + 1)
+    assert got["made_slices"] == 0
+    assert all(same for _, _, same in got["fields"].values())
+    assert {k: f[:2] for k, f in got["fields"].items()} == got["ref"]
+    # the one slice `_raw` dispatches still spans the four devices
+    assert got["devices"] == DEVICES
 
 
 @pytest.mark.parametrize("key,devices", [("spans", DEVICES), ("odd_spans", 3)])

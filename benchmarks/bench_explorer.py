@@ -186,8 +186,8 @@ def run(
             all_agree=all(c["backends_agree"] for c in per_circuit.values()),
         ),
     )
-    # Merge-preserving write: other benches (bench_variation's model
-    # sweep) own sibling top-level keys in the same json.
+    # Merge-preserving write: other benches own sibling top-level keys
+    # in the same json.
     merge_json(out_json, out)
     csv.add(
         "explorer/TOTAL", totals["jax_us"],

@@ -15,8 +15,8 @@ decoupled so the sweep stays CPU-tractable):
 Both sections ride the suite-level engine (core/batch.py): section A is
 one `explorer.explore_suite` call (suite characterization + a single
 circuits x recipes x topologies sweep); section B stacks the paper-scale
-baselines into a `SuiteTable` and runs ONE `evaluate_suite` call for all
-circuits x 12 topologies.
+baselines into a `SuiteTable` and runs ONE `evaluate_select_suite` call
+for all circuits x 12 topologies.
 """
 
 from __future__ import annotations
@@ -24,7 +24,7 @@ from __future__ import annotations
 import time
 
 from repro.core import circuits as C
-from repro.core.batch import SuiteTable, TopologyTable, evaluate_suite
+from repro.core.batch import SuiteTable, TopologyTable, evaluate_select_suite
 from repro.core.explorer import explore_suite
 from repro.core.sram import (
     MACRO_SIZES_KB,
@@ -70,7 +70,7 @@ def run(csv: Csv, scale: str = "tiny", recipes=None, backend: str = "jax",
 
     if backend == "jax":
         # ONE jitted pass: all circuits x 12 topologies (baseline recipe).
-        sg = evaluate_suite(
+        sg, _ = evaluate_select_suite(
             SuiteTable.from_cha({n: {(): s} for n, s in stats.items()}),
             topo_table, em,
         )

@@ -86,8 +86,8 @@ def run(csv: Csv, out_json: str = "BENCH_explorer.json") -> dict:
     csv.add("kernel/vmem_residency_model", 0.0,
             f"levels={levels};modeled_speedup={t_roundtrip/t_resident:.0f}x")
 
-    # Merge-preserving write: bench_explorer / bench_variation own
-    # sibling top-level keys in the same json.
+    # Merge-preserving write: the other benches own sibling top-level
+    # keys in the same json.
     merge_json(out_json, {"kernel": record})
     return record
 

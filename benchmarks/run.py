@@ -16,12 +16,12 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=["tiny", "default", "paper"], default="tiny")
     ap.add_argument("--only", default=None,
-                    help="comma list: fig9,table1,table2,variation,kernel,"
+                    help="comma list: fig9,table1,table2,kernel,"
                          "roofline,explorer,characterization,service,"
                          "system,faults")
     args = ap.parse_args()
     which = set(args.only.split(",")) if args.only else {
-        "fig9", "table1", "table2", "variation", "kernel", "roofline",
+        "fig9", "table1", "table2", "kernel", "roofline",
         "explorer", "characterization", "service", "system", "faults",
     }
 
@@ -45,21 +45,11 @@ def main() -> None:
         from . import bench_table2
 
         bench_table2.run(csv)
-    if "variation" in which:
-        from . import bench_variation
-
-        bench_variation.run(csv)
-        # energy-model variation sweep (yield FoM): vmapped vs serial,
-        # merged into runs/BENCH_explorer_variation.json
-        bench_variation.run_model_sweep(
-            csv, scale=args.scale, cache_dir=cache,
-            out_json="runs/BENCH_explorer_variation.json",
-        )
     if "kernel" in which:
         from . import bench_kernel
 
         # merged into BENCH_explorer.json under "kernel" alongside the
-        # explorer / variation / characterization sections
+        # explorer / characterization sections
         bench_kernel.run(csv, out_json="BENCH_explorer.json")
     if "characterization" in which:
         from . import bench_characterization

@@ -109,8 +109,8 @@ ann = "  # repro: host-boundary\n"
 assert ann in src, "no trailing host-boundary annotation to strip"
 with open(os.path.join(d, "strip_annotation.py"), "w") as f:
     f.write(src.replace(ann, "\n", 1))
-cnt = 'TRACE_COUNTS["schedule_grid"] += 1'
-assert cnt in src, "no schedule_grid trace-counter increment to strip"
+cnt = 'TRACE_COUNTS["select_batch"] += 1'
+assert cnt in src, "no select_batch trace-counter increment to strip"
 with open(os.path.join(d, "strip_counter.py"), "w") as f:
     f.write(src.replace(cnt, "", 1))
 EOF
@@ -185,55 +185,6 @@ print(f"characterization: python cold {cha['python_cold_s']}s, device "
       f"stats + all transform fingerprints")
 EOF
 
-    echo "== model-variation sweep bench (smoke) =="
-    python -m benchmarks.bench_variation --smoke --skip-pvt \
-        --out runs/BENCH_explorer_smoke.json
-    python - <<'EOF'
-import json
-with open("runs/BENCH_explorer_smoke.json") as f:
-    v = json.load(f)["variation"]
-assert v["all_agree"], \
-    "backends disagree on a (circuit, variant) winner"
-assert v["python_winners_checked"] > 0, "python cross-check did not run"
-assert v["speedup"] > 1.0, \
-    f"vmapped model sweep ({v['sweep_us']}us) must beat the serial " \
-    f"per-model loop ({v['serial_us']}us)"
-assert v["compiles"] == 1, \
-    f"an N-variant sweep must cost exactly one jit trace, got {v['compiles']}"
-assert v["recompiles_on_float_change"] == 0, \
-    "changing only model floats retriggered tracing"
-assert v["selection_agree"], \
-    "batched selection disagrees with the per-(circuit, variant) " \
-    "select_best loop"
-assert v["selection_speedup"] > 1.0, \
-    f"batched selection ({v['selection_batched_us']}us) must beat the " \
-    f"per-variant loop ({v['selection_loop_us']}us)"
-assert v["correlated_agree"], \
-    "correlated (V, T) sweep: batched winners disagree with the loop"
-assert v["correlated_compiles"] == 1, \
-    f"a correlated (V, T) sweep must cost exactly one jit trace, " \
-    f"got {v['correlated_compiles']}"
-assert v["fused_agree"], \
-    "fused on-device selection disagrees with host select_best_batch " \
-    "on a (circuit, variant) winner"
-assert v["fused_compiles"] == 1, \
-    f"the fused evaluate+select sweep must cost exactly one jit " \
-    f"trace, got {v['fused_compiles']}"
-assert v["payload_fused_bytes"] < v["payload_host_bytes"], \
-    f"fused device->host payload ({v['payload_fused_bytes']}B) must " \
-    f"shrink vs the full-tensor transfer ({v['payload_host_bytes']}B)"
-print(f"model sweep: {v['n_variants']} variants x "
-      f"{v['implementations'] // v['n_variants']} designs in "
-      f"{v['sweep_us']:.0f}us, serial {v['serial_us']:.0f}us "
-      f"-> {v['speedup']}x, compiles={v['compiles']}; "
-      f"selection {v['selection_loop_us']:.0f}us -> "
-      f"{v['selection_batched_us']:.0f}us "
-      f"({v['selection_speedup']}x); correlated sweep "
-      f"compiles={v['correlated_compiles']}; fused pipeline "
-      f"payload {v['payload_host_bytes']}B -> {v['payload_fused_bytes']}B "
-      f"({v['payload_shrink']}x), {v['host_us']:.0f}us -> "
-      f"{v['fused_us']:.0f}us, compiles={v['fused_compiles']}")
-EOF
     echo "== system bench (smoke, rCiM vs conventional roofline per token) =="
     python -m benchmarks.bench_system --smoke \
         --out runs/BENCH_explorer_smoke.json

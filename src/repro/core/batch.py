@@ -9,32 +9,31 @@ ChaAIG -> Evaluate -> FilterEnergy sweep is one jitted `jax.numpy` pass:
 
   * ``TopologyTable``  — the SRAM topology library stacked into arrays
     (rows, cols, macro counts, total bits, sense-amp widths);
-  * ``WorkloadTable``  — the characterized recipes stacked into a
-    ``(n_recipes, n_levels, n_op_types)`` op-count tensor;
-  * ``schedule_batch`` — `mapping.schedule_stats` (both the "list" and
-    "levels" disciplines) over the full recipe x topology grid;
-  * ``evaluate_batch`` — `sram.evaluate` (both "paper" and "physical"
-    accounting modes) over the grid, yielding an ``ExplorationGrid`` —
-    or, given a `sram.ModelTable`, a ``VariationGrid`` with a leading
-    model-variant axis;
+  * ``WorkloadTable`` / ``SuiteTable`` — the characterized recipes of
+    one circuit / a whole suite stacked into op-count tensors
+    (``(R, L, 3)`` / ``(C, R, L, 3)``);
+  * ``evaluate_select_suite`` — the one back-half program: schedule
+    (`mapping.schedule_stats`, both the "list" and "levels"
+    disciplines), evaluate (`sram.evaluate`, both "paper" and
+    "physical" accounting modes) and the three-tier FilterEnergy argmin
+    over circuits x model variants x topologies x recipes in one jitted
+    **device-resident** pass.  The device returns a ``SelectionResult``
+    (winner indices + per-winner metrics, a few KB) instead of the full
+    float64 metric tensors, and the returned grid (``SuiteGrid`` for
+    one `EnergyModel`, ``SuiteVariationGrid`` for a `sram.ModelTable`)
+    is *lazy* (`_LazyArrays`) — its tensors stay on device until first
+    access.  A single circuit is a one-circuit suite.  The variant axis
+    optionally shards across devices (`_shard_variants`);
   * ``select_best`` / ``select_best_batch`` / ``select_best_worst`` —
-    the shared capacity / latency admissibility filter + energy
-    argmin/argmax used by `explorer`, `mesh_explorer`, and the
-    benchmarks.  ``select_best_batch`` is the batched filter: winners
-    for every (circuit, variant) cell of a variation sweep in one masked
+    the host-side capacity / latency admissibility filter + energy
+    argmin/argmax: the parity reference the tests compare the fused
+    winners against, and the filter `mesh_explorer` and
+    `explorer.best_worst` use.  ``select_best_batch`` is the batched
+    form: winners for every (circuit, variant) cell in one masked
     three-tier argmin pass (non-finite energies are inadmissible in
-    every tier), so the selection stage scales with the evaluate stage
-    instead of looping per variant in python;
-  * ``evaluate_select_batch`` / ``evaluate_select_suite`` — the fused
-    **device-resident** pipeline: the same three-tier argmin runs as
-    pure-jnp ops inside the jitted evaluate kernel, the device returns a
-    ``SelectionResult`` (winner indices + per-winner metrics, a few KB)
-    instead of the full float64 metric tensors, and the returned grids
-    are *lazy* (`_LazyArrays`) — their tensors stay on device until
-    first access.  The variant axis optionally shards across devices
-    (`_shard_variants`); ``select_best_batch`` stays as the host-side
-    parity reference.  ``select_best_batch_device`` is the standalone
-    jitted filter for precomputed metric arrays (mesh explorer).
+    every tier).  ``select_best_batch_device`` is the standalone jitted
+    filter for precomputed metric arrays (mesh explorer, service
+    re-rank).
 
 Parity contract: every cycle/flag quantity is exact integer arithmetic,
 and the energy expressions are the *same functions* the scalar path uses
@@ -408,8 +407,7 @@ class SuiteTable:
     def bucket_shape(self, n_topologies: int, n_variants: int = 1) -> tuple:
         """The jit-trace bucket this table compiles under (see
         `bucket_suite`): ``(C, R, L_pad, T, V)``.  Two suites with equal
-        bucket shapes reuse one compiled `evaluate_suite` /
-        `evaluate_select_suite` trace."""
+        bucket shapes reuse one compiled `evaluate_select_suite` trace."""
         c, r, l, _ = self.ops.shape
         return (c, r, l, int(n_topologies), int(n_variants))
 
@@ -531,7 +529,7 @@ def bucket_suite(
 
 
 # ---------------------------------------------------------------------------
-# Jitted grid kernels
+# Schedule + evaluate math, traced inside the fused program
 # ---------------------------------------------------------------------------
 
 
@@ -596,16 +594,6 @@ def _schedule_core(ops, n_levels, width, mpt, is_single, total_bits, rows,
         rows_needed <= rows[None, :]
     )
     return cycles, active, fits
-
-
-def _make_schedule_grid():
-    def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, discipline):
-        TRACE_COUNTS["schedule_grid"] += 1
-        return _schedule_core(
-            ops, n_levels, width, mpt, is_single, total_bits, rows, discipline
-        )
-
-    return jax.jit(fn, static_argnames=("discipline",))
 
 
 def _evaluate_core(ops, n_levels, width, mpt, is_single, total_bits, rows,
@@ -674,74 +662,8 @@ def _evaluate_core(ops, n_levels, width, mpt, is_single, total_bits, rows,
     return out
 
 
-def _make_evaluate_grid():
-    def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
-           params, discipline, mode):
-        TRACE_COUNTS["evaluate_grid"] += 1
-        return _evaluate_core(
-            ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
-            params, discipline, mode,
-        )
-
-    return jax.jit(fn, static_argnames=("discipline", "mode"))
-
-
-def _make_schedule_suite():
-    def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, discipline):
-        TRACE_COUNTS["schedule_suite"] += 1
-
-        def per_circuit(o, nl):
-            return _schedule_core(
-                o, nl, width, mpt, is_single, total_bits, rows, discipline
-            )
-
-        return jax.vmap(per_circuit)(ops, n_levels)
-
-    return jax.jit(fn, static_argnames=("discipline",))
-
-
-def _make_evaluate_suite():
-    def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
-           params, discipline, mode):
-        TRACE_COUNTS["evaluate_suite"] += 1
-
-        def per_circuit(o, nl):
-            return _evaluate_core(
-                o, nl, width, mpt, is_single, total_bits, rows, cols,
-                params, discipline, mode,
-            )
-
-        return jax.vmap(per_circuit)(ops, n_levels)
-
-    return jax.jit(fn, static_argnames=("discipline", "mode"))
-
-
-_SCHEDULE_GRID = None
-_EVALUATE_GRID = None
-_SCHEDULE_SUITE = None
-_EVALUATE_SUITE = None
-
-
-def _grids():
-    global _SCHEDULE_GRID, _EVALUATE_GRID
-    _load_jax()
-    if _SCHEDULE_GRID is None:
-        _SCHEDULE_GRID = _make_schedule_grid()
-        _EVALUATE_GRID = _make_evaluate_grid()
-    return _SCHEDULE_GRID, _EVALUATE_GRID
-
-
-def _suite_grids():
-    global _SCHEDULE_SUITE, _EVALUATE_SUITE
-    _load_jax()
-    if _SCHEDULE_SUITE is None:
-        _SCHEDULE_SUITE = _make_schedule_suite()
-        _EVALUATE_SUITE = _make_evaluate_suite()
-    return _SCHEDULE_SUITE, _EVALUATE_SUITE
-
-
 # ---------------------------------------------------------------------------
-# Public batched API
+# Sweep grids
 # ---------------------------------------------------------------------------
 
 
@@ -749,7 +671,7 @@ _SCHED_KEYS = ("cycles", "active_macro_cycles", "fits")
 _METRIC_KEYS = (
     "latency_ns", "energy_nj", "power_mw", "throughput_gops", "tops_per_watt"
 )
-# Grid fields that may hold device-resident (jax) arrays in lazy mode.
+# Grid fields that hold device-resident (jax) arrays until first read.
 _LAZY_FIELDS = frozenset(_SCHED_KEYS + _METRIC_KEYS)
 
 # Deferred views made by the grids' view methods, and deferred slices
@@ -810,11 +732,11 @@ def _view(a, idx: tuple):
 
 
 class _LazyArrays:
-    """Mixin for the grid dataclasses: metric/schedule fields may hold
-    *device* (jax) arrays instead of numpy — the lazy mode of the fused
-    pipeline.  A field is materialized to numpy on first attribute access
-    and cached in place (the dataclasses are frozen, so the swap goes
-    through ``object.__setattr__``), which means a grid that is never
+    """Mixin for the grid dataclasses: metric/schedule fields hold
+    *device* (jax) arrays, as the fused program returned them, until
+    they are read.  A field is materialized to numpy on first attribute
+    access and cached in place (the dataclasses are frozen, so the swap
+    goes through ``object.__setattr__``), which means a grid that is never
     inspected never pays the device->host transfer: the fused selection
     already moved the winners across, and the full (C, V, T, R) tensors
     stay where they were computed.
@@ -823,7 +745,7 @@ class _LazyArrays:
     grids deferred slices (`_Slice`) of the device arrays, so making a
     view does no device work either: a child's field is sliced on the
     device when it is first read, through ``_raw`` or an attribute, and
-    numpy-backed grids keep plain numpy views.
+    views of materialized (numpy) fields are plain numpy views.
     """
 
     def __getattribute__(self, name):
@@ -983,8 +905,9 @@ class VariationGrid(_LazyArrays):
     Schedules (``cycles`` / ``active_macro_cycles`` / ``fits``) are
     model-free exact integers, stored once as ``(T, R)``; each metric
     carries a leading variant axis ``(V, T, R)``.  ``grid(v)`` slices
-    variant ``v`` back out as a standard `ExplorationGrid` (numpy views,
-    or deferred device slices of a lazy grid — see `_LazyArrays`).
+    variant ``v`` back out as a standard `ExplorationGrid` (deferred
+    device slices of a lazy grid, numpy views once materialized — see
+    `_LazyArrays`).
     """
 
     recipes: tuple[tuple[str, ...], ...]
@@ -1075,142 +998,14 @@ class VariationGrid(_LazyArrays):
         )
 
 
-def schedule_batch(
-    work: WorkloadTable,
-    topos: TopologyTable,
-    discipline: str = "list",
-) -> dict[str, np.ndarray]:
-    """``mapping.schedule_stats`` over the full grid in one jitted pass.
-
-    Returns ``(n_topologies, n_recipes)`` arrays: ``cycles``,
-    ``active_macro_cycles``, ``fits``.  (Pipelined writeback only — the
-    scalar path's default.)  Schedules are model-free, so there is no
-    variant axis here.
-    """
-    schedule_grid, _ = _grids()
-    with jax_env.x64():
-        cycles, active, fits = schedule_grid(
-            work.ops, work.n_levels, topos.ops_per_cycle,
-            topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, discipline,
-        )
-        return dict(
-            cycles=np.asarray(cycles).T,  # repro: host-boundary
-            active_macro_cycles=np.asarray(active).T,  # repro: host-boundary
-            fits=np.asarray(fits).T,  # repro: host-boundary
-        )
-
-
-def _grid_feasible(topos, feasible) -> np.ndarray:
-    if feasible is None:
-        feasible = np.ones(len(topos), dtype=bool)
-    return np.asarray(feasible, dtype=bool)
-
-
-def _layout_outputs(out, lazy):
-    """Kernel outputs ((..., R, T)-major) -> final (..., T, R) layout
-    schedule/metric dicts; ``lazy`` keeps them device-resident."""
-    conv = (lambda a: a) if lazy else np.asarray
-    return (
-        {k: conv(jnp.swapaxes(out[k], -1, -2)) for k in _SCHED_KEYS},
-        {k: conv(jnp.swapaxes(out[k], -1, -2)) for k in _METRIC_KEYS},
-    )
-
-
-def _fused_outputs(res, lazy):
-    """The fused kernels' schedule/metric dicts (already final-layout);
-    ``lazy`` keeps them device-resident."""
-    conv = (lambda a: a) if lazy else np.asarray
-    return (
-        {k: conv(res["sched"][k]) for k in _SCHED_KEYS},
-        {k: conv(res["mets"][k]) for k in _METRIC_KEYS},
-    )
-
-
-def _build_grid(
-    work, topos, table, model, is_sweep, mode, discipline, feasible,
-    sched, mets,
-) -> "ExplorationGrid | VariationGrid":
-    """Assemble the single-circuit grid result from (possibly
-    device-resident) schedule/metric arrays."""
-    if not is_sweep:
-        return ExplorationGrid(
-            recipes=work.recipes,
-            topologies=topos.topologies,
-            area_mm2=topos.area_mm2(table.model(0)),
-            feasible=feasible,
-            mode=mode,
-            discipline=discipline,
-            model=model if isinstance(model, EnergyModel) else table.model(0),
-            **sched,
-            **{k: _view(v, (0,)) for k, v in mets.items()},
-        )
-    return VariationGrid(
-        recipes=work.recipes,
-        topologies=topos.topologies,
-        models=table,
-        area_mm2=topos.area_mm2(table),
-        feasible=feasible,
-        mode=mode,
-        discipline=discipline,
-        **sched,
-        **mets,
-    )
-
-
-def evaluate_batch(
-    work: WorkloadTable,
-    topos: TopologyTable,
-    model: "EnergyModel | ModelTable | None" = None,
-    mode: str = "physical",
-    discipline: str = "list",
-    feasible: np.ndarray | None = None,
-    lazy: bool = False,
-) -> "ExplorationGrid | VariationGrid":
-    """Schedule + evaluate the full recipe x topology grid in one jitted
-    float64 pass; the batched ``sram.evaluate``.
-
-    ``model`` may be a single `EnergyModel` (returns an
-    `ExplorationGrid`, as before) or a `sram.ModelTable` of variants
-    (returns a `VariationGrid` with a leading variant axis).  Either way
-    the model constants are traced operands — the kernel never recompiles
-    on a model change, only on a new (grid shape, n_variants,
-    discipline, mode).
-
-    ``lazy=True`` keeps the metric tensors device-resident: the grid's
-    array fields materialize to numpy on first access instead of paying
-    the device->host transfer eagerly (see `_LazyArrays`).
-    """
-    _, evaluate_grid = _grids()
-    table, is_sweep = _as_table(model)
-    _check_topo_axis(table, topos)
-    feasible = _grid_feasible(topos, feasible)
-    with jax_env.x64():
-        out = evaluate_grid(
-            work.ops, work.n_levels, topos.ops_per_cycle,
-            topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, topos.cols, _model_params(table), discipline, mode,
-        )
-        sched, mets = _layout_outputs(out, lazy)
-        return _build_grid(
-            work, topos, table, model, is_sweep, mode, discipline,
-            feasible, sched, mets,
-        )
-
-
-# ---------------------------------------------------------------------------
-# Suite-level sweep: circuits x recipes x topologies in one jitted call
-# ---------------------------------------------------------------------------
-
-
 @dataclasses.dataclass(frozen=True)
 class SuiteGrid(_LazyArrays):
     """The whole-suite sweep as ``(n_circuits, n_topologies, n_recipes)``
     arrays — one `ExplorationGrid` per circuit, stacked.
 
-    Produced by `evaluate_suite`; ``grid(circuit)`` slices one circuit
-    back out as a standard `ExplorationGrid` (numpy views, no copies; a
-    lazy grid's views are deferred device slices), so
+    Produced by `evaluate_select_suite`; ``grid(circuit)`` slices one
+    circuit back out as a standard `ExplorationGrid` (deferred device
+    slices of a lazy grid, numpy views once materialized), so
     everything downstream of the per-circuit sweep (``best_index``,
     `select_best`, `explorer.best_worst`) works unchanged.
     """
@@ -1280,28 +1075,6 @@ class SuiteGrid(_LazyArrays):
             throughput_gops=g("throughput_gops", (c, t, r)),
             tops_per_watt=g("tops_per_watt", (c, t, r)),
             area_mm2=float(np.asarray(self._raw("area_mm2")[t])),  # repro: host-boundary
-        )
-
-
-def schedule_suite(
-    suite: SuiteTable,
-    topos: TopologyTable,
-    discipline: str = "list",
-) -> dict[str, np.ndarray]:
-    """`schedule_batch` vmapped over the circuit axis: one jitted pass
-    computing ``(n_circuits, n_topologies, n_recipes)`` ``cycles`` /
-    ``active_macro_cycles`` / ``fits`` arrays for the whole suite."""
-    schedule, _ = _suite_grids()
-    with jax_env.x64():
-        cycles, active, fits = schedule(
-            suite.ops, suite.n_levels, topos.ops_per_cycle,
-            topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, discipline,
-        )
-        return dict(
-            cycles=np.swapaxes(np.asarray(cycles), 1, 2),  # repro: host-boundary
-            active_macro_cycles=np.swapaxes(np.asarray(active), 1, 2),  # repro: host-boundary
-            fits=np.swapaxes(np.asarray(fits), 1, 2),  # repro: host-boundary
         )
 
 
@@ -1465,57 +1238,15 @@ def _build_suite_grid(
     )
 
 
-def evaluate_suite(
-    suite: SuiteTable,
-    topos: TopologyTable,
-    model: "EnergyModel | ModelTable | None" = None,
-    mode: str = "physical",
-    discipline: str = "list",
-    feasible: np.ndarray | None = None,
-    lazy: bool = False,
-) -> "SuiteGrid | SuiteVariationGrid":
-    """Schedule + evaluate circuits x recipes x topologies in one jitted
-    float64 pass — the suite-level `evaluate_batch`.
-
-    ``model`` may be a single `EnergyModel` (returns a `SuiteGrid`) or a
-    `sram.ModelTable` (returns a `SuiteVariationGrid` with a leading
-    variant axis on every metric): the model constants are traced
-    operands, so the whole circuits x variants x topologies x recipes
-    hypercube is one compile and one device call.
-
-    ``feasible``: optional ``(n_circuits, n_topologies)`` bool mask of
-    capacity-feasible topologies per circuit (Alg. I line 9); defaults to
-    all-feasible, as in `evaluate_batch`.
-
-    ``lazy=True`` keeps the metric tensors device-resident (materialized
-    to numpy on first access — see `_LazyArrays`).
-    """
-    _, evaluate = _suite_grids()
-    table, is_sweep = _as_table(model)
-    _check_topo_axis(table, topos)
-    feasible = _suite_feasible(suite, topos, feasible)
-    with jax_env.x64():
-        out = evaluate(
-            suite.ops, suite.n_levels, topos.ops_per_cycle,
-            topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, topos.cols, _model_params(table), discipline, mode,
-        )
-        sched, mets = _layout_outputs(out, lazy)
-        return _build_suite_grid(
-            suite, topos, table, model, is_sweep, mode, discipline,
-            feasible, sched, mets,
-        )
-
-
 # ---------------------------------------------------------------------------
 # Device-resident pipeline: fused evaluate + select, variant sharding
 # ---------------------------------------------------------------------------
 #
-# The host-side `select_best_batch` below pulls the full (C, V, T, R)
-# metric tensors off the device and reduces them to (C, V) winner
-# indices — for a large Monte-Carlo sweep the dominant cost is the
+# The host-side `select_best_batch` below needs the full (C, V, T, R)
+# metric tensors on the host to reduce them to (C, V) winner indices —
+# for a large Monte-Carlo sweep the dominant cost would be the
 # device->host transfer of data that is immediately thrown away.  The
-# fused kernels run the same three-tier masked argmin *inside* the
+# fused program runs the same three-tier masked argmin *inside* the
 # jitted evaluate pass, so only the winners + per-winner metrics cross
 # the host boundary; the full tensors stay device-resident and back the
 # lazy grids.  `select_best_batch` remains the parity reference the
@@ -1596,19 +1327,6 @@ def _jit_fused(fn):
     return jax.jit(fn, static_argnames=("discipline", "mode", "use_latency"))
 
 
-def _make_fused_grid():
-    def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
-           params, feasible, max_latency, discipline, mode, use_latency):
-        TRACE_COUNTS["fused_grid"] += 1
-        out = _evaluate_core(
-            ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
-            params, discipline, mode,
-        )
-        return _fused_tail(out, feasible, max_latency, use_latency)
-
-    return _jit_fused(fn)
-
-
 def _make_fused_suite():
     def fn(ops, n_levels, width, mpt, is_single, total_bits, rows, cols,
            params, feasible, max_latency, discipline, mode, use_latency):
@@ -1626,17 +1344,24 @@ def _make_fused_suite():
     return _jit_fused(fn)
 
 
-_FUSED_GRID = None
 _FUSED_SUITE = None
 
 
-def _fused_kernels():
-    global _FUSED_GRID, _FUSED_SUITE
+def _fused_program():
+    global _FUSED_SUITE
     _load_jax()
-    if _FUSED_GRID is None:
-        _FUSED_GRID = _make_fused_grid()
+    if _FUSED_SUITE is None:
         _FUSED_SUITE = _make_fused_suite()
-    return _FUSED_GRID, _FUSED_SUITE
+    return _FUSED_SUITE
+
+
+def _fused_outputs(res):
+    """The fused program's schedule/metric dicts (already final-layout),
+    left on the device for the lazy grids."""
+    return (
+        {k: res["sched"][k] for k in _SCHED_KEYS},
+        {k: res["mets"][k] for k in _METRIC_KEYS},
+    )
 
 
 def _host_nbytes(operands) -> int:
@@ -1690,9 +1415,8 @@ class SelectionResult:
     (C, V, T, R) tensors.
 
     ``winner_idx`` holds flat topology-major indices (``grid.unravel``
-    decodes them), shaped ``(V,)`` for a single-circuit sweep and
-    ``(C, V)`` for a suite (V=1 when a single `EnergyModel` was
-    evaluated).  ``nominal_latency_ns`` / ``nominal_fits`` are each
+    decodes them), shaped ``(C, V)`` (V=1 when a single `EnergyModel`
+    was evaluated).  ``nominal_latency_ns`` / ``nominal_fits`` are each
     variant's latency / the capacity flag at the *nominal* (variant-0)
     winner cell — the inputs of the latency-yield figure.
     ``payload_bytes`` is the actual number of bytes materialized to
@@ -1741,50 +1465,6 @@ def _fetch_selection(res, sharded: bool) -> SelectionResult:
     )
 
 
-def evaluate_select_batch(
-    work: WorkloadTable,
-    topos: TopologyTable,
-    model: "EnergyModel | ModelTable | None" = None,
-    mode: str = "physical",
-    discipline: str = "list",
-    feasible: np.ndarray | None = None,
-    max_latency_ns: float | None = None,
-    lazy: bool = True,
-    shard: "bool | None" = None,
-) -> "tuple[ExplorationGrid | VariationGrid, SelectionResult]":
-    """`evaluate_batch` with the FilterEnergy stage fused into the same
-    jitted pass: schedule, evaluate, and the three-tier masked argmin run
-    on device, and only the (V,) winner indices + per-winner metrics are
-    transferred.  The grid is returned lazy by default — its full metric
-    tensors stay device-resident until (unless) someone reads them.
-
-    ``shard`` controls multi-device execution of the variant axis (see
-    `_shard_variants`); the single-device path is bit-identical to
-    `evaluate_batch` + `select_best_batch`.
-    """
-    fused_grid, _ = _fused_kernels()
-    table, is_sweep = _as_table(model)
-    _check_topo_axis(table, topos)
-    feasible = _grid_feasible(topos, feasible)
-    use_latency = max_latency_ns is not None
-    with jax_env.x64():
-        params, sharded = _shard_variants(_model_params(table), shard)
-        res = fused_grid(
-            work.ops, work.n_levels, topos.ops_per_cycle,
-            topos.macros_per_type, topos.is_single, topos.total_bits,
-            topos.rows, topos.cols, params, feasible,
-            np.float64(max_latency_ns if use_latency else 0.0),
-            discipline, mode, use_latency,
-        )
-        sel = _fetch_selection(res, sharded)
-        sched, mets = _fused_outputs(res, lazy)
-        grid = _build_grid(
-            work, topos, table, model, is_sweep, mode, discipline,
-            feasible, sched, mets,
-        )
-    return grid, sel
-
-
 def evaluate_select_suite(
     suite: SuiteTable,
     topos: TopologyTable,
@@ -1793,21 +1473,27 @@ def evaluate_select_suite(
     discipline: str = "list",
     feasible: np.ndarray | None = None,
     max_latency_ns: float | None = None,
-    lazy: bool = True,
     shard: "bool | None" = None,
 ) -> "tuple[SuiteGrid | SuiteVariationGrid, SelectionResult]":
-    """The suite-level fused pipeline: circuits x variants x topologies x
-    recipes evaluated AND filtered in one jitted device call.  Only the
-    ``(C, V)`` winner indices + per-winner metrics cross the host
-    boundary; the full metric tensors back the returned lazy grid and
-    are materialized only on access.
+    """The back half of every sweep: circuits x variants x topologies x
+    recipes scheduled, evaluated AND filtered in one jitted device call.
+    A single circuit is a one-circuit suite.  Only the ``(C, V)`` winner
+    indices + per-winner metrics cross the host boundary; the full
+    metric tensors back the returned lazy grid and are materialized
+    only on access.
 
-    Winner parity with the host path (`evaluate_suite` +
-    `SuiteVariationGrid.best_indices`) is exact — same tiering, same
-    lowest-flat-index tie-breaking, same all-non-finite error — and is
-    pinned by tests/test_fused.py.
+    ``model`` is one `EnergyModel` (a `SuiteGrid`, V=1) or a
+    `sram.ModelTable` (a `SuiteVariationGrid`); ``feasible`` is an
+    optional ``(n_circuits, n_topologies)`` bool mask of
+    capacity-feasible topologies per circuit (Alg. I line 9, default
+    all-feasible).
+
+    Winner parity with the host reference (`select_best_batch` over the
+    grid's materialized tensors, `SuiteVariationGrid.best_indices`) is
+    exact — same tiering, same lowest-flat-index tie-breaking, same
+    all-non-finite error — and is pinned by tests/test_fused.py.
     """
-    _, fused_suite = _fused_kernels()
+    fused_suite = _fused_program()
     table, is_sweep = _as_table(model)
     _check_topo_axis(table, topos)
     feasible = _suite_feasible(suite, topos, feasible)
@@ -1842,7 +1528,7 @@ def evaluate_select_suite(
         with trace.span("batch.fetch", devices=devices) as span:
             sel = _fetch_selection(res, sharded)
             span.set_metadata(d2h_bytes=sel.payload_bytes)
-        sched, mets = _fused_outputs(res, lazy)
+        sched, mets = _fused_outputs(res)
         grid = _build_suite_grid(
             suite, topos, table, model, is_sweep, mode, discipline,
             feasible, sched, mets,
@@ -2133,10 +1819,10 @@ def table2_batch(
 # discipline of every kernel at lint time.
 
 
-def _example_operands() -> dict:
-    """Tiny but shape-representative kernel operands: T=2 topologies,
-    R=2 recipes, L=4 levels, V=2 model variants, C=2 circuits — the same
-    dtypes and axis layout production tables carry."""
+def _ex_fused_suite():
+    """The fused program on tiny but shape-representative operands: C=2
+    circuits, R=2 recipes, L=4 levels, T=2 topologies, V=2 model
+    variants — the same dtypes and axis layout production tables carry."""
     _load_jax()
     lvl = np.array(
         [[2, 1, 0], [1, 0, 1], [1, 2, 1], [0, 1, 1]], dtype=np.int32
@@ -2152,72 +1838,25 @@ def _example_operands() -> dict:
         alpha_mw_per_level=np.full((v,), 0.01),
         pipeline_utilization=np.full((v,), 0.9),
     )
-    return dict(
-        ops=ops,
-        n_levels=np.array([4, 3], dtype=np.int32),
-        width=np.array([4, 8], dtype=np.int32),
-        mpt=np.array([[1, 1, 1], [2, 1, 1]], dtype=np.int32),
-        is_single=np.array([True, False]),
-        total_bits=np.array([1024, 4096], dtype=np.int32),
-        rows=np.array([16, 32], dtype=np.int32),
-        cols=np.array([16, 32], dtype=np.int32),
-        params=params,
-        suite_ops=np.stack([ops, ops]),                  # (C, R, L, 3)
-        suite_n_levels=np.array([[4, 3], [3, 4]], dtype=np.int32),
-        feasible=np.array([True, True]),
-        suite_feasible=np.ones((2, 2), dtype=bool),
-        max_latency=np.float64(1.0e6),
+    return _registry.KernelExample(
+        fn=_make_fused_suite(),
+        args=(
+            np.stack([ops, ops]),                        # (C, R, L, 3)
+            np.array([[4, 3], [3, 4]], dtype=np.int32),  # n_levels (C, R)
+            np.array([4, 8], dtype=np.int32),            # width
+            np.array([[1, 1, 1], [2, 1, 1]], dtype=np.int32),  # mpt
+            np.array([True, False]),                     # is_single
+            np.array([1024, 4096], dtype=np.int32),      # total_bits
+            np.array([16, 32], dtype=np.int32),          # rows
+            np.array([16, 32], dtype=np.int32),          # cols
+            params,
+            np.ones((2, 2), dtype=bool),                 # feasible (C, T)
+            np.float64(1.0e6),                           # max_latency
+        ),
+        statics={
+            "discipline": "list", "mode": "physical", "use_latency": True,
+        },
     )
-
-
-def _sched_args(o, suite):
-    ops = o["suite_ops"] if suite else o["ops"]
-    nl = o["suite_n_levels"] if suite else o["n_levels"]
-    return (
-        ops, nl, o["width"], o["mpt"], o["is_single"], o["total_bits"],
-        o["rows"],
-    )
-
-
-def _ex_schedule(maker, suite):
-    def build():
-        o = _example_operands()
-        return _registry.KernelExample(
-            fn=maker(),
-            args=_sched_args(o, suite),
-            statics={"discipline": "list"},
-        )
-
-    return build
-
-
-def _ex_evaluate(maker, suite):
-    def build():
-        o = _example_operands()
-        return _registry.KernelExample(
-            fn=maker(),
-            args=_sched_args(o, suite) + (o["cols"], o["params"]),
-            statics={"discipline": "list", "mode": "physical"},
-        )
-
-    return build
-
-
-def _ex_fused(maker, suite):
-    def build():
-        o = _example_operands()
-        feas = o["suite_feasible"] if suite else o["feasible"]
-        return _registry.KernelExample(
-            fn=maker(),
-            args=_sched_args(o, suite)
-            + (o["cols"], o["params"], feas, o["max_latency"]),
-            statics={
-                "discipline": "list", "mode": "physical",
-                "use_latency": True,
-            },
-        )
-
-    return build
 
 
 def _ex_select_batch():
@@ -2232,22 +1871,5 @@ def _ex_select_batch():
     )
 
 
-_registry.register_kernel(
-    "schedule_grid", __name__, _ex_schedule(_make_schedule_grid, False)
-)
-_registry.register_kernel(
-    "schedule_suite", __name__, _ex_schedule(_make_schedule_suite, True)
-)
-_registry.register_kernel(
-    "evaluate_grid", __name__, _ex_evaluate(_make_evaluate_grid, False)
-)
-_registry.register_kernel(
-    "evaluate_suite", __name__, _ex_evaluate(_make_evaluate_suite, True)
-)
-_registry.register_kernel(
-    "fused_grid", __name__, _ex_fused(_make_fused_grid, False)
-)
-_registry.register_kernel(
-    "fused_suite", __name__, _ex_fused(_make_fused_suite, True)
-)
+_registry.register_kernel("fused_suite", __name__, _ex_fused_suite)
 _registry.register_kernel("select_batch", __name__, _ex_select_batch)

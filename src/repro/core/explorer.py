@@ -25,15 +25,17 @@ Two backends drive the back half (ChaAIG -> Evaluate -> FilterEnergy):
     reference.  The sweep lands in ``ExplorationResult.evaluations``.
   * ``backend="jax"``    — the tensorized engine (`core/batch.py`): the
     full recipe x topology grid is scheduled, evaluated, and filtered in
-    one jitted array pass.  The sweep lands in ``ExplorationResult.grid``
-    and ``best`` is re-materialized through the scalar model for an
-    exactly-comparable `Evaluation`.
+    one jitted device pass (`batch.evaluate_select_suite`).  The sweep
+    lands in ``ExplorationResult.grid`` and ``best`` is re-materialized
+    through the scalar model for an exactly-comparable `Evaluation`.
 
 Suite-level entry point: `explore_suite` runs Algorithm I over a whole
 benchmark suite at once — the front half through
 `transforms.characterize_suite` (shared-prefix DAG, on-disk cache,
-process pool) and the back half through one `batch.evaluate_suite` call
-vmapped over circuits x recipes x topologies.
+process pool) and the back half through one `batch.evaluate_select_suite`
+call over circuits x recipes x topologies.  That one fused program is the
+back half of ``backend="jax"`` everywhere: a single circuit is a
+one-circuit suite.
 """
 
 from __future__ import annotations
@@ -49,15 +51,10 @@ from .aig import Aig, AigStats
 from .batch import (
     VIEW_COUNTS,
     ExplorationGrid,
-    SelectionResult,
     SuiteTable,
     TopologyTable,
     VariationGrid,
-    WorkloadTable,
-    evaluate_batch,
-    evaluate_select_batch,
     evaluate_select_suite,
-    evaluate_suite,
     winner_summary,
 )
 from .mapping import BITS_PER_GATE, MappingResult, schedule_stats
@@ -279,7 +276,6 @@ def explore(
     cha: Mapping[tuple[str, ...], AigStats] | None = None,
     cache: "CharacterizationCache | str | os.PathLike | None" = None,
     n_jobs: int | None = 1,
-    fused: bool = True,
     cha_backend: str = "auto",
 ) -> ExplorationResult:
     """Algorithm I for one circuit.
@@ -297,8 +293,10 @@ def explore(
             reports); ``False`` restricts lines 10-13 to the two optimal
             AIGs exactly as the pseudocode does.
         max_latency_ns: optional latency admissibility bound (ns).
-        backend: ``"python"`` scalar reference loop or ``"jax"`` batched
-            grid (`core/batch.py`).
+        backend: ``"python"`` scalar reference loop or ``"jax"``: the
+            circuit as a one-circuit suite through the fused device
+            program (`batch.evaluate_select_suite`), whose lazy grid
+            stays on the device unless read.
         discipline: cycle schedule — ``"list"`` (ASAP, default) or the
             paper's lock-step ``"levels"``.
         cha: precomputed characterizations (`characterize_recipes` output,
@@ -306,10 +304,6 @@ def explore(
         cache: persistent characterization cache (path or
             `CharacterizationCache`) consulted when ``cha`` is None.
         n_jobs: process-pool width for characterization (1 = serial).
-        fused: with ``backend="jax"``, run FilterEnergy on device in the
-            same jitted pass (`batch.evaluate_select_batch`) so only the
-            winner crosses the host boundary and the grid stays lazy;
-            ``False`` keeps the host-side `select_best` path.
         cha_backend: transform engine for the *front* half —
             ``"device"`` (batched `kernels.aig_sim` truth tables),
             ``"python"`` (bigint parity reference), or ``"auto"``
@@ -340,7 +334,10 @@ def explore(
     opt_gate, opt_level, feasible = _opt_and_feasible(cha, sram_list)
 
     # Lines 10-13 (+ optional full sweep for Fig 9).
-    sweep_recipes = all_recipes if full_sweep else [opt_gate, opt_level]
+    # (the two optimal AIGs may be one recipe: it is swept once)
+    sweep_recipes = (
+        all_recipes if full_sweep else list(dict.fromkeys([opt_gate, opt_level]))
+    )
     sweep_topos = list(sram_list) if full_sweep else list(feasible)
 
     evaluations: list[Evaluation] = []
@@ -367,30 +364,23 @@ def explore(
             pool = [e for e in evaluations if e.schedule.fits] or evaluations
         best = min(pool, key=lambda e: e.metrics.energy_nj)
     else:
-        work = WorkloadTable.from_stats([(r, cha[r]) for r in sweep_recipes])
-        topo_table = TopologyTable.from_topologies(sweep_topos)
-        feas = np.array([t in feasible for t in sweep_topos], dtype=bool)
-        if fused:
-            # Device-resident back half: evaluate + FilterEnergy in one
-            # jitted pass; only the winner index leaves the device and
-            # the grid materializes lazily if anyone reads it.
-            grid, sel = evaluate_select_batch(
-                work, topo_table, model, mode=mode, discipline=discipline,
-                feasible=feas, max_latency_ns=max_latency_ns, lazy=True,
-            )
-            best_flat = int(sel.winner_idx[0])  # V=1: one winner
-        else:
-            grid = evaluate_batch(
-                work, topo_table, model, mode=mode, discipline=discipline,
-                feasible=feas,
-            )
-            best_flat = grid.best_index(max_latency_ns)
+        # A one-circuit suite through the fused device program: evaluate +
+        # FilterEnergy in one jitted pass; only the winner index leaves
+        # the device and the grid materializes lazily if anyone reads it.
+        suite = SuiteTable.from_cha({rtl.name: {r: cha[r] for r in sweep_recipes}})
+        feas = np.array([[t in feasible for t in sweep_topos]], dtype=bool)
+        sg, sel = evaluate_select_suite(
+            suite, TopologyTable.from_topologies(sweep_topos), model,
+            mode=mode, discipline=discipline, feasible=feas,
+            max_latency_ns=max_latency_ns,
+        )
+        grid = sg.grid(0)
         # Line 14 on the grid; re-materialize the winner through the scalar
         # model so `best` is exactly the object the python backend returns.
-        ti, ri = grid.unravel(best_flat)
+        ti, ri = grid.unravel(int(sel.winner_idx[0, 0]))  # C=1, V=1
+        recipe = grid.recipes[ri]
         best = _materialize(
-            sweep_recipes[ri], sweep_topos[ti], cha[sweep_recipes[ri]],
-            model, mode, discipline,
+            recipe, sweep_topos[ti], cha[recipe], model, mode, discipline,
         )
 
     # Line 15: inductor sizing for the chosen topology.
@@ -414,23 +404,20 @@ def explore(
 def _variation_result(
     vgrid: VariationGrid,
     max_latency_ns: float | None,
-    idx: np.ndarray | None = None,
-    winner_energy: np.ndarray | None = None,
-    nominal_latency: np.ndarray | None = None,
-    nominal_fits: "bool | None" = None,
+    idx: np.ndarray,
+    winner_energy: np.ndarray,
+    nominal_latency: np.ndarray,
+    nominal_fits: bool,
 ) -> VariationResult:
     """Per-variant winners + yield summary for one circuit's sweep.
 
-    ``idx``: precomputed ``(V,)`` winner indices.  The fused pipeline
-    passes one row of the on-device `SelectionResult` — together with
-    its per-winner energies (``winner_energy``) and the nominal-winner
-    latencies/fits (``nominal_latency``/``nominal_fits``) the whole
-    summary is computed without touching the full (V, T, R) tensors,
-    which then stay device-resident.  Callers without a fused result
-    (host fallback) omit them and the summary is derived from the grid.
+    The arguments are one circuit's row of the on-device
+    `SelectionResult`: the ``(V,)`` winner indices (``idx``), their
+    energies (``winner_energy``) and each variant's latency / the
+    capacity flag at the nominal winner (``nominal_latency`` /
+    ``nominal_fits``).  The whole summary is computed from them without
+    touching the full (V, T, R) tensors, which stay device-resident.
     """
-    if idx is None:
-        idx = vgrid.best_indices(max_latency_ns)
     pairs = [vgrid.unravel(int(i)) for i in idx]
     winners = [(vgrid.recipes[ri], vgrid.topologies[ti]) for ti, ri in pairs]
     share, best_yield = winner_summary(
@@ -439,17 +426,9 @@ def _variation_result(
     # Does the nominal (variant-0) winner stay admissible under each
     # variant?  Capacity is model-free; latency shifts with each
     # variant's achievable clock.
-    ti0, ri0 = pairs[0]
-    if nominal_fits is None:
-        nominal_fits = bool(vgrid.fits[ti0, ri0])
     ok = np.full(len(idx), bool(nominal_fits))
     if max_latency_ns is not None:
-        if nominal_latency is None:
-            nominal_latency = vgrid.latency_ns[:, ti0, ri0]
         ok &= np.asarray(nominal_latency) <= max_latency_ns
-    if winner_energy is None:
-        flat = vgrid.energy_nj.reshape(len(idx), -1)
-        winner_energy = flat[np.arange(len(idx)), np.asarray(idx)]
     winner_energy = np.asarray(winner_energy, dtype=float)
     quantiles = {
         q: float(np.quantile(winner_energy, q)) for q in ENERGY_QUANTILES
@@ -479,7 +458,6 @@ def explore_suite(
     cache: "CharacterizationCache | str | os.PathLike | None" = None,
     n_jobs: int | None = None,
     model_sweep: ModelTable | None = None,
-    fused: bool = True,
     shard: "bool | None" = None,
     cha_backend: str = "auto",
 ) -> dict[str, ExplorationResult]:
@@ -493,33 +471,31 @@ def explore_suite(
     tables), ``"python"`` (bigint parity reference), or ``"auto"``.
 
     Back half (``backend="jax"``): the characterizations are stacked into
-    a `batch.SuiteTable` and ONE `batch.evaluate_suite` call sweeps
-    circuits x recipes x topologies; each circuit's `ExplorationGrid` is
-    then a view into the stacked result.  ``backend="python"`` falls back
-    to the scalar per-circuit loop (still sharing the suite front half).
+    a `batch.SuiteTable` and ONE `batch.evaluate_select_suite` call
+    sweeps circuits x recipes x topologies on the device: the three-tier
+    FilterEnergy runs inside the same jitted pass, only the (C, V)
+    winner indices + per-winner metrics cross the host boundary, and
+    each circuit's `ExplorationGrid` is a lazy view into the stacked
+    device result whose tensors materialize on first access.
+    ``backend="python"`` falls back to the scalar per-circuit loop
+    (still sharing the suite front half).
 
     ``model_sweep``: a `sram.ModelTable` of energy-model variants
     (process corners, sensitivity grids, Monte-Carlo samples — variant 0
     is the nominal model).  Correlated (topology-dependent) tables —
     e.g. `ModelTable.bitcell_sigma_per_macro` keyed on ``sram_list``'s
-    macro geometries — flow through the same kernels via their
+    macro geometries — flow through the same program via their
     ``(V, T)`` fields.  The same single compile/device call then covers
-    circuits x variants x topologies x recipes; the selection stage is
-    one batched `select_best_batch` pass over every (circuit, variant)
-    cell, and every result's ``variation`` field carries the
-    per-variant winners and the yield summary (`VariationResult`).  The
+    circuits x variants x topologies x recipes, the selection of every
+    (circuit, variant) cell's winner included, and every result's
+    ``variation`` field carries the per-variant winners and the yield
+    summary (`VariationResult`).  The
     headline ``best``/``grid`` stay the nominal variant's, so downstream
     consumers are unchanged.  Mutually exclusive with ``model``;
     requires ``backend="jax"``.
 
-    ``fused`` (default): the whole back half is device-resident — the
-    three-tier FilterEnergy runs inside the same jitted pass
-    (`batch.evaluate_select_suite`), only the (C, V) winner indices +
-    per-winner metrics cross the host boundary, and each result's
-    ``grid`` is a lazy view whose tensors materialize on first access.
-    ``fused=False`` keeps the host-side `select_best_batch` path (the
-    parity reference).  ``shard`` spreads the variant axis over the
-    available devices (see `batch._shard_variants`; None = auto).
+    ``shard`` spreads the variant axis over the available devices (see
+    `batch._shard_variants`; None = auto).
 
     Returns ``{circuit: ExplorationResult}`` in the input's order; each
     result's ``wall_s`` is the suite wall time divided evenly across
@@ -579,61 +555,36 @@ def explore_suite(
             topo_table = TopologyTable.from_topologies(sram_list)
             span.set_metadata(built=built, reused=len(records) - built)
         swept = model_sweep if model_sweep is not None else model
-        sel: SelectionResult | None = None
         with trace.span("explore.fused"):
-            if fused:
-                # Device-resident back half: evaluate + FilterEnergy fused
-                # into one jitted (optionally variant-sharded) pass — only
-                # (C, V) winner indices + per-winner metrics are
-                # transferred, and the grids below are lazy device views.
-                sg, sel = evaluate_select_suite(
-                    suite, topo_table, swept, mode=mode, discipline=discipline,
-                    feasible=feas_mask, max_latency_ns=max_latency_ns, lazy=True,
-                    shard=shard,
-                )
-            else:
-                sg = evaluate_suite(
-                    suite, topo_table, swept,
-                    mode=mode, discipline=discipline, feasible=feas_mask,
-                )
+            # Device-resident back half: evaluate + FilterEnergy fused
+            # into one jitted (optionally variant-sharded) pass — only
+            # (C, V) winner indices + per-winner metrics are
+            # transferred, and the grids below are lazy device views.
+            sg, sel = evaluate_select_suite(
+                suite, topo_table, swept, mode=mode, discipline=discipline,
+                feasible=feas_mask, max_latency_ns=max_latency_ns,
+                shard=shard,
+            )
 
         with trace.span("explore.assemble") as span:
             views, slices = VIEW_COUNTS["views"], VIEW_COUNTS["slices"]
             out = {}
             wall = (time.time() - t0) / max(1, len(names))
-            if sel is not None:
-                suite_winners = sel.winner_idx  # (C, V) — computed on device
-            elif model_sweep is not None:
-                # Host selection stage for the whole hypercube: every (circuit,
-                # variant) winner from ONE batched masked-argmin pass.
-                suite_winners = sg.best_indices(max_latency_ns)  # (C, V)
             for i, name in enumerate(names):
                 variation = None
                 if model_sweep is not None:
                     vgrid = sg.variation(name)
                     variation = _variation_result(
-                        vgrid, max_latency_ns, idx=suite_winners[i],
-                        winner_energy=(
-                            None if sel is None else sel.winner_energy_nj[i]
-                        ),
-                        nominal_latency=(
-                            None if sel is None else sel.nominal_latency_ns[i]
-                        ),
-                        nominal_fits=(
-                            None if sel is None else bool(sel.nominal_fits[i])
-                        ),
+                        vgrid, max_latency_ns, sel.winner_idx[i],
+                        sel.winner_energy_nj[i], sel.nominal_latency_ns[i],
+                        bool(sel.nominal_fits[i]),
                     )
                     grid = vgrid.grid(0)  # nominal variant, the headline result
-                    # the batched pass already holds variant 0's winner under
-                    # the same tiers — no per-circuit re-selection needed
-                    best_flat = int(suite_winners[i, 0])
-                elif sel is not None:
-                    grid = sg.grid(name)
-                    best_flat = int(sel.winner_idx[i, 0])  # V=1 hypercube
                 else:
                     grid = sg.grid(name)
-                    best_flat = grid.best_index(max_latency_ns)
-                ti, ri = grid.unravel(best_flat)
+                # (C, V) winners computed on the device; variant 0's is the
+                # headline winner under the same tiers
+                ti, ri = grid.unravel(int(sel.winner_idx[i, 0]))
                 recipe, topo = grid.recipes[ri], sram_list[ti]
                 best = _materialize(
                     recipe, topo, cha[name][recipe], model, mode, discipline
@@ -673,7 +624,6 @@ def explore_request(
     cha: Mapping[tuple[str, ...], AigStats] | None = None,
     cache: "CharacterizationCache | str | os.PathLike | None" = None,
     n_jobs: int | None = 1,
-    fused: bool = True,
     shard: "bool | None" = None,
     cha_backend: str = "auto",
 ) -> ExplorationResult:
@@ -718,7 +668,6 @@ def explore_request(
         cache=cache,
         n_jobs=n_jobs,
         model_sweep=model_sweep,
-        fused=fused,
         shard=shard,
         cha_backend=cha_backend,
     )

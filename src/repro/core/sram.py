@@ -159,7 +159,7 @@ def topology_grid(
     Every combination whose macro is a whole number of KB and whose macro
     count the mapping model supports (1 or a multiple of 3) becomes a
     design point; the batched engine sweeps the whole grid in one device
-    call (``evaluate_batch`` / ``evaluate_suite``), so grid size is cheap.
+    call (``batch.evaluate_select_suite``), so grid size is cheap.
     Deduplicates against geometry collisions and keeps the given order
     (rows-major, then cols, then macro count).
     """
@@ -298,8 +298,8 @@ class ModelTable:
     broadcasts uniformly and is bit-identical to the same values as a
     ``(V,)`` / ``(V, 3)`` field.
 
-    The batched kernels (`batch.evaluate_batch` /
-    `batch.evaluate_suite`) take these arrays as *traced* operands and
+    The fused sweep program (`batch.evaluate_select_suite`) takes these
+    arrays as *traced* operands and
     vmap over the variant axis, so one jit compilation sweeps every
     variant — no per-model recompile, which is what makes corner /
     sensitivity / Monte-Carlo studies (the paper's yield FoM) cheap.
@@ -676,8 +676,9 @@ class Metrics:
 #
 # These helpers are written against plain arithmetic operators so they accept
 # python floats, numpy arrays, and jax arrays alike.  ``evaluate`` (scalar)
-# and ``evaluate_batch`` (grid) call the *same* expressions, so the two paths
-# agree to floating-point round-off by construction.
+# and the fused grid program (``batch.evaluate_select_suite``) call the *same*
+# expressions, so the two paths agree to floating-point round-off by
+# construction.
 
 
 def paper_power_mw(n_levels, model: EnergyModel):

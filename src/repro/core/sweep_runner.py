@@ -307,7 +307,7 @@ class SweepRunner:
                 )
                 _, sel = evaluate_select_suite(
                     part, topo_table, model, mode=mode, discipline=discipline,
-                    feasible=feas, max_latency_ns=max_latency_ns, lazy=True,
+                    feasible=feas, max_latency_ns=max_latency_ns,
                     shard=shard,
                 )
                 dev_sharded = sel.sharded

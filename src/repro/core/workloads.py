@@ -7,8 +7,8 @@ topology.  This module closes the loop from the *application* side
 (`repro.configs`) into counts of three exactly-constructed primitive
 tiles, characterizes each tile once into the same `AigStats` shape the
 schedule/evaluate kernels consume, and exposes the result as a
-`SuiteTable` so the existing batched `evaluate_suite` /
-`evaluate_select_suite` pipelines price a whole model per token.
+`SuiteTable` so the fused `evaluate_select_suite` program prices a whole
+model per token.
 
 Primitive tiles (exact gate-level constructions, verified against
 integer arithmetic by tests/test_workloads.py):
@@ -301,8 +301,7 @@ def conservation_report(lowered: LoweredModel) -> dict:
 
 def primitive_suite():
     """The primitive tiles as a `SuiteTable` (one trivial recipe per
-    tile), the input shape `evaluate_suite`/`evaluate_select_suite`
-    consume."""
+    tile), the input shape `evaluate_select_suite` consumes."""
     from .batch import SuiteTable  # local import: keep workloads jax-free
 
     return SuiteTable.from_cha(

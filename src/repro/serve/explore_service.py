@@ -724,7 +724,6 @@ class ExplorationService:
             discipline=self._discipline,
             feasible=feas,
             max_latency_ns=None,
-            lazy=True,
         )
         with self._stats_lock:
             self._stats["batches"] += 1

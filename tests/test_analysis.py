@@ -120,8 +120,7 @@ def test_all_kernel_modules_register():
     for s in specs:
         by_module.setdefault(s.module, []).append(s.name)
     assert sorted(by_module.get("repro.core.batch", [])) == [
-        "evaluate_grid", "evaluate_suite", "fused_grid", "fused_suite",
-        "schedule_grid", "schedule_suite", "select_batch",
+        "fused_suite", "select_batch",
     ]
     assert sorted(by_module.get("repro.kernels.aig_sim", [])) == [
         "aig_eval", "aig_sig",
@@ -130,6 +129,14 @@ def test_all_kernel_modules_register():
     # the Pallas kernels register counters (AST-enforced), not specs
     assert registry.KERNEL_OWNERS["aig_eval_pallas"] == "repro.kernels.aig_sim"
     assert registry.KERNEL_OWNERS["cim_pallas"] == "repro.kernels.cim_logic"
+
+
+def test_batch_registers_one_sweep_program():
+    """`core/batch.py` owns exactly two jitted programs: the fused sweep
+    that every exploration runs and the standalone selection filter."""
+    registry.kernel_specs()  # imports every kernel module
+    owned = {k for k, m in registry.KERNEL_OWNERS.items() if m == "repro.core.batch"}
+    assert owned == {"fused_suite", "select_batch"}
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +166,7 @@ def test_flip_removing_trace_count_increment(tmp_path):
     src = open(os.path.join(SRC, "repro", "core", "batch.py")).read()
     stripped = tmp_path / "batch_stripped.py"
     stripped.write_text(
-        _strip_one(src, 'TRACE_COUNTS["schedule_grid"] += 1')
+        _strip_one(src, 'TRACE_COUNTS["select_batch"] += 1')
     )
     findings = ast_lint.lint_paths([str(stripped)])
     assert any(f.rule == "ast-jit-no-counter" for f in findings)
@@ -240,7 +247,7 @@ def test_trace_counts_views_are_module_scoped():
 
 def test_counter_ownership_conflict_rejected():
     with pytest.raises(ValueError, match="already registered"):
-        registry.register_counter("schedule_grid", "some.other.module")
+        registry.register_counter("fused_suite", "some.other.module")
 
 
 # ---------------------------------------------------------------------------

@@ -16,15 +16,20 @@ import pytest
 from repro.core import circuits as C
 from repro.core.aig import AigStats
 from repro.core.batch import (
+    SuiteTable,
     TopologyTable,
     WorkloadTable,
-    evaluate_batch,
-    schedule_batch,
+    evaluate_select_suite,
     select_best,
     select_best_worst,
     table2_batch,
 )
-from repro.core.explorer import best_worst, characterize_recipes, explore
+from repro.core.explorer import (
+    best_worst,
+    characterize_recipes,
+    explore,
+    explore_suite,
+)
 from repro.core.mapping import schedule_stats
 from repro.core.sram import (
     TOPOLOGY_LIBRARY,
@@ -58,6 +63,14 @@ SYNTH = [
 ]
 
 
+def one_circuit_grid(items, topos, model=EM, **kw):
+    """``items`` ((recipe, stats) pairs) swept as a one-circuit suite by
+    the fused program: the circuit's `ExplorationGrid`."""
+    suite = SuiteTable.from_cha({"c": dict(items)})
+    sg, _ = evaluate_select_suite(suite, topos, model, **kw)
+    return sg.grid(0)
+
+
 # ---------------------------------------------------------------------------
 # Grid vs scalar parity
 # ---------------------------------------------------------------------------
@@ -65,32 +78,27 @@ SYNTH = [
 
 @pytest.mark.parametrize("discipline", ["list", "levels"])
 def test_schedule_batch_matches_scalar(discipline):
-    work = WorkloadTable.from_stats(SYNTH)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
-    grid = schedule_batch(work, topos, discipline=discipline)
+    grid = one_circuit_grid(SYNTH, topos, discipline=discipline)
     for ti, topo in enumerate(TOPOLOGY_LIBRARY):
         for ri, (_, st) in enumerate(SYNTH):
             ref = schedule_stats(st, topo, discipline=discipline)
-            assert grid["cycles"][ti, ri] == ref.total_cycles
-            assert (
-                grid["active_macro_cycles"][ti, ri] == ref.active_macro_cycles
-            )
-            assert bool(grid["fits"][ti, ri]) == ref.fits
+            assert grid.cycles[ti, ri] == ref.total_cycles
+            assert grid.active_macro_cycles[ti, ri] == ref.active_macro_cycles
+            assert bool(grid.fits[ti, ri]) == ref.fits
 
 
 @pytest.mark.parametrize("mode", ["physical", "paper"])
 @pytest.mark.parametrize("discipline", ["list", "levels"])
 def test_evaluate_batch_matches_scalar(mode, discipline):
-    work = WorkloadTable.from_stats(SYNTH)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
-    grid = evaluate_batch(work, topos, EM, mode=mode, discipline=discipline)
+    grid = one_circuit_grid(SYNTH, topos, mode=mode, discipline=discipline)
     for ti, topo in enumerate(TOPOLOGY_LIBRARY):
         for ri, (_, st) in enumerate(SYNTH):
-            ref = evaluate(
-                schedule_stats(st, topo, discipline=discipline),
-                topo, EM, mode=mode,
-            )
+            sched = schedule_stats(st, topo, discipline=discipline)
+            ref = evaluate(sched, topo, EM, mode=mode)
             assert grid.cycles[ti, ri] == ref.cycles
+            assert bool(grid.fits[ti, ri]) == sched.fits
             np.testing.assert_allclose(
                 grid.energy_nj[ti, ri], ref.energy_nj, rtol=1e-12
             )
@@ -187,6 +195,37 @@ def test_backend_parity_latency_constraint_and_pseudocode_sweep(full_cha):
         jx = explore(rtl, cha=cha, backend="jax", **kw)
         assert (jx.best.recipe, jx.best.topo) == (py.best.recipe, py.best.topo)
         assert abs(jx.best.metrics.energy_nj - py.best.metrics.energy_nj) < 1e-6
+        assert jx.n_evaluations == py.n_evaluations
+
+
+GRID_FIELDS = (
+    "cycles", "active_macro_cycles", "fits", "latency_ns", "energy_nj",
+    "power_mw", "throughput_gops", "tops_per_watt", "area_mm2", "feasible",
+)
+
+
+@pytest.mark.parametrize("bounded", [False, True], ids=["free", "latency"])
+def test_explore_jax_is_a_one_circuit_explore_suite(full_cha, bounded):
+    """`explore(backend="jax")` runs the circuit as a one-circuit suite:
+    the same winner and the same grid as `explore_suite` on that one
+    circuit, with a latency bound and without one."""
+    rtl, cha = full_cha
+    kw = {}
+    if bounded:
+        free = explore(rtl, cha=cha, backend="jax")
+        kw["max_latency_ns"] = free.best.metrics.latency_ns * 0.9
+    one = explore(rtl, cha=cha, backend="jax", **kw)
+    suite = explore_suite({rtl.name: rtl}, cha={rtl.name: cha}, **kw)[rtl.name]
+    assert one.backend == suite.backend == "jax"
+    assert (one.best.recipe, one.best.topo) == (suite.best.recipe, suite.best.topo)
+    assert one.best.metrics.energy_nj == suite.best.metrics.energy_nj
+    a, b = one.grid, suite.grid
+    assert (a.recipes, a.topologies) == (b.recipes, b.topologies)
+    assert (a.mode, a.discipline, a.model) == (b.mode, b.discipline, b.model)
+    for field in GRID_FIELDS:
+        x, y = getattr(a, field), getattr(b, field)
+        assert isinstance(x, np.ndarray), field
+        assert x.dtype == y.dtype and np.array_equal(x, y), field
 
 
 # ---------------------------------------------------------------------------
@@ -233,9 +272,8 @@ def test_select_best_matches_mesh_explorer_fallback_chain():
 
 
 def test_grid_flat_order_is_topology_major():
-    work = WorkloadTable.from_stats(SYNTH[:3])
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[:4])
-    grid = evaluate_batch(work, topos, EM)
+    grid = one_circuit_grid(SYNTH[:3], topos)
     i = grid.best_index()
     ti, ri = grid.unravel(i)
     assert grid.energy_nj.ravel()[i] == grid.energy_nj[ti, ri]
@@ -243,7 +281,7 @@ def test_grid_flat_order_is_topology_major():
     flat = [
         (grid.energy_nj[t, r], bool(grid.fits[t, r]))
         for t in range(len(topos.topologies))
-        for r in range(len(work.recipes))
+        for r in range(len(grid.recipes))
     ]
     pool = [e for e, f in flat if f] or [e for e, _ in flat]
     assert grid.energy_nj.ravel()[i] == min(pool)

@@ -3,26 +3,28 @@ sharding bit-identity, lazy grids, and the per-op (V, T, 3) plumbing.
 
 Contracts under test:
 
-  * `evaluate_select_batch` / `evaluate_select_suite` (evaluate + the
-    three-tier masked argmin fused into one jitted pass) return winners
-    identical to the host-side parity reference (`evaluate_suite` +
-    `select_best_batch`) on every (circuit, variant) cell — including
+  * `evaluate_select_suite` (evaluate + the three-tier masked argmin
+    fused into one jitted pass), on a suite or a one-circuit suite,
+    returns winners identical to the host-side parity reference
+    (`select_best_batch` over the same call's materialized tensors) on
+    every (circuit, variant) cell — including
     grids salted with NaN/±inf energies via pathological model variants,
     exact-tie grids (duplicate topology columns; lowest flat index wins),
     all-infeasible cells, and under latency/feasibility constraints;
   * an all-non-finite cell raises, exactly like `select_best_batch`;
   * the 1-device sharded path (`shard=True`) is bit-identical to the
     unsharded path — winners, per-winner metrics, and the full tensors;
-  * lazy grids materialize to the same arrays the eager path returns,
-    and the fused payload is orders of magnitude below the full-tensor
-    transfer;
+  * lazy grids and their views materialize to the same arrays as a
+    numpy copy of the root grid, and the fused payload is orders of
+    magnitude below the full-tensor transfer;
   * one jit trace per fused sweep; float-only model changes do not
     retrace;
   * correlated generators may emit per-op ``(V, T, 3)`` fields which
     flow through the same kernels and match the scalar path cell by
     cell, with `_check_topo_axis` rejecting mismatched topology lists;
-  * `explore_suite(fused=True)` equals the `fused=False` host path end
-    to end, `VariationResult` quantiles/CVaR included.
+  * `explore_suite` equals the host reference end to end: its
+    `VariationResult` (winners, shares, yields, quantiles/CVaR) is
+    rebuilt from its grid's tensors through `select_best_batch`.
 """
 
 import dataclasses
@@ -36,14 +38,18 @@ from repro.core.batch import (
     SuiteTable,
     TopologyTable,
     WorkloadTable,
-    evaluate_batch,
-    evaluate_select_batch,
     evaluate_select_suite,
-    evaluate_suite,
+    select_best_batch,
     table2_batch,
     trace_counts,
+    winner_summary,
 )
-from repro.core.explorer import characterize_recipes, explore_suite
+from repro.core.explorer import (
+    ENERGY_QUANTILES,
+    VariationResult,
+    characterize_recipes,
+    explore_suite,
+)
 from repro.core.mapping import schedule_stats
 from repro.core.sram import (
     TOPOLOGY_LIBRARY,
@@ -102,9 +108,20 @@ def salted_table(topos, n=6, seed=0, nan_frac=0.15):
 
 
 def host_reference(grid, max_latency_ns=None):
-    """The host-side parity reference: (C, V) winners via
-    `SuiteVariationGrid.best_indices` (select_best_batch underneath)."""
+    """The host-side parity reference: (C, V) winners from the grid's
+    materialized tensors via `SuiteVariationGrid.best_indices`
+    (select_best_batch underneath)."""
     return grid.best_indices(max_latency_ns)
+
+
+def one_circuit(work, topos, model, **kw):
+    """``work`` swept as a one-circuit suite: its grid (a `VariationGrid`
+    for a `ModelTable`, an `ExplorationGrid` for one model) and the
+    ``(1, V)`` selection."""
+    sg, sel = evaluate_select_suite(
+        SuiteTable.from_workloads({"c": work}), topos, model, **kw
+    )
+    return (sg.variation(0) if isinstance(model, ModelTable) else sg.grid(0)), sel
 
 
 @pytest.fixture(scope="module")
@@ -128,35 +145,35 @@ def workloads():
 def test_fused_suite_matches_host_selection(workloads, mode, max_lat):
     _, suite, topos = workloads
     table = ModelTable.monte_carlo(n=5, sigma=0.3, seed=7)
-    svg = evaluate_suite(suite, topos, table, mode=mode)
     grid, sel = evaluate_select_suite(
         suite, topos, table, mode=mode, max_latency_ns=max_lat
     )
-    host = host_reference(svg, max_lat)
+    host = host_reference(grid, max_lat)
     np.testing.assert_array_equal(sel.winner_idx.astype(np.int64), host)
     assert sel.winner_idx.dtype == np.int32
     # per-winner metrics equal the host gather on every metric
     c, v = host.shape
     for k in METRIC_KEYS:
-        flat = getattr(svg, k).reshape(c, v, -1)
+        flat = getattr(grid, k).reshape(c, v, -1)
         ref = np.take_along_axis(flat, host[..., None], -1)[..., 0]
         np.testing.assert_array_equal(sel.winner_metrics[k], ref)
-    # the lazy grid holds the same tensors the host path materialized
-    for k in METRIC_KEYS + ("cycles", "fits"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(grid, k)), getattr(svg, k)
-        )
+    # each circuit's tensors equal the circuit run as its own suite
+    for name in suite.circuits:
+        alone, _ = one_circuit(suite.workload(name), topos, table, mode=mode)
+        for k in METRIC_KEYS + ("cycles", "fits"):
+            np.testing.assert_array_equal(
+                getattr(grid.variation(name), k), getattr(alone, k)
+            )
 
 
 def test_fused_matches_host_on_nan_salted_grids(workloads):
     _, suite, topos = workloads
     table = salted_table(topos, n=6, seed=3)
-    svg = evaluate_suite(suite, topos, table)
-    assert not np.isfinite(svg.energy_nj).all()  # the salt is real
-    assert np.isfinite(svg.energy_nj).any(axis=(2, 3)).all()
     grid, sel = evaluate_select_suite(suite, topos, table)
+    assert not np.isfinite(grid.energy_nj).all()  # the salt is real
+    assert np.isfinite(grid.energy_nj).any(axis=(2, 3)).all()
     np.testing.assert_array_equal(
-        sel.winner_idx.astype(np.int64), host_reference(svg)
+        sel.winner_idx.astype(np.int64), host_reference(grid)
     )
     # NaN cells never win
     c, v = sel.winner_idx.shape
@@ -173,7 +190,7 @@ def test_fused_all_non_finite_raises(workloads):
     with pytest.raises(ValueError, match="finite"):
         evaluate_select_suite(suite, topos, table)
     with pytest.raises(ValueError, match="finite"):
-        evaluate_select_batch(work, topos, table)
+        one_circuit(work, topos, table)
 
 
 def test_fused_ties_break_to_lowest_flat_index(workloads):
@@ -183,12 +200,9 @@ def test_fused_ties_break_to_lowest_flat_index(workloads):
     dup = TopologyTable.from_topologies(
         (TOPOLOGY_LIBRARY[4], TOPOLOGY_LIBRARY[4], TOPOLOGY_LIBRARY[4])
     )
-    vg = evaluate_batch(work, dup, ModelTable.monte_carlo(n=3, seed=1))
-    grid, sel = evaluate_select_batch(
-        work, dup, ModelTable.monte_carlo(n=3, seed=1)
-    )
-    host = vg.best_indices()
-    np.testing.assert_array_equal(sel.winner_idx.astype(np.int64), host)
+    grid, sel = one_circuit(work, dup, ModelTable.monte_carlo(n=3, seed=1))
+    host = grid.best_indices()
+    np.testing.assert_array_equal(sel.winner_idx[0].astype(np.int64), host)
     # the duplicate columns really did tie, and column 0 won
     n_r = len(grid.recipes)
     assert (host < n_r).all()
@@ -200,27 +214,25 @@ def test_fused_all_infeasible_falls_through(workloads):
     rng = np.random.default_rng(9)
     big = random_workload(rng, n_recipes=4, max_ops=10_000_000)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[:4])
-    feas = np.zeros(4, dtype=bool)
+    feas = np.zeros((1, 4), dtype=bool)
     table = ModelTable.monte_carlo(n=4, sigma=0.2, seed=2)
-    vg = evaluate_batch(big, topos, table, feasible=feas)
-    assert not vg.fits.any()
-    grid, sel = evaluate_select_batch(big, topos, table, feasible=feas)
+    grid, sel = one_circuit(big, topos, table, feasible=feas)
+    assert not grid.fits.any()
     np.testing.assert_array_equal(
-        sel.winner_idx.astype(np.int64), vg.best_indices()
+        sel.winner_idx[0].astype(np.int64), grid.best_indices()
     )
 
 
 def test_fused_single_model_matches_host(workloads):
     work, suite, topos = workloads
     em = EnergyModel()
-    sg = evaluate_suite(suite, topos, em)
-    grid, sel = evaluate_select_suite(suite, topos, em)
+    sg, sel = evaluate_select_suite(suite, topos, em)
     assert sel.winner_idx.shape == (len(suite), 1)
     for i, name in enumerate(suite.circuits):
         assert int(sel.winner_idx[i, 0]) == sg.grid(name).best_index()
-    g, s = evaluate_select_batch(work, topos, em)
-    ref = evaluate_batch(work, topos, em)
-    assert int(s.winner_idx[0]) == ref.best_index()
+    g, s = one_circuit(work, topos, em)
+    assert s.winner_idx.shape == (1, 1)
+    assert int(s.winner_idx[0, 0]) == g.best_index()
     assert g.model == em
 
 
@@ -266,9 +278,17 @@ LAZY_VIEWS = {
 }
 
 
+def _eager(grid):
+    """The eager reference: a copy of a lazy root grid whose fields are
+    numpy arrays (``np.asarray`` of each device field)."""
+    return dataclasses.replace(
+        grid, **{k: np.asarray(grid._raw(k)) for k in LAZY_KEYS}
+    )
+
+
 def _check_lazy_view(suite, topos, model, make, at, n_views):
-    lazy_root, _ = evaluate_select_suite(suite, topos, model, lazy=True)
-    eager_root, _ = evaluate_select_suite(suite, topos, model, lazy=False)
+    lazy_root, _ = evaluate_select_suite(suite, topos, model)
+    eager_root = _eager(evaluate_select_suite(suite, topos, model)[0])
     made = dict(batch.VIEW_COUNTS)
     lazy, eager = make(lazy_root), make(eager_root)
     # making a view (of a view) dispatches no slice; numpy-backed grids
@@ -300,8 +320,8 @@ def test_lazy_grid_materializes_identically(workloads, view):
         model = table if swept else EnergyModel()
         _check_lazy_view(suite, topos, model, make, at, n_views)
         return
-    lazy_grid, _ = evaluate_select_suite(suite, topos, table, lazy=True)
-    eager_grid, _ = evaluate_select_suite(suite, topos, table, lazy=False)
+    lazy_grid, _ = evaluate_select_suite(suite, topos, table)
+    eager_grid = _eager(evaluate_select_suite(suite, topos, table)[0])
     # before access the lazy fields are device arrays, not numpy
     assert not isinstance(lazy_grid._raw("energy_nj"), np.ndarray)
     for k in LAZY_KEYS:
@@ -311,7 +331,7 @@ def test_lazy_grid_materializes_identically(workloads, view):
     # access materialized + cached the field in place
     assert isinstance(lazy_grid._raw("energy_nj"), np.ndarray)
     # sliced views and shape queries inherit laziness
-    lazy2, _ = evaluate_select_suite(suite, topos, table, lazy=True)
+    lazy2, _ = evaluate_select_suite(suite, topos, table)
     vgrid = lazy2.variation(suite.circuits[0])
     assert lazy2.size == eager_grid.size  # .size must not materialize
     assert not isinstance(lazy2._raw("energy_nj"), np.ndarray)
@@ -323,9 +343,8 @@ def test_lazy_grid_materializes_identically(workloads, view):
 def test_fused_payload_is_small(workloads):
     _, suite, topos = workloads
     table = ModelTable.monte_carlo(n=8, sigma=0.2, seed=6)
-    svg = evaluate_suite(suite, topos, table)
-    _, sel = evaluate_select_suite(suite, topos, table)
-    full = sum(getattr(svg, k).nbytes for k in METRIC_KEYS)
+    grid, sel = evaluate_select_suite(suite, topos, table)
+    full = sum(getattr(grid, k).nbytes for k in METRIC_KEYS)
     assert sel.payload_bytes < full / 10
     c, v = len(suite), len(table)
     assert sel.winner_idx.nbytes == c * v * 4  # (C, V) int32
@@ -387,7 +406,7 @@ def test_per_op_topology_axis_shapes_and_validation():
     short = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[:5])
     rng = np.random.default_rng(0)
     with pytest.raises(ValueError, match="per-topology axis|generated for"):
-        evaluate_batch(random_workload(rng), short, table)
+        one_circuit(random_workload(rng), short, table)
     with pytest.raises(ValueError, match="per-topology axis|generated for"):
         table2_batch(short, table)
     # mixed per-op/scalar widths inside one table are rejected
@@ -421,7 +440,7 @@ def test_per_op_correlated_sweep_matches_scalar_path():
         TOPOLOGY_LIBRARY, n=3, sigma=0.4, seed=8,
         fields=("e_op_fj", "e_op_marginal_fj"),
     )
-    vg = evaluate_batch(work, topos, table)
+    vg, sel = one_circuit(work, topos, table)
     for v in range(3):
         for t in range(len(TOPOLOGY_LIBRARY)):
             m = table.model(v, topology=t)
@@ -443,14 +462,13 @@ def test_per_op_correlated_sweep_matches_scalar_path():
                 tb["power_mw"][v, t], ref["power_mw"][0], rtol=1e-12
             )
     # ...and the fused filter handles the (V, T, 3) shape too
-    grid, sel = evaluate_select_batch(work, topos, table)
     np.testing.assert_array_equal(
-        sel.winner_idx.astype(np.int64), vg.best_indices()
+        sel.winner_idx[0].astype(np.int64), vg.best_indices()
     )
 
 
 # ---------------------------------------------------------------------------
-# explore_suite end to end: fused == host, quantiles/CVaR
+# explore_suite end to end: fused == host reference, quantiles/CVaR
 # ---------------------------------------------------------------------------
 
 
@@ -461,16 +479,51 @@ def bar_suite():
     return suite, cha
 
 
+def host_variation(vgrid, max_latency_ns=None):
+    """The host reference of a `VariationResult`: winners, shares,
+    yields and winner energies recomputed from the grid's materialized
+    tensors through `select_best_batch`."""
+    v = len(vgrid.models)
+    energy = vgrid.energy_nj.reshape(v, -1)
+    feas = np.broadcast_to(vgrid.feasible[:, None], vgrid.fits.shape)
+    idx = select_best_batch(
+        energy, vgrid.fits.reshape(1, -1),
+        latency=vgrid.latency_ns.reshape(v, -1), max_latency=max_latency_ns,
+        feasible=feas.reshape(1, -1),
+    )
+    pairs = [vgrid.unravel(int(i)) for i in idx]
+    winners = [(vgrid.recipes[ri], vgrid.topologies[ti]) for ti, ri in pairs]
+    share, best_yield = winner_summary(
+        [f"{topo.name}/{','.join(recipe) or '-'}" for recipe, topo in winners]
+    )
+    ti0, ri0 = pairs[0]
+    ok = np.full(v, bool(vgrid.fits[ti0, ri0]))
+    if max_latency_ns is not None:
+        ok &= vgrid.latency_ns[:, ti0, ri0] <= max_latency_ns
+    winner_energy = energy[np.arange(v), idx]
+    return VariationResult(
+        models=vgrid.models, grid=vgrid, winners=winners,
+        winner_share=share, best_yield=best_yield,
+        latency_yield=float(np.mean(ok)), winner_energy_nj=winner_energy,
+        energy_quantiles={
+            q: float(np.quantile(winner_energy, q)) for q in ENERGY_QUANTILES
+        },
+    )
+
+
 def test_explore_suite_fused_equals_host_path(bar_suite):
     suite, cha = bar_suite
     table = ModelTable.monte_carlo(n=6, sigma=0.25, seed=4)
-    fused = explore_suite(suite, cha=cha, model_sweep=table, fused=True)
-    host = explore_suite(suite, cha=cha, model_sweep=table, fused=False)
+    fused = explore_suite(suite, cha=cha, model_sweep=table)
     for name in suite:
-        f, h = fused[name], host[name]
-        assert (f.best.recipe, f.best.topo) == (h.best.recipe, h.best.topo)
-        assert f.best.metrics.energy_nj == h.best.metrics.energy_nj
-        vf, vh = f.variation, h.variation
+        f = fused[name]
+        vf, vh = f.variation, host_variation(f.variation.grid)
+        recipe, topo = vh.winners[0]
+        assert (f.best.recipe, f.best.topo) == (recipe, topo)
+        ref = evaluate(
+            schedule_stats(cha[name][recipe], topo), topo, table.model(0)
+        )
+        assert f.best.metrics.energy_nj == ref.energy_nj
         assert vf.winners == vh.winners
         assert vf.winner_share == vh.winner_share
         assert vf.best_yield == vh.best_yield
@@ -486,17 +539,13 @@ def test_explore_suite_fused_with_latency_bound(bar_suite):
     suite, cha = bar_suite
     table = ModelTable.corners(spread=0.2)
     fused = explore_suite(
-        suite, cha=cha, model_sweep=table, max_latency_ns=30.0, fused=True
-    )
-    host = explore_suite(
-        suite, cha=cha, model_sweep=table, max_latency_ns=30.0, fused=False
+        suite, cha=cha, model_sweep=table, max_latency_ns=30.0
     )
     for name in suite:
-        assert fused[name].variation.winners == host[name].variation.winners
-        assert (
-            fused[name].variation.latency_yield
-            == host[name].variation.latency_yield
-        )
+        vf = fused[name].variation
+        vh = host_variation(vf.grid, max_latency_ns=30.0)
+        assert vf.winners == vh.winners
+        assert vf.latency_yield == vh.latency_yield
 
 
 def test_fused_matches_host_on_full_ci_grid_with_nan_salt(bar_suite):
@@ -507,15 +556,14 @@ def test_fused_matches_host_on_full_ci_grid_with_nan_salt(bar_suite):
     suite_table = SuiteTable.from_cha(cha)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     table = salted_table(topos, n=8, seed=17)
-    svg = evaluate_suite(suite_table, topos, table)
-    assert svg.energy_nj.shape[2:] == (12, 65)
-    assert not np.isfinite(svg.energy_nj).all()
     for max_lat in (None, 40.0):
         grid, sel = evaluate_select_suite(
             suite_table, topos, table, max_latency_ns=max_lat
         )
+        assert grid.energy_nj.shape[2:] == (12, 65)
+        assert not np.isfinite(grid.energy_nj).all()
         np.testing.assert_array_equal(
-            sel.winner_idx.astype(np.int64), svg.best_indices(max_lat)
+            sel.winner_idx.astype(np.int64), grid.best_indices(max_lat)
         )
 
 

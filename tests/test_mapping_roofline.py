@@ -46,7 +46,7 @@ def test_row_budget_gates_feasibility():
     4-bits/gate rule passes (8000 <= 8192 bits) but the single level
     needs ceil(2000/512) = 4 batches -> 3*4+2 = 14 rows > 8.
     """
-    from repro.core.batch import TopologyTable, WorkloadTable, schedule_batch
+    from repro.core.batch import SuiteTable, TopologyTable, evaluate_select_suite
     from repro.core.mapping import BITS_PER_GATE
 
     starved = SramTopology.from_geometry(8, 1024, 1)
@@ -60,14 +60,15 @@ def test_row_budget_gates_feasibility():
         assert schedule_stats(deep, starved, discipline=disc).fits
 
     # The batched engine applies the identical two-term feasibility check.
-    work = WorkloadTable.from_stats({("wide",): wide, ("deep",): deep})
+    suite = SuiteTable.from_cha({"c": {("wide",): wide, ("deep",): deep}})
     topos = TopologyTable.from_topologies([starved, SramTopology(8, 1)])
     for disc in ("levels", "list"):
-        got = schedule_batch(work, topos, discipline=disc)
+        sg, _ = evaluate_select_suite(suite, topos, discipline=disc)
+        got = sg.grid(0)
         for ti, topo in enumerate(topos.topologies):
             for ri, st_ in enumerate((wide, deep)):
                 ref = schedule_stats(st_, topo, discipline=disc)
-                assert bool(got["fits"][ti, ri]) == ref.fits
+                assert bool(got.fits[ti, ri]) == ref.fits
 
 
 # ------------------------------ roofline parse ------------------------------

@@ -143,7 +143,7 @@ def _measure(tmp: Path) -> dict:
 
     # the operands the fused program is given, by placement: host arrays
     # (replicated to every device) and arrays already laid over devices
-    fused = batch._fused_kernels()[1]
+    fused = batch._fused_program()
     given = []
 
     def counted(*args, **kw):

@@ -8,7 +8,7 @@ Contracts under test:
   * the on-disk cache hits, misses, and invalidates on a
     `TRANSFORM_VERSION` bump;
   * `SuiteTable` padding/masking is invisible: suite results equal each
-    circuit's own `WorkloadTable` results on the full 65 x 12 grid;
+    circuit run as its own one-circuit suite on the full 65 x 12 grid;
   * the programmatic topology grid schedules/evaluates exactly like the
     scalar path.
 """
@@ -24,10 +24,7 @@ from repro.core.batch import (
     SuiteTable,
     TopologyTable,
     WorkloadTable,
-    evaluate_batch,
-    evaluate_suite,
-    schedule_batch,
-    schedule_suite,
+    evaluate_select_suite,
 )
 from repro.core.explorer import explore, explore_suite
 from repro.core.mapping import macros_per_type, schedule_stats
@@ -199,8 +196,14 @@ def test_aig_stats_roundtrip(tiny_cha):
 
 
 # ---------------------------------------------------------------------------
-# SuiteTable / evaluate_suite parity on the 65 x 12 grid
+# SuiteTable / evaluate_select_suite parity on the 65 x 12 grid
 # ---------------------------------------------------------------------------
+
+
+def one_circuit_grid(cha, topos, model=EM, **kw):
+    """One circuit's recipes swept as a one-circuit suite: its grid."""
+    sg, _ = evaluate_select_suite(SuiteTable.from_cha({"c": cha}), topos, model, **kw)
+    return sg.grid(0)
 
 
 @pytest.mark.parametrize("mode", ["physical", "paper"])
@@ -209,10 +212,9 @@ def test_suite_matches_per_circuit_grids(tiny_cha, mode, discipline):
     suite = SuiteTable.from_cha(tiny_cha)
     assert suite.ops.shape[:2] == (len(tiny_cha), 65)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
-    sg = evaluate_suite(suite, topos, EM, mode=mode, discipline=discipline)
+    sg, _ = evaluate_select_suite(suite, topos, EM, mode=mode, discipline=discipline)
     for name, cha in tiny_cha.items():
-        work = WorkloadTable.from_stats(cha)
-        ref = evaluate_batch(work, topos, EM, mode=mode, discipline=discipline)
+        ref = one_circuit_grid(cha, topos, mode=mode, discipline=discipline)
         got = sg.grid(name)
         assert np.array_equal(got.cycles, ref.cycles)
         assert np.array_equal(got.active_macro_cycles, ref.active_macro_cycles)
@@ -232,11 +234,11 @@ def test_suite_padding_is_masked(tiny_cha):
     names = list(tiny_cha)
     assert suite.n_levels[0].max() != suite.n_levels[1].max()
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[:3])
-    ss = schedule_suite(suite, topos)
+    ss, _ = evaluate_select_suite(suite, topos, EM)
     for i, name in enumerate(names):
-        ref = schedule_batch(WorkloadTable.from_stats(tiny_cha[name]), topos)
-        assert np.array_equal(ss["cycles"][i], ref["cycles"])
-        assert np.array_equal(ss["fits"][i], ref["fits"])
+        ref = one_circuit_grid(tiny_cha[name], topos)
+        assert np.array_equal(ss.cycles[i], ref.cycles)
+        assert np.array_equal(ss.fits[i], ref.fits)
 
 
 def test_suite_table_workload_view(tiny_cha):
@@ -331,7 +333,7 @@ def test_cell_matches_materialized_grids(tiny_pair, tiny_cha):
     suite grids."""
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     suite = SuiteTable.from_cha(tiny_cha)
-    sg = evaluate_suite(suite, topos, EM)
+    sg, _ = evaluate_select_suite(suite, topos, EM)
     t, r = 3, 5
     for ci, name in enumerate(sg.circuits):
         cell = sg.cell(name, t, r)
@@ -398,8 +400,7 @@ def test_grid_topology_schedule_matches_scalar(tiny_cha):
     cha = tiny_cha[name]
     topos = topology_grid(rows=(128, 512), cols=(256, 512), macro_counts=(1, 3, 9))
     table = TopologyTable.from_topologies(topos)
-    work = WorkloadTable.from_stats(cha)
-    grid = evaluate_batch(work, table, EM)
+    grid = one_circuit_grid(cha, table)
     recipes = list(cha)
     for ti, topo in enumerate(topos):
         for ri in (0, len(recipes) // 2, len(recipes) - 1):

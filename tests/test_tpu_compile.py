@@ -89,7 +89,7 @@ def _compile_fused_suite(table_sharding, param_sharding, n_variants: int):
             _spec(table_sharding, (n_c, n_t), jnp.bool_),
             _spec(table_sharding, (), jnp.float64),
         )
-        _, fused_suite = B._fused_kernels()
+        fused_suite = B._fused_program()
         return fused_suite.lower(
             *args, discipline="list", mode="physical", use_latency=True
         ).compile()

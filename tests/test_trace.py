@@ -94,7 +94,7 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
              for n, m in cha.items()}
     n_records = sum(map(len, fresh.values()))
 
-    _, fused_suite = batch._fused_kernels()
+    fused_suite = batch._fused_program()
     calls = []
 
     def counted(*args, **kw):
@@ -136,19 +136,16 @@ def test_explore_suite_spans(tmp_path, monkeypatch):
     assert table_span["args"]["reused"] == n_records
 
 
-@pytest.mark.parametrize("fused,views_per_circuit", [(True, 2), (False, 0)])
-def test_assemble_span_counts_views(tmp_path, fused, views_per_circuit):
+def test_assemble_span_counts_views(tmp_path):
     """Assembly makes each circuit's `VariationGrid` and its nominal
-    grid: deferred device slices on the fused path, numpy views on the
-    host path, and reads none of their fields."""
+    grid as deferred device slices, and reads none of their fields."""
     suite = {"adder": C.gen_adder(6), "max": C.gen_max(6, 4)}
     cha = characterize_suite(suite, DEPTH1, n_jobs=1, backend="python")
     table = ModelTable.monte_carlo(n=2, sigma=0.1, seed=3)
     events = _record(tmp_path / "trace", lambda: explore_suite(
-        suite, TOPOLOGY_LIBRARY, DEPTH1, cha=cha, model_sweep=table, fused=fused))
+        suite, TOPOLOGY_LIBRARY, DEPTH1, cha=cha, model_sweep=table))
     (assemble,) = _named(events, "rcim.explore.assemble")
-    assert assemble["args"] == {"views": views_per_circuit * len(suite),
-                                "view_slices": 0}
+    assert assemble["args"] == {"views": 2 * len(suite), "view_slices": 0}
 
 
 def test_characterize_suite_spans(tmp_path, monkeypatch):

@@ -28,8 +28,7 @@ from repro.core.batch import (
     SuiteTable,
     TopologyTable,
     WorkloadTable,
-    evaluate_batch,
-    evaluate_suite,
+    evaluate_select_suite,
     select_best,
     table2_batch,
     trace_counts,
@@ -79,6 +78,20 @@ def random_workload(rng, n_recipes=5, max_levels=9, max_ops=2000):
     return WorkloadTable.from_stats(items)
 
 
+def one_circuit(work, topos, model, **kw):
+    """``work`` swept as a one-circuit suite by the fused program: an
+    `ExplorationGrid` for one `EnergyModel`, a `VariationGrid` for a
+    `ModelTable`."""
+    suite = SuiteTable.from_workloads({"c": work})
+    sg, _ = evaluate_select_suite(suite, topos, model, **kw)
+    return sg.variation(0) if isinstance(model, ModelTable) else sg.grid(0)
+
+
+def suite_grid(suite, topos, model, **kw):
+    """The suite's grid from the fused program."""
+    return evaluate_select_suite(suite, topos, model, **kw)[0]
+
+
 def scale_model(base: EnergyModel, k: float) -> EnergyModel:
     """Every sweepable field scaled by ``k`` — a maximally 'different'
     model that still exercises all constants."""
@@ -90,9 +103,8 @@ def scale_model(base: EnergyModel, k: float) -> EnergyModel:
 
 
 def assert_one_variant_bit_identical(work, topos, model, mode, discipline):
-    static = evaluate_batch(work, topos, model, mode=mode,
-                            discipline=discipline)
-    sweep = evaluate_batch(
+    static = one_circuit(work, topos, model, mode=mode, discipline=discipline)
+    sweep = one_circuit(
         work, topos, ModelTable.from_models([model]), mode=mode,
         discipline=discipline,
     )
@@ -273,21 +285,21 @@ def test_sweep_traces_exactly_once_and_float_changes_do_not_retrace():
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     table_a = ModelTable.monte_carlo(n=8, sigma=0.1, seed=0)
 
-    before = trace_counts().get("evaluate_suite", 0)
-    svg_a = evaluate_suite(suite, topos, table_a)
-    assert trace_counts().get("evaluate_suite", 0) == before + 1
+    before = trace_counts().get("fused_suite", 0)
+    svg_a = suite_grid(suite, topos, table_a)
+    assert trace_counts().get("fused_suite", 0) == before + 1
 
     # Same shapes, different model floats: served from the jit cache.
     table_b = ModelTable.monte_carlo(n=8, sigma=0.4, seed=99)
-    svg_b = evaluate_suite(suite, topos, table_b)
-    assert trace_counts().get("evaluate_suite", 0) == before + 1
+    svg_b = suite_grid(suite, topos, table_b)
+    assert trace_counts().get("fused_suite", 0) == before + 1
     # ...and the floats really flowed through (not a stale constant).
     assert not np.array_equal(svg_a.energy_nj, svg_b.energy_nj)
     np.testing.assert_array_equal(svg_a.cycles, svg_b.cycles)
 
     # A new variant count is a new shape: exactly one more trace.
-    evaluate_suite(suite, topos, ModelTable.monte_carlo(n=16, seed=1))
-    assert trace_counts().get("evaluate_suite", 0) == before + 2
+    suite_grid(suite, topos, ModelTable.monte_carlo(n=16, seed=1))
+    assert trace_counts().get("fused_suite", 0) == before + 2
 
 
 def test_serial_static_models_share_one_compile():
@@ -296,16 +308,16 @@ def test_serial_static_models_share_one_compile():
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     table = ModelTable.monte_carlo(n=6, sigma=0.2, seed=5)
 
-    before = trace_counts().get("evaluate_grid", 0)
+    before = trace_counts().get("fused_suite", 0)
     grids = [
-        evaluate_batch(work, topos, table.model(v)) for v in range(6)
+        one_circuit(work, topos, table.model(v)) for v in range(6)
     ]
     # the old engine paid one compile per EnergyModel; now the first call
     # traces and the other five hit the cache
-    assert trace_counts().get("evaluate_grid", 0) == before + 1
+    assert trace_counts().get("fused_suite", 0) == before + 1
     # parity of the serial runs against the one-call sweep
-    sweep = evaluate_batch(work, topos, table)
-    assert trace_counts().get("evaluate_grid", 0) == before + 2  # V=6 shape
+    sweep = one_circuit(work, topos, table)
+    assert trace_counts().get("fused_suite", 0) == before + 2  # V=6 shape
     for v, g in enumerate(grids):
         np.testing.assert_array_equal(sweep.energy_nj[v], g.energy_nj)
         assert int(sweep.best_indices()[v]) == g.best_index()
@@ -391,7 +403,7 @@ def test_empty_model_table_raises():
     with pytest.raises(ValueError, match="empty ModelTable"):
         table2_batch(tt, rogue)
     with pytest.raises(ValueError, match="empty ModelTable"):
-        evaluate_batch(random_workload(np.random.default_rng(0)), tt, rogue)
+        one_circuit(random_workload(np.random.default_rng(0)), tt, rogue)
 
 
 # ---------------------------------------------------------------------------
@@ -417,8 +429,8 @@ def test_v1_table_bit_identical_to_uniform_sweep():
     table = ModelTable.monte_carlo(n=4, sigma=0.2, seed=9)
     v1 = as_v1_table(table)
     assert v1.n_topologies is None  # (V, 1) broadcasts uniformly
-    a = evaluate_batch(work, topos, table)
-    b = evaluate_batch(work, topos, v1)
+    a = one_circuit(work, topos, table)
+    b = one_circuit(work, topos, v1)
     for k in METRIC_KEYS:
         np.testing.assert_array_equal(getattr(a, k), getattr(b, k))
     np.testing.assert_array_equal(a.area_mm2, b.area_mm2)
@@ -465,7 +477,7 @@ def test_correlated_generator_shapes_and_validation():
     short = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[:5])
     table_12 = ModelTable.bitcell_sigma_per_macro(TOPOLOGY_LIBRARY, n=2)
     with pytest.raises(ValueError, match="per-topology axis"):
-        evaluate_batch(
+        one_circuit(
             random_workload(np.random.default_rng(0)), short, table_12
         )
     with pytest.raises(ValueError, match="per-topology axis"):
@@ -477,7 +489,7 @@ def test_correlated_generator_shapes_and_validation():
     )
     reordered = TopologyTable.from_topologies(TOPOLOGY_LIBRARY[::-1])
     with pytest.raises(ValueError, match="generated for"):
-        evaluate_batch(
+        one_circuit(
             random_workload(np.random.default_rng(0)), reordered, table_12
         )
     with pytest.raises(ValueError, match="generated for"):
@@ -503,7 +515,7 @@ def test_correlated_variant_slices_work_without_scalar_model():
         TOPOLOGY_LIBRARY, n=3, sigma=0.3, seed=4
     )
     assert table.uniform_row(0) and not table.uniform_row(1)
-    vg = evaluate_batch(work, topos, table)
+    vg = one_circuit(work, topos, table)
     g0, g1 = vg.grid(0), vg.grid(1)
     assert g0.model == EnergyModel()
     assert g1.model is None  # no single EnergyModel represents row 1
@@ -511,7 +523,7 @@ def test_correlated_variant_slices_work_without_scalar_model():
     assert g1.best_index() == int(vg.best_indices()[1])
     assert g1.fit_energies().size > 0
     suite = SuiteTable.from_workloads({"a": work, "b": work})
-    svg = evaluate_suite(suite, topos, table)
+    svg = suite_grid(suite, topos, table)
     assert svg.suite(0).model == EnergyModel()
     assert svg.suite(1).model is None
     # best_worst needs a scalar model to materialize Evaluations: clear
@@ -544,7 +556,7 @@ def test_correlated_sweep_matches_scalar_path():
     table = ModelTable.bitcell_sigma_per_macro(
         TOPOLOGY_LIBRARY, n=3, sigma=0.4, seed=21
     )
-    vg = evaluate_batch(work, topos, table)
+    vg = one_circuit(work, topos, table)
     for v in range(3):
         for t in range(len(TOPOLOGY_LIBRARY)):
             m = table.model(v, topology=t)
@@ -584,7 +596,7 @@ def test_suite_best_indices_match_select_best_loop(bar_suite):
     }
     for max_lat in (None, 40.0):
         for kind, table in tables.items():
-            svg = evaluate_suite(suite_table, topos, table)
+            svg = suite_grid(suite_table, topos, table)
             assert svg.energy_nj.shape[2:] == (12, 65)
             got = svg.best_indices(max_lat)
             assert got.shape == (len(svg.circuits), len(table))
@@ -609,7 +621,7 @@ def test_variation_cell_matches_materialized_grids(bar_suite):
     suite_table = SuiteTable.from_cha(cha)
     topos = TopologyTable.from_topologies(TOPOLOGY_LIBRARY)
     table = ModelTable.monte_carlo(n=5, sigma=0.2, seed=17)
-    svg = evaluate_suite(suite_table, topos, table)
+    svg = suite_grid(suite_table, topos, table)
     v, t, r = 3, 7, 11
     cell = svg.cell("bar", v, t, r)
     assert cell.circuit == "bar" and cell.variant == v

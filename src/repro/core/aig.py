@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 from functools import lru_cache
 from typing import Callable, Iterable, Sequence
 
@@ -337,6 +338,62 @@ class Aig:
                 seen.add(n)
                 out.append(n)
         return out
+
+    def cone_pairs(
+        self,
+        roots: Sequence[Sequence[int]],
+        leaves: Sequence[Sequence[int]],
+        lv: np.ndarray,
+    ) -> tuple[np.ndarray, np.ndarray, int]:
+        """Batched `cone_nodes`: the AND nodes of every query's cone, as
+        ``(query, node)`` pairs sorted by query, then node.
+
+        Query ``i`` unions the cones of the nodes ``roots[i]``, stopping at
+        and excluding ``leaves[i]``.  ``lv`` ranks nodes so that every
+        fanin ranks below its node (`levels`).  Each round expands, in
+        every query at once, the pending nodes at that query's highest
+        pending rank: no later round can reach them again, so the pending
+        set is deduplicated once per round and the work grows with the
+        members and frontiers, not with queries x nodes.  Returns
+        ``(query, node, rounds)``.
+        """
+        n = self.n_nodes
+        f0 = np.asarray(self._f0, dtype=np.int64) >> 1
+        f1 = np.asarray(self._f1, dtype=np.int64) >> 1
+        lv = np.asarray(lv, dtype=np.int64)
+
+        def keys_of(groups: Sequence[Sequence[int]]) -> np.ndarray:
+            sizes = np.fromiter(map(len, groups), dtype=np.int64, count=len(groups))
+            nodes = np.fromiter(
+                itertools.chain.from_iterable(groups), dtype=np.int64, count=int(sizes.sum())
+            )
+            return np.unique(np.repeat(np.arange(len(groups)) * n, sizes) + nodes)
+
+        # a trailing sentinel above every key keeps each lookup in range
+        leaf_keys = np.append(keys_of(leaves), np.iinfo(np.int64).max)
+
+        def inner(keys: np.ndarray) -> np.ndarray:
+            is_leaf = leaf_keys[np.searchsorted(leaf_keys, keys)] == keys
+            return keys[(keys % n > self.n_pis) & ~is_leaf]
+
+        pending = inner(keys_of(roots))
+        done: list[np.ndarray] = []
+        rounds = 0
+        while pending.size:
+            rounds += 1
+            q = pending // n
+            rank = lv[pending - q * n]
+            starts = np.flatnonzero(np.concatenate(([True], q[1:] != q[:-1])))
+            top = np.maximum.reduceat(rank, starts)
+            take = rank == np.repeat(top, np.diff(np.append(starts, len(q))))
+            now = pending[take]
+            done.append(now)
+            node = now % n
+            base = now - node
+            kids = inner(np.concatenate((base + f0[node], base + f1[node])))
+            pending = np.unique(np.concatenate((pending[~take], kids)))
+        keys = np.sort(np.concatenate(done)) if done else np.zeros(0, dtype=np.int64)
+        return keys // n, keys % n, rounds
 
     def truth_table(
         self,

@@ -724,64 +724,42 @@ def resolve_backend(backend: str | None) -> str:
     return backend
 
 
-def _cone_matrix(
-    aig: Aig, roots: Sequence[int], leaves_list: Sequence[Sequence[int]]
-) -> np.ndarray:
-    """(B, n_nodes) bool cone membership for a batch of (root, leaves)
-    queries — the vectorized counterpart of `Aig.cone_nodes` (AND nodes
-    only, stopping at and excluding the leaves).
-
-    One descending-index scan over the node array serves the whole batch:
-    node indices are topological, so by the time the scan reaches ``n``
-    every cone that contains ``n`` has already marked it.
-    """
-    n = aig.n_nodes
-    n_b = len(roots)
-    roots_a = np.asarray(roots, dtype=np.int64)
-    f0 = np.asarray(aig._f0, dtype=np.int64)
-    f1 = np.asarray(aig._f1, dtype=np.int64)
-    # (n_nodes, batch) scan layout: node rows are contiguous (see
-    # `aig_sim._cone_members`), transposed back on return.
-    vis = np.zeros((n, n_b), dtype=bool)
-    leaf = np.zeros((n, n_b), dtype=bool)
-    for i, lvs in enumerate(leaves_list):
-        leaf[list(lvs), i] = True
-    vis[roots_a, np.arange(n_b)] = True
-    for node in range(int(roots_a.max()), aig.n_pis, -1):
-        act = vis[node] & ~leaf[node]
-        if act.any():
-            vis[f0[node] >> 1][act] = True
-            vis[f1[node] >> 1][act] = True
-    members = vis & ~leaf
-    members[: aig.n_pis + 1] = False
-    return np.ascontiguousarray(members.T)
-
-
-def _mffc_sizes_batch(
+def _mffc_sizes(
     aig: Aig,
     roots: Sequence[int],
-    members: np.ndarray,
+    b_idx: np.ndarray,
+    node_idx: np.ndarray,
     fanout: np.ndarray,
 ) -> np.ndarray:
-    """(B,) MFFC sizes matching `_mffc_size` for each (root, cone) row of
-    ``members`` (from `_cone_matrix`): cone nodes whose every fanout
-    reference comes from inside the cone, the root always counted."""
+    """(B,) MFFC sizes matching `_mffc_size` for the ``(query, node)``
+    cone pairs of `Aig.cone_pairs`: members whose every fanout reference
+    comes from inside their cone, the root always counted."""
     n = aig.n_nodes
-    n_b = members.shape[0]
     f0 = np.asarray(aig._f0, dtype=np.int64) >> 1
     f1 = np.asarray(aig._f1, dtype=np.int64) >> 1
-    # Cones are tiny relative to the graph, so work on the sparse member
-    # entries: bincount the two fanin edges of every (item, cone node)
-    # pair into per-item reference counts, then test each member entry.
-    b_idx, node_idx = np.nonzero(members)
-    keys = np.concatenate([b_idx * n + f0[node_idx], b_idx * n + f1[node_idx]])
-    refs = np.bincount(keys, minlength=n_b * n)
+    # In-cone reference counts, keyed by query * n + fanin; a trailing
+    # sentinel above every key keeps each lookup in range.
+    refs, counts = np.unique(
+        np.concatenate((b_idx * n + f0[node_idx], b_idx * n + f1[node_idx])),
+        return_counts=True,
+    )
+    refs = np.append(refs, np.iinfo(np.int64).max)
     mkeys = b_idx * n + node_idx
-    freed_mask = refs[mkeys] >= fanout[node_idx]
-    freed = np.bincount(b_idx[freed_mask], minlength=n_b)
+    pos = np.searchsorted(refs, mkeys)
+    inside = np.where(refs[pos] == mkeys, np.append(counts, 0)[pos], 0)
     roots_a = np.asarray(roots, dtype=np.int64)
-    root_pass = refs[np.arange(n_b) * n + roots_a] >= fanout[roots_a]
-    return freed - root_pass.astype(np.int64) + 1
+    freed = (inside >= fanout[node_idx]) | (node_idx == roots_a[b_idx])
+    return np.bincount(b_idx[freed], minlength=len(roots_a))
+
+
+def _cones(aig: Aig, lv: np.ndarray, roots: Sequence[int],
+           leaves_list: Sequence[Sequence[int]], fanout: np.ndarray):
+    """One batch's cone pairs and MFFC sizes, under the ``cha.cones`` span."""
+    with trace.span("cha.cones", queries=len(roots)) as span:
+        b_idx, node_idx, rounds = aig.cone_pairs([(r,) for r in roots], leaves_list, lv)
+        old_costs = _mffc_sizes(aig, roots, b_idx, node_idx, fanout)
+        span.set_metadata(pairs=len(b_idx), rounds=rounds)
+    return (b_idx, node_idx), old_costs
 
 
 def _rewrite_device(aig: Aig, k: int = 4, max_cuts: int = 8) -> Aig:
@@ -809,9 +787,7 @@ def _rewrite_device(aig: Aig, k: int = 4, max_cuts: int = 8) -> Aig:
     if items:
         prog = aig_sim.compile_aig(aig)
         roots = [n for n, _ in items]
-        with trace.span("cha.cones", queries=len(items)):
-            members = _cone_matrix(aig, roots, [s for _, s in items])
-            old_costs = _mffc_sizes_batch(aig, roots, members, fanout)
+        members, old_costs = _cones(aig, prog.lv, roots, [s for _, s in items], fanout)
         tts = aig_sim.eval_tts(
             aig,
             [((lit(n),), sup) for n, sup in items],
@@ -883,9 +859,7 @@ def _refactor_device(aig: Aig, max_leaves: int = 10) -> Aig:
     if cand_items:
         prog = aig_sim.compile_aig(aig)
         roots = [n for n, _ in cand_items]
-        with trace.span("cha.cones", queries=len(cand_items)):
-            members = _cone_matrix(aig, roots, [l for _, l in cand_items])
-            old_costs = _mffc_sizes_batch(aig, roots, members, fanout)
+        members, old_costs = _cones(aig, prog.lv, roots, [l for _, l in cand_items], fanout)
         tts = aig_sim.eval_tts(
             aig,
             [((lit(n),), lvs) for n, lvs in cand_items],

@@ -476,45 +476,25 @@ def _resolve_engine(engine: str, prog: "AigProgram | None" = None) -> str:
 # ---------------------------------------------------------------------------
 
 
-def _cone_members(
-    aig: Aig,
-    items: Sequence[tuple[Sequence[int], Sequence[int]]],
-    idxs: Sequence[int],
-) -> np.ndarray:
-    """(len(idxs), n_nodes) bool: AND nodes in each query's pinned cone(s).
+def _row_map(n_nodes: int, item: np.ndarray, node: np.ndarray, row: np.ndarray):
+    """The (query, node) -> row lookup of one mega-program, kept sparse.
 
-    Descending-index scan (fanins always have smaller indices): a node
-    active in a query (visited, not a leaf) marks both fanin nodes.
-    Multi-root queries seed every root's node, so one row covers the
-    union cone (resub's (n, m) pairs).
-    """
-    n = aig.n_nodes
-    n_pis = aig.n_pis
-    f0 = np.asarray(aig._f0, dtype=np.int64)
-    f1 = np.asarray(aig._f1, dtype=np.int64)
-    # (n_nodes, batch) layout: the scan touches whole node rows, which
-    # are contiguous this way round (the (B, n) layout strides by n per
-    # element and is several times slower).
-    vis = np.zeros((n, len(idxs)), dtype=bool)
-    leaf = np.zeros((n, len(idxs)), dtype=bool)
-    hi = n_pis
-    for row, i in enumerate(idxs):
-        roots, support = items[i]
-        leaf[list(support), row] = True
-        for rl in roots:
-            r = rl >> 1
-            vis[r, row] = True
-            if r > hi:
-                hi = r
-    for node in range(hi, n_pis, -1):
-        act = vis[node] & ~leaf[node]
-        if not act.any():
-            continue
-        vis[f0[node] >> 1][act] = True
-        vis[f1[node] >> 1][act] = True
-    members = vis & ~leaf
-    members[: n_pis + 1] = False
-    return np.ascontiguousarray(members.T)
+    A node listed twice for one query maps to its last row, as a
+    repeated support variable does on the python path.  Unmapped nodes
+    read row 0 (const0): the python path would raise on such a read, and
+    no caller makes one (cones are closed over their supports)."""
+    flat = item * n_nodes + node
+    order = np.argsort(flat, kind="stable")
+    # a leading sentinel below every key stands for "unmapped"
+    keys = np.concatenate(([-1], flat[order]))
+    rows = np.concatenate(([0], row[order]))
+
+    def row_of(q: np.ndarray, n: np.ndarray) -> np.ndarray:
+        want = q * n_nodes + n
+        pos = np.searchsorted(keys, want, side="right") - 1
+        return np.where(keys[pos] == want, rows[pos], 0)
+
+    return row_of
 
 
 def _eval_mega_tier(
@@ -523,10 +503,13 @@ def _eval_mega_tier(
     items: Sequence[tuple[Sequence[int], Sequence[int]]],
     idxs: list[int],
     w: int,
-    mem: np.ndarray,
+    members: tuple[np.ndarray, np.ndarray],
     results: list,
 ) -> None:
     """Run one word tier's queries as mega-programs on the jnp engine.
+
+    ``members`` holds the tier's cone pairs (`Aig.cone_pairs`), their
+    query numbered by position in ``idxs``.
 
     Each chunk concatenates the per-query cone programs into one flat
     row space (row 0 = const0, then per query: k support rows pinned to
@@ -543,7 +526,8 @@ def _eval_mega_tier(
     dev_elem = _dev_elem(k_max)
     f0 = np.asarray(aig._f0, dtype=np.int64)  # repro: host-boundary
     f1 = np.asarray(aig._f1, dtype=np.int64)  # repro: host-boundary
-    sizes = mem.sum(axis=1).astype(np.int64)
+    mem_b, mem_node = members
+    sizes = np.bincount(mem_b, minlength=len(idxs))
     budget = _MEGA_BUDGET[w]
     wave_m = _MEGA_WAVE[w]
     fn = _jnp_mega_fn()
@@ -565,11 +549,10 @@ def _eval_mega_tier(
 
     for chunk in chunks:
         with trace.span("aig_sim.pack") as span:
-            if len(chunk) == len(idxs):
-                cm, counts = mem, sizes
-            else:
-                sel = np.asarray(chunk, dtype=np.int64)  # repro: host-boundary
-                cm, counts = mem[sel], sizes[sel]
+            # chunks are runs of consecutive positions
+            lo, hi = np.searchsorted(mem_b, (chunk[0], chunk[-1] + 1))
+            b_idx, node_idx = mem_b[lo:hi] - chunk[0], mem_node[lo:hi]
+            counts = sizes[chunk[0] : chunk[-1] + 1]
             it = [items[idxs[p]] for p in chunk]
             k_b = np.array([len(s) for _, s in it], dtype=np.int64)  # repro: host-boundary
             r_b = np.array([len(r) for r, _ in it], dtype=np.int64)  # repro: host-boundary
@@ -589,23 +572,22 @@ def _eval_mega_tier(
             sup_rows = row_base[item_of_sup] + var_idx
             pin_rows = np.full(n_rows_pad, -1, dtype=np.int32)
             pin_rows[sup_rows] = var_idx
-            # node -> row per query; unmapped nodes fall through to row 0
-            # (const0) — the python path would raise on such a read, and no
-            # caller produces one (cones are closed over their supports).
-            rowmap = np.zeros((len(it), aig.n_nodes), dtype=np.int32)
-            rowmap[item_of_sup, sup_nodes] = sup_rows
-            b_idx, node_idx = np.nonzero(cm)
+            starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+            local = np.arange(len(b_idx)) - np.repeat(starts, counts)
+            cone_rows = row_base[b_idx] + k_b[b_idx] + local
+            row_of = _row_map(
+                aig.n_nodes,
+                np.concatenate((item_of_sup, b_idx)),
+                np.concatenate((sup_nodes, node_idx)),
+                np.concatenate((sup_rows, cone_rows)),
+            )
             n_waves = 0
             if len(b_idx):
-                starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-                local = np.arange(len(b_idx)) - np.repeat(starts, counts)
-                cone_rows = row_base[b_idx] + k_b[b_idx] + local
-                rowmap[b_idx, node_idx] = cone_rows
                 f0n = f0[node_idx]
                 f1n = f1[node_idx]
                 kind = (f0n & 1) | ((f1n & 1) << 1)
-                a_row = rowmap[b_idx, f0n >> 1]
-                b_row = rowmap[b_idx, f1n >> 1]
+                a_row = row_of(b_idx, f0n >> 1)
+                b_row = row_of(b_idx, f1n >> 1)
                 instr = np.stack([kind, a_row, b_row, cone_rows], axis=1).astype(
                     np.int32
                 )
@@ -633,7 +615,7 @@ def _eval_mega_tier(
                 dtype=np.int64,
                 count=int(r_b.sum()),
             )
-            root_rows = rowmap[q_item, root_lits >> 1]
+            root_rows = row_of(q_item, root_lits >> 1)
             n_q = len(root_lits)
             n_q_pad = _next_pow2(n_q, floor=6)
             rootp = np.zeros(n_q_pad, dtype=np.int32)
@@ -684,7 +666,7 @@ def eval_tts(
     items: Sequence[tuple[Sequence[int], Sequence[int]]],
     engine: str = "auto",
     program: AigProgram | None = None,
-    members: np.ndarray | None = None,
+    members: tuple[np.ndarray, np.ndarray] | None = None,
 ) -> list[tuple[int, ...]]:
     """Batched exact truth tables: ``items[i] = (root_lits, support)``.
 
@@ -696,9 +678,8 @@ def eval_tts(
     are bucketed by word tier and evaluated as chunked *mega-programs*
     (see `_eval_mega_tier`); wider queries take the host bigint path,
     where CPython's limb loops already run at memory speed.  ``members``
-    may supply precomputed cone membership rows aligned with ``items``
-    (callers that already ran an MFFC sweep have them); otherwise
-    membership is derived here with the same descending scan.
+    may supply the items' cone pairs from `Aig.cone_pairs` (callers that
+    already sized MFFCs have them); otherwise they are walked here.
 
     The Pallas engine evaluates every query against the whole graph
     (one grid step per block of lane-packed queries, VMEM scratch = the
@@ -722,12 +703,20 @@ def eval_tts(
         else:
             _, w = _tier_for(k)
             tiers.setdefault(w, []).append(idx)
+    if tiers and members is None:
+        members = aig.cone_pairs(
+            [[rl >> 1 for rl in roots] for roots, _ in items],
+            [support for _, support in items],
+            prog.lv,
+        )[:2]
     for w, idxs in tiers.items():
-        if members is not None:
-            mem = members[np.asarray(idxs, dtype=np.int64)]
-        else:
-            mem = _cone_members(aig, items, idxs)
-        _eval_mega_tier(aig, prog, items, idxs, w, mem, results)
+        pos_of = np.full(len(items), -1, dtype=np.int64)
+        pos_of[idxs] = np.arange(len(idxs))
+        tier_b = pos_of[members[0]]
+        keep = tier_b >= 0
+        _eval_mega_tier(
+            aig, prog, items, idxs, w, (tier_b[keep], members[1][keep]), results
+        )
     return results  # type: ignore[return-value]
 
 

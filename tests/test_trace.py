@@ -204,6 +204,24 @@ def test_characterize_suite_spans(tmp_path, monkeypatch):
         == launches["h2d"]
 
 
+def test_cones_span_counts_pairs(tmp_path):
+    """A device rewrite records one ``rcim.cha.cones`` span whose
+    ``pairs`` are its queries' summed cone sizes."""
+    from repro.core import transforms as T
+
+    rtl = C.gen_adder(8)
+    events = _record(tmp_path / "trace", lambda: T.rewrite(rtl, backend="device"))
+    (cones,) = _named(events, "rcim.cha.cones")
+    cuts = T._enumerate_cuts(rtl, k=4, max_cuts=8)
+    reach = T._reachable(rtl)
+    queries = [(n, c) for n in range(rtl.n_pis + 1, rtl.n_nodes) if reach[n]
+               for c in cuts[n] if len(c) >= 2 and n not in c]
+    assert set(cones["args"]) == {"queries", "pairs", "rounds"}
+    assert cones["args"]["queries"] == len(queries)
+    assert cones["args"]["pairs"] == sum(len(rtl.cone_nodes(n, set(c))) for n, c in queries)
+    assert 1 <= cones["args"]["rounds"] <= cones["args"]["pairs"]
+
+
 def test_span_imports_nothing():
     """Without jax a span never imports it; with jax and no profiler
     session it imports nothing either."""
